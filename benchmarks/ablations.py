@@ -106,6 +106,8 @@ def main(smoke: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.runtime import device_banner
+    print(device_banner())
     for k, rows in main().items():
         print(f"== {k}")
         for r in rows:

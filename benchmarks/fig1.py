@@ -231,6 +231,8 @@ if __name__ == "__main__":
     ap.add_argument("--no-selection", action="store_true",
                     help="skip the selection-rule ablation")
     args = ap.parse_args()
+    from repro.launch.runtime import device_banner
+    print(device_banner())
     for row in main(scale=args.scale, max_iters=args.max_iters,
                     with_batched=not args.no_batched,
                     with_selection=not args.no_selection):

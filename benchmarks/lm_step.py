@@ -76,5 +76,7 @@ def main() -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.runtime import device_banner
+    print(device_banner())
     for r in main():
         print(r)

@@ -228,6 +228,8 @@ if __name__ == "__main__":
                     help="seconds-scale CI configuration (deterministic "
                          "gates only; overhead recorded, not gated)")
     args = ap.parse_args()
+    from repro.launch.runtime import device_banner
+    print(device_banner())
     art = main(requests=args.requests, seed=args.seed, m=args.m,
                n=args.n, max_iters=args.max_iters,
                slab_capacity=args.slab_capacity,

@@ -282,6 +282,8 @@ if __name__ == "__main__":
                     help="seconds-scale CI gate (deterministic criteria)")
     ap.add_argument("--skip-cv", action="store_true")
     a = ap.parse_args()
+    from repro.launch.runtime import device_banner
+    print(device_banner())
     art = main(m=a.m, n=a.n, nnz=a.nnz, seed=a.seed, points=a.points,
                lam_min_ratio=a.lam_min_ratio, max_iters=a.max_iters,
                smoke=a.smoke, skip_cv=a.skip_cv)

@@ -1,14 +1,16 @@
 """Remote solver-service smoke: the wire adds no error and drains clean.
 
-The seconds-scale CI gate for ``repro.remote``: a real server subprocess
-is started on a loopback port (READY handshake on stdout), every
+The seconds-scale CI gate for ``repro.remote``: the server runs on a
+loopback port in this process (``InProcessServer`` — one process holds
+the device, so the server and the inline reference share it), every
 workload kind (solo, batch, path, CV) × two problem families runs
 through ``FlexaClient(backend="remote")``, and each answer is diffed
 against the inline reference — deterministic criteria only, the same
 1e-5 envelope the in-process backend matrix gates on.  The run ends
-with a graceful-drain check: SIGTERM with the last ticket in flight
-must complete that ticket, flush a schema-versioned telemetry snapshot,
-print ``DRAINED`` and exit 0.
+with a graceful-drain check: a drain (the SIGTERM path) with the last
+ticket in flight must complete that ticket, flush a schema-versioned
+telemetry snapshot, print ``DRAINED`` and exit 0.  The subprocess
+server and its signal handling are covered by ``tests/test_remote.py``.
 
 Artifact: ``results/bench/BENCH_remote.json`` — the kind × family
 deviation matrix plus the drain record.
@@ -17,11 +19,9 @@ Run: ``PYTHONPATH=src python benchmarks/remote_smoke.py`` (≈30 s).
 """
 from __future__ import annotations
 
+import contextlib
+import io
 import json
-import os
-import signal
-import subprocess
-import sys
 import tempfile
 from pathlib import Path
 
@@ -32,9 +32,9 @@ from repro.client import (BatchSpec, CVSpec, ClientConfig, FlexaClient,
 from repro.config.base import SolverConfig
 from repro.problems.lasso import nesterov_instance
 from repro.problems.logreg import random_logreg_instance
+from repro.remote.server import InProcessServer
 
 RESULTS = Path(__file__).resolve().parent.parent / "results" / "bench"
-SRC = Path(__file__).resolve().parent.parent / "src"
 
 TOL = 1e-5
 #: The fixed-τ calibration the in-process equivalence matrix uses.
@@ -76,26 +76,13 @@ def _x_of(kind: str, result) -> np.ndarray:
     return np.asarray(result.x)
 
 
-def spawn_server(extra_args=()) -> tuple[subprocess.Popen, str]:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(SRC) + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "repro.remote.server", "--port", "0",
-         *SERVER_ARGS, *extra_args],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, env=env,
-        text=True)
-    for line in proc.stdout:
-        if line.startswith("READY port="):
-            port = int(line.split("=")[1])
-            return proc, f"http://127.0.0.1:{port}"
-    err = proc.stderr.read()
-    proc.kill()
-    raise RuntimeError(f"server failed to start:\n{err}")
-
-
 def main() -> dict:
     snap_file = Path(tempfile.mkdtemp()) / "drain_snapshot.json"
-    proc, url = spawn_server(["--telemetry-out", str(snap_file)])
+    server_out = io.StringIO()          # READY / DRAINED handshake lines
+    with contextlib.redirect_stdout(server_out):
+        server = InProcessServer([*SERVER_ARGS,
+                                  "--telemetry-out", str(snap_file)])
+    url = server.url
     matrix: dict[str, dict] = {}
     ok = True
     try:
@@ -120,17 +107,18 @@ def main() -> dict:
                 print(f"[remote/{family:>11}] {kind:<5} dev={dev:.2e} "
                       f"ok={cell['dev_ok']}")
 
-        # Graceful drain: SIGTERM with a ticket in flight — the ticket
-        # completes, telemetry flushes, DRAINED prints, exit code 0.
+        # Graceful drain with a ticket in flight — the ticket completes,
+        # telemetry flushes, DRAINED prints, exit code 0.
         t = remote.submit(SoloSpec(problem=_instance("lasso", 3)))
-        proc.send_signal(signal.SIGTERM)
-        drained_res = remote.result(t)
-        out, _ = proc.communicate(timeout=120)
+        with contextlib.redirect_stdout(server_out):
+            server.begin_drain()
+            drained_res = remote.result(t)
+            exit_code = server.join(timeout_s=120)
         snap = json.loads(snap_file.read_text())
         drain = {
             "inflight_completed": bool(drained_res.converged),
-            "exit_code": proc.returncode,
-            "drained_printed": "DRAINED" in out,
+            "exit_code": exit_code,
+            "drained_printed": "DRAINED" in server_out.getvalue(),
             "snapshot_schema": snap.get("schema"),
             "completed": snap.get("telemetry", {}).get("completed"),
         }
@@ -143,8 +131,9 @@ def main() -> dict:
         print(f"[remote/drain] completed={drain['completed']} "
               f"exit={drain['exit_code']} ok={drain['ok']}")
     finally:
-        if proc.poll() is None:
-            proc.kill()
+        if server.exit_code is None:
+            server.begin_drain()
+            server.join(timeout_s=120)
 
     cells = [c for fam in matrix.values() for c in fam.values()]
     artifact = {

@@ -60,6 +60,9 @@ def main() -> None:
     args = ap.parse_args()
     failures: list[str] = []
 
+    from repro.launch.runtime import device_banner, use_compile_cache
+    use_compile_cache()
+    print(device_banner())
     print("name,us_per_call,derived")
 
     from benchmarks import fig1
@@ -182,7 +185,7 @@ def main() -> None:
           f"patience={art['stall_patience']}")
 
     if not args.skip_remote:
-        # Solver-service smoke: server subprocess on a loopback port,
+        # Solver-service smoke: in-process server on a loopback port,
         # remote-backend equivalence vs inline + graceful-drain gate
         # (writes BENCH_remote.json; deterministic criteria only).
         from benchmarks import remote_smoke

@@ -644,6 +644,8 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="seconds-scale CI configuration")
     args = ap.parse_args()
+    from repro.launch.runtime import device_banner
+    print(device_banner())
     if args.devices:
         # Per-device capacity defaults SMALL in the mesh bench: the
         # throughput ratio compares saturated schedules, and a large

@@ -205,18 +205,52 @@ def flexa_iteration(problem: Problem, cfg: SolverConfig,
 def make_step(problem: Problem, cfg: SolverConfig, active=None):
     """Build the jitted Algorithm-1 iteration ``state -> (state, info)``.
 
-    ``active`` optionally bakes a per-coordinate freeze mask into the
-    compiled step (see :func:`flexa_iteration`)."""
+    ``active`` optionally restricts the step to a per-coordinate freeze
+    mask (see :func:`flexa_iteration`).
+
+    A registered family's data arrays enter the compiled step as
+    arguments, its F closures rebuilt inside from them: arrays a jitted
+    function closes over are compiled into the program as constants, so
+    every design matrix would be copied into its executable (hundreds of
+    MB at fig1b size, past the 2 GB program limit at fig1d).  Ad-hoc
+    problems keep their closures."""
+    from repro.problems.families import (available_families, build_problem,
+                                         get_family)
+
     tau_base = _base_tau(problem, cfg)
     if active is not None:
         active = jnp.asarray(active, jnp.float32)
+    fam = (get_family(problem.family)
+           if problem.family in available_families() else None)
+    if fam is None or any(k not in problem.data for k in fam.data_keys):
+        @jax.jit
+        def step(state: FlexaState):
+            return flexa_iteration(problem, cfg, tau_base, state,
+                                   active=active)
+
+        return step
+
+    # A batch of one, vmapped like the batched engine's iteration: the
+    # products then reduce in the order they do there, which keeps solo
+    # and batched trajectories together.
+    arrays = tuple(jnp.asarray(problem.data[k])[None] for k in fam.data_keys)
+    col_sq = jax.vmap(fam.col_sq)(*arrays)
+
+    def instance_step(arrays, col_sq, state, tau_base, active):
+        p = build_problem(problem.family, arrays, problem.g_weight,
+                          n=problem.n, block_size=problem.block_size,
+                          g_kind=problem.g_kind, col_sq=col_sq)
+        return flexa_iteration(p, cfg, tau_base, state, active=active)
 
     @jax.jit
-    def step(state: FlexaState):
-        return flexa_iteration(problem, cfg, tau_base, state,
-                               active=active)
+    def family_step(arrays, col_sq, tau_base, active, state: FlexaState):
+        one = jax.tree_util.tree_map(lambda a: a[None], state)
+        new, info = jax.vmap(instance_step, in_axes=(0, 0, 0, None, None))(
+            arrays, col_sq, one, tau_base, active)
+        return jax.tree_util.tree_map(lambda a: a[0], (new, info))
 
-    return step
+    return lambda state: family_step(arrays, col_sq, tau_base, active,
+                                     state)
 
 
 def solve(problem: Problem, x0=None, cfg: SolverConfig | None = None,

@@ -28,14 +28,16 @@ from typing import NamedTuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config.base import SolverConfig
-from repro.compat import shard_map
 from repro.core.flexa import MAX_TAU_CHANGES
 from repro.core.prox import soft_threshold
 from repro.core import selection, stepsize
 from repro.core.result import SolverResult
+from repro.launch.mesh import make_mesh
+from repro.problems.base import mv
 
 
 class PFlexaState(NamedTuple):
@@ -114,7 +116,7 @@ def make_sharded_step(mesh: Mesh, axis: str, c: float, cfg: SolverConfig,
     def local_step(A_loc, colsq_loc, b, state: PFlexaState):
         x, r = state.x, state.r
         tau = tau0 * state.tau_scale
-        g_loc = 2.0 * (A_loc.T @ r)                      # ∇ᵢF, local columns
+        g_loc = 2.0 * mv(A_loc.T, r)                     # ∇ᵢF, local columns
         d_loc = tau + 2.0 * colsq_loc                    # surrogate (6)
         z_loc = soft_threshold(x - g_loc / d_loc, c / d_loc)
 
@@ -125,11 +127,11 @@ def make_sharded_step(mesh: Mesh, axis: str, c: float, cfg: SolverConfig,
         dx_loc = state.gamma * mask * (z_loc - x)
         x_new = x + dx_loc
         # Residual carry: r ← r + A·Δx (one matvec + one psum).
-        r_new = r + jax.lax.psum(A_loc @ dx_loc, axis)
+        r_new = r + jax.lax.psum(mv(A_loc, dx_loc), axis)
 
         # Objective at the new point (no extra matvec thanks to the carry).
         g_abs = jax.lax.psum(jnp.sum(jnp.abs(x_new)), axis)
-        v_new = jnp.dot(r_new, r_new) + c * g_abs
+        v_new = mv(r_new, r_new) + c * g_abs
 
         can_change = state.n_tau_changes < MAX_TAU_CHANGES
         adapt = bool(cfg.tau_adapt)
@@ -181,7 +183,7 @@ def solve(A, b, c: float, cfg: SolverConfig | None = None,
     """
     cfg = cfg or SolverConfig()
     if mesh is None:
-        mesh = jax.make_mesh((len(jax.devices()),), (axis,))
+        mesh = make_mesh((len(jax.devices()),), (axis,))
     p = int(np.prod(mesh.devices.shape))
 
     A_np = np.asarray(A, np.float32)
@@ -208,8 +210,8 @@ def solve(A, b, c: float, cfg: SolverConfig | None = None,
         x0 = jnp.concatenate([jnp.asarray(x0, jnp.float32),
                               jnp.zeros((pad,), jnp.float32)])
     x0 = jax.device_put(x0, col_sharding)
-    r0 = A_dev @ x0 - b_dev
-    v0 = jnp.dot(r0, r0) + c * jnp.sum(jnp.abs(x0))
+    r0 = mv(A_dev, x0) - b_dev
+    v0 = mv(r0, r0) + c * jnp.sum(jnp.abs(x0))
 
     state = PFlexaState(
         x=x0, r=r0,

@@ -37,10 +37,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.config.base import ModelConfig
-from repro.compat import shard_map
 from repro.models import layers as L
 from repro.models import transformer as T
 

@@ -40,7 +40,26 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK = (256, 512)  # 256×512 fp32 ≈ 0.5 MB/operand — comfortably VMEM
 
 
-def _br_kernel(x_ref, g_ref, d_ref, c_ref, z_ref, e2_ref, *, scalar_d: bool):
+def _tile_valid(shape, rows: int, cols: int):
+    """Mask of the in-bounds entries of the current (br, bc) tile: edge
+    tiles of a grid that does not divide (R, C) read padding, which must
+    not reach a reduction."""
+    br, bc = shape
+    r = pl.program_id(0) * br + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    c = pl.program_id(1) * bc + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (r < rows) & (c < cols)
+
+
+def _partial_tile(value):
+    """An (8, 128) output tile holding ``value`` at [0, 0] and zeros
+    elsewhere, so summing every tile adds each partial exactly once."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    return jnp.where((r == 0) & (c == 0), value, 0.0)
+
+
+def _br_kernel(x_ref, g_ref, d_ref, c_ref, z_ref, e2_ref, *, scalar_d: bool,
+               rows: int, cols: int):
     x = x_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
     d = d_ref[0, 0] if scalar_d else d_ref[...].astype(jnp.float32)
@@ -49,13 +68,15 @@ def _br_kernel(x_ref, g_ref, d_ref, c_ref, z_ref, e2_ref, *, scalar_d: bool):
     t = c / d
     z = jnp.sign(w) * jnp.maximum(jnp.abs(w) - t, 0.0)
     z_ref[...] = z
-    e2_ref[0, 0] = jnp.sum((z - x) ** 2)
+    sq = jnp.where(_tile_valid(x.shape, rows, cols), (z - x) ** 2, 0.0)
+    e2_ref[...] = _partial_tile(jnp.sum(sq, keepdims=True))
 
 
 def best_response(x, g, d, c, *, block=DEFAULT_BLOCK, interpret: bool = False):
     """x, g: (R, C) 2-D views. d: scalar () or (R, C). c: scalar ().
 
-    Returns (z fp32 (R,C), e2 fp32 scalar).
+    Returns (z fp32 (R,C), e2 fp32 scalar).  Scalars ride in SMEM; each
+    tile's Eᵢ² partial lands in its own (8, 128) output tile.
     """
     R, C = x.shape
     br, bc = min(block[0], R), min(block[1], C)
@@ -64,24 +85,24 @@ def best_response(x, g, d, c, *, block=DEFAULT_BLOCK, interpret: bool = False):
     d_arr = jnp.asarray(d, jnp.float32).reshape(1, 1) if scalar_d else d
     c_arr = jnp.asarray(c, jnp.float32).reshape(1, 1)
 
-    d_spec = (pl.BlockSpec((1, 1), lambda i, j: (0, 0)) if scalar_d
-              else pl.BlockSpec((br, bc), lambda i, j: (i, j)))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    d_spec = smem if scalar_d else pl.BlockSpec((br, bc), lambda i, j: (i, j))
     z, e2p = pl.pallas_call(
-        partial(_br_kernel, scalar_d=scalar_d),
+        partial(_br_kernel, scalar_d=scalar_d, rows=R, cols=C),
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, bc), lambda i, j: (i, j)),
             pl.BlockSpec((br, bc), lambda i, j: (i, j)),
             d_spec,
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            smem,
         ],
         out_specs=[
             pl.BlockSpec((br, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((8, 128), lambda i, j: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, C), jnp.float32),
-            jax.ShapeDtypeStruct(grid, jnp.float32),
+            jax.ShapeDtypeStruct((grid[0] * 8, grid[1] * 128), jnp.float32),
         ],
         interpret=interpret,
     )(x, g, d_arr, c_arr)
@@ -258,21 +279,53 @@ def batched_apply_update(x, g, d, c, gamma_mask, *, block=DEFAULT_BLOCK,
 # These kernels move whole *block rows* between the full layout (N rows)
 # and the compact layout (K = capacity rows).  The row index array rides
 # in scalar-prefetch memory (`PrefetchScalarGridSpec`): BlockSpec index
-# maps read it to pick each tile's source row, so the gather is a pure
-# DMA pattern — no in-kernel address arithmetic, one row tile per grid
-# step.  Index −1 marks unused capacity (gather) or an inactive
-# destination (scatter); −1 clamps to row 0 for the DMA and the kernel
-# body masks the value, so padded work is read-only and algebraically
-# inert.  Column tiling assumes C is a multiple of the block width —
-# ``ops.py`` zero-pads ragged layouts before dispatch (zero columns are
-# inert for gather, scatter and the fused prox alike).
+# maps read it to pick each tile's source.  A TPU tile is 8 rows deep, so
+# one row cannot be a block of its own: each grid step writes an (8, bc)
+# output tile, and fetches, for each of its 8 rows, the aligned 8-row
+# source tile holding that row (8 block specs over the same array), then
+# copies the one row it needs.  Index −1 marks unused capacity (gather)
+# or an inactive destination (scatter); −1 clamps to row 0 for the DMA
+# and the kernel body masks the value, so padded work is read-only and
+# algebraically inert.  Index vectors are padded with −1 to a multiple of
+# 8; ragged row and column edges are clipped by the grid.  ``ops.py``
+# zero-pads C to a lane multiple before dispatch (zero columns are inert
+# for gather, scatter and the fused prox alike).
 COMPACT_BLOCK_C = 512
+_ROWS = 8                       # sublanes per TPU tile
 
 
-def _gather_kernel(idx_ref, src_ref, out_ref):
-    i = pl.program_id(0)
-    valid = (idx_ref[i] >= 0).astype(jnp.float32)
-    out_ref[...] = src_ref[...].astype(jnp.float32) * valid
+def _pad_index(idx, mult: int = _ROWS):
+    idx = jnp.asarray(idx, jnp.int32)
+    pad = (-idx.shape[0]) % mult
+    if pad:
+        idx = jnp.concatenate([idx, jnp.full((pad,), -1, jnp.int32)])
+    return idx
+
+
+def _row_specs(bc: int):
+    """The 8 block specs that fetch, for output row r of grid step g, the
+    aligned source tile holding row ``idx[8g + r]``."""
+    def spec(r):
+        return pl.BlockSpec(
+            (_ROWS, bc),
+            lambda g, j, idx_ref: (
+                jnp.maximum(idx_ref[g * _ROWS + r], 0) // _ROWS, j))
+    return [spec(r) for r in range(_ROWS)]
+
+
+def _picked_row(idx_ref, src_refs, r):
+    """(index, row) for output row r of this grid step: the source row
+    ``idx[8g + r]`` read out of its aligned tile."""
+    i = idx_ref[pl.program_id(0) * _ROWS + r]
+    return i, src_refs[r][pl.ds(jnp.maximum(i, 0) % _ROWS, 1), :]
+
+
+def _gather_kernel(idx_ref, *refs):
+    src_refs, out_ref = refs[:_ROWS], refs[_ROWS]
+    for r in range(_ROWS):
+        i, row = _picked_row(idx_ref, src_refs, r)
+        out_ref[pl.ds(r, 1), :] = jnp.where(i >= 0, row.astype(jnp.float32),
+                                            0.0)
 
 
 def gather_rows(src, idx, *, block_c: int = COMPACT_BLOCK_C,
@@ -283,26 +336,28 @@ def gather_rows(src, idx, *, block_c: int = COMPACT_BLOCK_C,
     """
     N, C = src.shape
     K = idx.shape[0]
+    idx = _pad_index(idx)
     bc = min(block_c, C)
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(K, pl.cdiv(C, bc)),
-        in_specs=[pl.BlockSpec(
-            (1, bc), lambda i, j, idx_ref: (jnp.maximum(idx_ref[i], 0), j))],
-        out_specs=pl.BlockSpec((1, bc), lambda i, j, idx_ref: (i, j)),
+        grid=(idx.shape[0] // _ROWS, pl.cdiv(C, bc)),
+        in_specs=_row_specs(bc),
+        out_specs=pl.BlockSpec((_ROWS, bc), lambda g, j, idx_ref: (g, j)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel, grid_spec=gs,
-        out_shape=jax.ShapeDtypeStruct((K, C), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((idx.shape[0], C), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(idx, jnp.int32), src)
+    )(idx, *([src] * _ROWS))
+    return out[:K]
 
 
-def _scatter_kernel(inv_ref, vals_ref, base_ref, out_ref):
-    i = pl.program_id(0)
-    valid = inv_ref[i] >= 0
-    out_ref[...] = jnp.where(valid, vals_ref[...].astype(out_ref.dtype),
-                             base_ref[...])
+def _scatter_kernel(inv_ref, *refs):
+    vals_refs, base_ref, out_ref = refs[:_ROWS], refs[_ROWS], refs[_ROWS + 1]
+    for r in range(_ROWS):
+        i, row = _picked_row(inv_ref, vals_refs, r)
+        out_ref[pl.ds(r, 1), :] = jnp.where(
+            i >= 0, row.astype(out_ref.dtype), base_ref[pl.ds(r, 1), :])
 
 
 def scatter_rows(vals, inv, base, *, block_c: int = COMPACT_BLOCK_C,
@@ -314,23 +369,20 @@ def scatter_rows(vals, inv, base, *, block_c: int = COMPACT_BLOCK_C,
     permutation, so every output row is written exactly once.
     """
     N, C = base.shape
+    inv = _pad_index(inv)
     bc = min(block_c, C)
+    tile = pl.BlockSpec((_ROWS, bc), lambda g, j, inv_ref: (g, j))
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(N, pl.cdiv(C, bc)),
-        in_specs=[
-            pl.BlockSpec(
-                (1, bc),
-                lambda i, j, inv_ref: (jnp.maximum(inv_ref[i], 0), j)),
-            pl.BlockSpec((1, bc), lambda i, j, inv_ref: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bc), lambda i, j, inv_ref: (i, j)),
+        grid=(inv.shape[0] // _ROWS, pl.cdiv(C, bc)),
+        in_specs=_row_specs(bc) + [tile],
+        out_specs=tile,
     )
     return pl.pallas_call(
         _scatter_kernel, grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((N, C), base.dtype),
         interpret=interpret,
-    )(jnp.asarray(inv, jnp.int32), vals, base)
+    )(inv, *([vals] * _ROWS), base)
 
 
 def _compact_br_kernel(idx_ref, x_ref, g_ref, d_ref, c_ref, z_ref, e2_ref,

@@ -218,8 +218,12 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, force=None):
     if mode == "ref":
         y, h = ref.ssd_scan_ref(x, dt, A, B, C, chunk=chunk)
     else:
-        y, h = _ssd.ssd_scan(x, dt, A, B, C, chunk=chunk,
+        # Head-major for the kernel: the sequence chunk becomes the
+        # tiled (second-minor) dimension of every block.
+        y, h = _ssd.ssd_scan(x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1),
+                             A, B, C, chunk=chunk,
                              interpret=(mode == "interpret"))
+        y = y.transpose(0, 2, 1, 3)
     return (y[:, :S] if pad else y), h
 
 

@@ -24,9 +24,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.config.base import ModelConfig
-from repro.compat import shard_map
 from repro.models import layers as L
 
 

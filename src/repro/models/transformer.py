@@ -25,10 +25,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.config.base import ModelConfig
-from repro.compat import shard_map
 from repro.models import attention as ATT
 from repro.models import layers as L
 from repro.models import moe as MOE

@@ -11,9 +11,21 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.prox import group_soft_threshold, soft_threshold
+
+
+def mv(a, b):
+    """``a @ b`` at float32 accuracy on every backend.
+
+    XLA on TPU runs a float32 product as one bfloat16 pass unless told
+    otherwise (about three significant digits), which the solvers'
+    float32 equivalence contracts cannot absorb.  Every product of a
+    design matrix goes through here.
+    """
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 @dataclass

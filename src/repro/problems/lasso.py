@@ -10,7 +10,7 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
-from repro.problems.base import Problem
+from repro.problems.base import Problem, mv
 
 
 def quadratic_fns(A, b, col_sq=None):
@@ -26,11 +26,11 @@ def quadratic_fns(A, b, col_sq=None):
         col_sq = jnp.sum(A * A, axis=0)      # ‖aᵢ‖² per column
 
     def f(x):
-        r = A @ x - b
-        return jnp.dot(r, r)
+        r = mv(A, x) - b
+        return mv(r, r)
 
     def grad_f(x):
-        return 2.0 * (A.T @ (A @ x - b))
+        return 2.0 * mv(A.T, mv(A, x) - b)
 
     def diag_curv(_):
         return 2.0 * col_sq

@@ -12,7 +12,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.problems.base import Problem
+from repro.problems.base import Problem, mv
 from repro.problems.lasso import _power_iter_sq
 
 
@@ -27,14 +27,14 @@ def logistic_fns(Z, col_sq=None):
         col_sq = jnp.sum(Z * Z, axis=0)
 
     def f(x):
-        t = Z @ x
+        t = mv(Z, x)
         # log(1+e^{−t}) computed stably
         return jnp.sum(jnp.logaddexp(0.0, -t))
 
     def grad_f(x):
-        t = Z @ x
+        t = mv(Z, x)
         sig = jax.nn.sigmoid(-t)       # = e^{−t}/(1+e^{−t})
-        return -(Z.T @ sig)
+        return -mv(Z.T, sig)
 
     def diag_curv(x):
         # Global bound: σ(t)σ(−t) ≤ 1/4  ⇒  diag(∇²F) ≤ 0.25·Σ zⱼᵢ².

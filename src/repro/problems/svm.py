@@ -11,7 +11,7 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
-from repro.problems.base import Problem
+from repro.problems.base import Problem, mv
 from repro.problems.lasso import _power_iter_sq
 
 
@@ -25,12 +25,12 @@ def squared_hinge_fns(Z, col_sq=None):
         col_sq = jnp.sum(Z * Z, axis=0)
 
     def f(x):
-        h = jnp.maximum(0.0, 1.0 - Z @ x)
-        return jnp.dot(h, h)
+        h = jnp.maximum(0.0, 1.0 - mv(Z, x))
+        return mv(h, h)
 
     def grad_f(x):
-        h = jnp.maximum(0.0, 1.0 - Z @ x)
-        return -2.0 * (Z.T @ h)
+        h = jnp.maximum(0.0, 1.0 - mv(Z, x))
+        return -2.0 * mv(Z.T, h)
 
     def diag_curv(x):
         return 2.0 * col_sq

@@ -32,7 +32,10 @@ sweep every tick, so a past-deadline request is evicted as
 telemetry counted) whether it was still queued or already in a slot.
 
 On startup the server prints ``READY port=<N>`` on stdout — the
-subprocess handshake the smoke benchmark and CI wait for.
+subprocess handshake CI waits for.  :class:`InProcessServer` runs the
+same service on a thread of the calling process: a process that already
+holds the chip serves from itself, since a child server could not get
+the device.
 """
 from __future__ import annotations
 
@@ -42,6 +45,7 @@ import dataclasses
 import json
 import signal
 import sys
+import threading
 
 from repro.client.errors import ClientError
 from repro.client.specs import normalize
@@ -305,7 +309,10 @@ def build_service(args) -> SolverService:
                          tick_idle_s=args.tick_idle)
 
 
-async def serve(args) -> int:
+async def serve(args, on_ready=None) -> int:
+    """Serve until drained.  ``on_ready(port, loop, service)`` is called
+    once the socket listens.  SIGTERM/SIGINT begin the drain when the
+    server owns the main thread (only there can it install handlers)."""
     service = build_service(args)
     front = _HTTPFront(service)
     server = await asyncio.start_server(front.handle, args.host,
@@ -314,8 +321,11 @@ async def serve(args) -> int:
     print(f"READY port={port}", flush=True)
 
     loop = asyncio.get_running_loop()
-    for sig in (signal.SIGTERM, signal.SIGINT):
-        loop.add_signal_handler(sig, service.begin_drain)
+    if threading.current_thread() is threading.main_thread():
+        for sig in (signal.SIGTERM, signal.SIGINT):
+            loop.add_signal_handler(sig, service.begin_drain)
+    if on_ready is not None:
+        on_ready(port, loop, service)
 
     tick = asyncio.create_task(service.tick_loop())
     # Wait for a drain request, then for in-flight work to finish.
@@ -332,7 +342,55 @@ async def serve(args) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+class InProcessServer:
+    """The service on a daemon thread of this process.
+
+    ``argv`` takes the command-line options of ``python -m
+    repro.remote.server``; the port is picked free.
+    """
+
+    def __init__(self, argv=(), *, start_timeout_s: float = 60.0):
+        args = _parser().parse_args(["--port", "0", *argv])
+        self.exit_code: int | None = None
+        self.error: BaseException | None = None
+        self._ready = threading.Event()
+        self._thread = threading.Thread(target=self._run, args=(args,),
+                                        name="repro-remote-server",
+                                        daemon=True)
+        self._thread.start()
+        if not self._ready.wait(start_timeout_s) or self.error is not None:
+            raise RuntimeError(f"in-process server failed to start: "
+                               f"{self.error!r}")
+
+    def _run(self, args) -> None:
+        try:
+            self.exit_code = asyncio.run(serve(args, self._on_ready))
+        except Exception as e:              # noqa: BLE001 — surfaced
+            self.error = e                  # to the owning thread
+        finally:
+            self._ready.set()
+
+    def _on_ready(self, port, loop, service) -> None:
+        self.url = f"http://127.0.0.1:{port}"
+        self.service = service
+        self._loop = loop
+        self._ready.set()
+
+    def begin_drain(self) -> None:
+        """The SIGTERM path: stop admitting, finish in-flight work."""
+        self._loop.call_soon_threadsafe(self.service.begin_drain)
+
+    def join(self, timeout_s: float = 300.0) -> int:
+        """Wait for the drained server to exit; returns its exit code."""
+        self._thread.join(timeout_s)
+        if self._thread.is_alive():
+            raise TimeoutError(f"server did not drain in {timeout_s} s")
+        if self.error is not None:
+            raise RuntimeError("in-process server failed") from self.error
+        return self.exit_code
+
+
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         prog="python -m repro.remote.server",
         description="FLEXA solver service (HTTP/JSON front door over "
@@ -367,7 +425,14 @@ def main(argv=None) -> int:
     ap.add_argument("--telemetry-out", default="",
                     help="write the final telemetry snapshot JSON "
                          "here on drain")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None) -> int:
+    from repro.launch.runtime import use_compile_cache
+
+    args = _parser().parse_args(argv)
+    use_compile_cache()
     try:
         return asyncio.run(serve(args))
     except KeyboardInterrupt:

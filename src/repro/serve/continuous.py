@@ -50,6 +50,7 @@ import time
 from dataclasses import dataclass
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.config.base import ServeConfig, SolverConfig
@@ -160,7 +161,7 @@ class _SlotSlab:
         self._health_cfg = HealthConfig.of(serve)
         self.telemetry = telemetry
         self.queue = AdmissionQueue(serve.policy)
-        self.slab = slab_alloc(spec, cfg, self.capacity)
+        self.slab = self._to_device(slab_alloc(spec, cfg, self.capacity))
         self._health_carry = self._fresh_health(self.capacity)
         self._chunk = self._make_chunk()
         # warm_from resolver: req_id -> finished solution (None = still
@@ -205,14 +206,17 @@ class _SlotSlab:
         # buffers _stage() mutates — same race class as the per-tick
         # payload below, just waiting for a code path that reads the
         # initial payload after an admission.
-        self._payload = (
-            tuple(jnp.asarray(a.copy()) for a in self._stage_data),
-            jnp.asarray(self._stage_c.copy()),
-            jnp.asarray(self._stage_x0.copy()),
-            jnp.asarray(self._stage_ids.copy()),
-            jnp.asarray(self._stage_active.copy()),
-            jnp.asarray(self._stage_tol.copy()))
-        self._no_admit = jnp.zeros(S, bool)
+        self._payload = self._stage_payload()
+        self._no_admit = self._to_device(np.zeros(S, bool))
+
+    def _stage_payload(self):
+        """The staging buffers as device arrays (copies — see the
+        .copy() note in :meth:`step`)."""
+        return self._to_device((
+            tuple(a.copy() for a in self._stage_data),
+            self._stage_c.copy(), self._stage_x0.copy(),
+            self._stage_ids.copy(), self._stage_active.copy(),
+            self._stage_tol.copy()))
 
     def _fresh_health(self, capacity: int):
         """Device-resident per-slot health carry ``(prev_stat, stall)``
@@ -221,10 +225,16 @@ class _SlotSlab:
         watchdog is off."""
         if self._health_cfg is None:
             return None
-        return (jnp.full((capacity,), jnp.inf, jnp.float32),
-                jnp.zeros((capacity,), jnp.int32))
+        return self._to_device((np.full((capacity,), np.inf, np.float32),
+                                np.zeros((capacity,), np.int32)))
 
-    # -- subclass hooks (the mesh slab reshapes both) -------------- #
+    # -- subclass hooks (the mesh slab reshapes and places) --------- #
+    def _to_device(self, tree):
+        """Place host (or device) arrays where the chunk program reads
+        them: the default device.  The mesh slab shards the slot axis
+        over its devices instead."""
+        return jax.tree_util.tree_map(jnp.asarray, tree)
+
     def _slab_capacity(self, serve: ServeConfig) -> int:
         return serve.slab_capacity
 
@@ -433,14 +443,8 @@ class _SlotSlab:
         # async chunk dispatch (observed as admissions silently reading
         # all-False masks under load).
         if self._admit.any():
-            self._payload = (
-                tuple(jnp.asarray(a.copy()) for a in self._stage_data),
-                jnp.asarray(self._stage_c.copy()),
-                jnp.asarray(self._stage_x0.copy()),
-                jnp.asarray(self._stage_ids.copy()),
-                jnp.asarray(self._stage_active.copy()),
-                jnp.asarray(self._stage_tol.copy()))
-            admit = jnp.asarray(self._admit.copy())
+            self._payload = self._stage_payload()
+            admit = self._to_device(self._admit.copy())
             self._admit[:] = False
         else:
             admit = self._no_admit
@@ -451,7 +455,7 @@ class _SlotSlab:
                       chunk_iters=self.chunk_iters):
             if self._health_cfg is None:
                 self.slab, stop_dev = self._chunk(
-                    self.slab, jnp.asarray(self.stop.copy()), admit,
+                    self.slab, self._to_device(self.stop.copy()), admit,
                     new_data, new_c, new_x0, new_ids, new_active,
                     new_tol)
                 # The one per-chunk host sync (copy: host mirror is
@@ -464,7 +468,7 @@ class _SlotSlab:
                 # verdict vector (0=running / 1=stopped / 2=diverged /
                 # 3=stalled).  The health carry stays device-resident.
                 self.slab, status_dev, prev_stat, stall = self._chunk(
-                    self.slab, jnp.asarray(self.stop.copy()), admit,
+                    self.slab, self._to_device(self.stop.copy()), admit,
                     new_data, new_c, new_x0, new_ids, new_active,
                     new_tol, *self._health_carry)
                 self._health_carry = (prev_stat, stall)

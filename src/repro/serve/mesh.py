@@ -9,9 +9,7 @@ runtime across a device mesh" — built from the same parts:
   (:func:`repro.solvers.batched.make_sharded_chunk_stepper`): device d
   owns the contiguous slot block ``[d·S_dev, (d+1)·S_dev)`` and advances
   it with the *identical* per-slot math — the chunk core is
-  collective-free, so sharding adds no communication and no
-  ``axis_index`` (the jax<0.6 PartitionId lowering bug that parks
-  ``tests/test_pipeline.py`` is structurally unreachable here);
+  collective-free, so sharding adds no communication;
 * admission becomes two-level: the engine's shared policy-ordered
   :class:`~repro.serve.continuous.AdmissionQueue` feeds per-device
   queues through a routing policy (``ServeConfig.mesh_routing``), and
@@ -54,11 +52,13 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.config.base import ServeConfig, SolverConfig
 from repro.obs import trace as obs
 from repro.serve.continuous import (AdmissionQueue, ContinuousSolverEngine,
                                     QueueEntry, _SlotSlab)
+from repro.launch.mesh import make_mesh
 from repro.serve.metrics import MeshTelemetry
 from repro.solvers.batched import (BatchedProblemSpec,
                                    make_sharded_chunk_stepper)
@@ -124,6 +124,8 @@ class _MeshSlab(_SlotSlab):
         # The hooks below read these, and super().__init__ calls them.
         self.n_devices = int(n_devices)
         self.per_device_capacity = int(serve.slab_capacity)
+        self._rows = NamedSharding(make_mesh((self.n_devices,), ("serve",)),
+                                   PartitionSpec("serve"))
         super().__init__(spec, cfg, serve, telemetry,
                          resolve_x0=resolve_x0, deadline_of=deadline_of)
         self.routing = serve.mesh_routing
@@ -134,6 +136,11 @@ class _MeshSlab(_SlotSlab):
         self.steal_log = steal_log
 
     # -- hook overrides ------------------------------------------- #
+    def _to_device(self, tree):
+        # Straight to each device's slot block: no full-slab copy on
+        # device 0 on the way.
+        return jax.device_put(tree, self._rows)
+
     def _slab_capacity(self, serve: ServeConfig) -> int:
         return self.n_devices * self.per_device_capacity
 

@@ -540,9 +540,7 @@ def _build_sharded_chunk_stepper(spec: BatchedProblemSpec,
     of the ``n_devices`` mesh devices advances its own contiguous block
     of ``S / n_devices`` slots.  The core is collective-free (per-slot
     vmap + masked selects; no ``axis_index``, no cross-slot reductions),
-    so the sharded program needs no communication and — crucially on
-    jax < 0.6 — never trips the partial-manual ``axis_index`` →
-    PartitionId lowering bug that parks ``tests/test_pipeline.py``.
+    so the sharded program needs no communication.
 
     The slab capacity S must be divisible by ``n_devices`` (the engine
     allocates S = n_devices × per-device capacity).  Slab and stop mask
@@ -550,10 +548,10 @@ def _build_sharded_chunk_stepper(spec: BatchedProblemSpec,
     """
     from jax.sharding import PartitionSpec
 
-    from repro.compat import shard_map
+    from repro.launch.mesh import make_mesh
 
     core = _chunk_core(spec, cfg, chunk_iters, health)
-    mesh = jax.make_mesh((int(n_devices),), ("serve",))
+    mesh = make_mesh((int(n_devices),), ("serve",))
     row = PartitionSpec("serve")       # shard dim 0, replicate the rest
     slab_specs = SlabState(
         data=tuple(row for _ in slab_data_shapes(spec)),
@@ -570,8 +568,8 @@ def _build_sharded_chunk_stepper(spec: BatchedProblemSpec,
         # everything else; the verdict replaces the stop mask output.
         in_specs = (slab_specs, row, row) + payload_specs + (row, row)
         out_specs = (slab_specs, row, row, row)
-    sharded = shard_map(core, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_vma=False)
+    sharded = jax.shard_map(core, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
 
     if health is None:
         @partial(jax.jit, donate_argnums=(0, 1))

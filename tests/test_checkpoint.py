@@ -74,9 +74,10 @@ ELASTIC_SRC = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.checkpoint import Checkpointer
+    from repro.launch.mesh import make_mesh
 
     ckdir = sys.argv[1]
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     sh = {"w": NamedSharding(mesh, P("data", None)),
           "nested": {"b": NamedSharding(mesh, P()),
                      "s": NamedSharding(mesh, P())}}

@@ -77,9 +77,11 @@ MASK_CASES = [
 
 def _masks():
     if HAVE_HYPOTHESIS:
-        return settings(max_examples=60, deadline=None)(given(
+        strategies = given(
             st.lists(st.booleans(), min_size=1, max_size=40)
-            .map(lambda bs: np.asarray(bs, bool))))
+            .map(lambda bs: np.asarray(bs, bool)))
+        return lambda f: settings(max_examples=60, deadline=None)(
+            strategies(f))
     return pytest.mark.parametrize("mask", MASK_CASES)
 
 

@@ -23,7 +23,8 @@ RNG = np.random.default_rng(0)
 # flexa_prox                                                         #
 # ------------------------------------------------------------------ #
 @pytest.mark.parametrize("shape", [(8,), (130,), (33, 7), (4, 5, 6),
-                                   (1024,), (257, 3)])
+                                   (1024,), (257, 3),
+                                   (700, 900)])    # ragged multi-tile grid
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("c", [0.0, 0.3])
 def test_flexa_best_response_sweep(shape, dtype, c):
@@ -212,6 +213,8 @@ def _plan_arrays(n_rows, k_active, seed):
     (16, 5, 200),                   # ragged C (pad-to-128 path)
     (8, 8, 37),                     # everything active, tiny ragged C
     (12, 1, 128),
+    (37, 11, 300),                  # rows and capacity off the 8-row tile
+    (1000, 700, 600),               # several column tiles, ragged edge
 ])
 def test_gather_scatter_blocks_sweep(n_rows, k, C):
     idx, inv = _plan_arrays(n_rows, k, seed=n_rows + k + C)

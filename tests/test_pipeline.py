@@ -5,18 +5,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
-
-# The GPipe path keeps `model` *auto* inside a partial-manual shard_map;
-# jaxlib < 0.6 lowers lax.axis_index there to a PartitionId instruction the
-# SPMD partitioner rejects (see ROADMAP "Open items").  The test *runs* and
-# xfails only on that exact compiler rejection — so the skip can never go
-# stale: a jax/jaxlib bump that fixes the lowering flips this to PASSED
-# with no edit here, a bump that still rejects keeps the precise record of
-# the failing instruction, and any OTHER failure is a real failure.
-_PARTITION_ID_REJECTION = (
-    "PartitionId instruction is not supported for SPMD partitioning")
 
 SRC = textwrap.dedent("""
     import os, json
@@ -27,9 +16,10 @@ SRC = textwrap.dedent("""
     from repro.config.base import ShapeConfig
     from repro.distributed.sharding import Dist
     from repro.distributed.pipeline import pipeline_loss_fn
+    from repro.launch.mesh import make_mesh
     from repro.models import transformer as T, io as IO
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     dist = Dist(mesh=mesh, dp_axes=("data",))
     cfg = get_reduced("yi-6b").replace(num_layers=4)
     params = T.init_params(cfg, jax.random.PRNGKey(0))
@@ -75,12 +65,6 @@ def test_pipeline_and_zero3_match_reference():
                          capture_output=True, text=True, env=env,
                          cwd=os.path.dirname(os.path.dirname(__file__)),
                          timeout=560)
-    if out.returncode != 0 and _PARTITION_ID_REJECTION in out.stderr:
-        line = next(l for l in out.stderr.splitlines()
-                    if _PARTITION_ID_REJECTION in l)
-        pytest.xfail(
-            f"jax {jax.__version__}: partial-manual shard_map still "
-            f"lowers lax.axis_index to a rejected op — {line.strip()}")
     assert out.returncode == 0, out.stderr[-3000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert abs(rec["pp"] - rec["ref"]) < 5e-3          # bf16 schedule noise

@@ -365,3 +365,24 @@ def test_remote_sigterm_drains_gracefully(tmp_path):
     # Post-drain: new submissions are refused (server gone).
     with pytest.raises(ClientError):
         c.submit(SoloSpec(problem=_instance(seed=3)))
+
+
+def test_in_process_server_matches_continuous_and_drains(capsys):
+    """One process per chip: the service on a thread of the caller
+    answers exactly what the in-process continuous backend answers, and
+    its drain returns exit code 0 (no signal handlers off the main
+    thread)."""
+    from repro.config.base import ServeConfig
+    from repro.remote.server import InProcessServer
+
+    server = InProcessServer(SERVER_ARGS)
+    try:
+        got = _remote(server.url).run(SoloSpec(problem=_instance("lasso")))
+    finally:
+        server.begin_drain()
+        code = server.join(timeout_s=60)
+    ref = FlexaClient(backend="continuous", solver=CFG,
+                      serve=ServeConfig(slab_capacity=8, chunk_iters=16)) \
+        .run(SoloSpec(problem=_instance("lasso")))
+    np.testing.assert_array_equal(np.asarray(got.x), np.asarray(ref.x))
+    assert code == 0 and "DRAINED" in capsys.readouterr().out

@@ -42,10 +42,12 @@ SEEDS = (0, 1, 2)
 
 def _es():
     if HAVE_HYPOTHESIS:
-        return settings(max_examples=40, deadline=None)(given(
+        strategies = given(
             st.lists(st.floats(0, 100, allow_nan=False), min_size=2,
                      max_size=64),
-            st.sampled_from(RHOS), st.sampled_from(SEEDS)))
+            st.sampled_from(RHOS), st.sampled_from(SEEDS))
+        return lambda f: settings(max_examples=40, deadline=None)(
+            strategies(f))
     return pytest.mark.parametrize(
         "vals,rho,seed",
         [(e, r, s) for e in E_CASES for r in RHOS for s in SEEDS[:1]])

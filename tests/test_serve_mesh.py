@@ -59,9 +59,11 @@ LOAD_CASES = [[0], [0, 0, 0], [3, 1, 2], [5, 5, 5, 5], [2, 0, 0, 7],
 
 def _loads():
     if HAVE_HYPOTHESIS:
-        return settings(max_examples=60, deadline=None)(given(
+        strategies = given(
             st.lists(st.integers(0, 20), min_size=1, max_size=8),
-            st.integers(0, 100)))
+            st.integers(0, 100))
+        return lambda f: settings(max_examples=60, deadline=None)(
+            strategies(f))
     return pytest.mark.parametrize(
         "loads,cursor", [(l, c) for l in LOAD_CASES for c in (0, 3, 17)])
 
@@ -99,9 +101,11 @@ QLEN_CASES = [([0, 0, 0], 0, 1), ([4, 0, 2], 1, 1), ([4, 0, 2], 0, 1),
 
 def _qlens():
     if HAVE_HYPOTHESIS:
-        return settings(max_examples=60, deadline=None)(given(
+        strategies = given(
             st.lists(st.integers(0, 9), min_size=1, max_size=8),
-            st.integers(0, 7), st.integers(1, 4)))
+            st.integers(0, 7), st.integers(1, 4))
+        return lambda f: settings(max_examples=60, deadline=None)(
+            strategies(f))
     return pytest.mark.parametrize("qlens,thief,threshold", QLEN_CASES)
 
 
