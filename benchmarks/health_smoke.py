@@ -18,11 +18,6 @@ fault injections, gating the quarantine contract deterministically
   solutions, iteration counts and audit tick numbers.
 * **Conservation**: telemetry quarantine counters equal the engine's
   typed ``SolveFailure`` list, split by status.
-
-The artifact feeds the perf-history tracker (``repro.obs.history``):
-``nan.quarantine_tick`` / ``stall.quarantine_tick`` are gated history
-metrics — a scheduler change that delays quarantine shows up as a
-regression.
 """
 import argparse
 import json

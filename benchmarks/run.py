@@ -24,9 +24,7 @@ the CV-over-serve scenario), ``BENCH_compaction.json``
 (``compaction_bench.main`` — masked-dense vs capacity-bucketed compacted
 execution) and ``BENCH_health.json`` (``health_smoke.main`` —
 numerical-health watchdog fault-injection gates).  ``--skip-serve`` /
-``--skip-path`` / ``--skip-lm`` drop the slower sections.  ``--gate``
-additionally appends the run's key metrics to the persistent perf
-history (``results/bench/history.jsonl``, see ``repro.obs.history``).
+``--skip-path`` / ``--skip-lm`` drop the slower sections.
 """
 from __future__ import annotations
 
@@ -203,17 +201,6 @@ def main() -> None:
         for r in lm_step.main():
             print(f"lm_step/{r['arch']},{r['train_us']},"
                   f"decode_us={r['decode_us']}")
-
-    if args.gate:
-        # Persist this gated run's key metrics to the perf history
-        # (append even on failure — regressions should be visible in
-        # the record stream, not erased by the gate).
-        from repro.obs import history as obs_history
-        bench_dir = Path(__file__).resolve().parent.parent / "results" / "bench"
-        record = obs_history.collect(bench_dir, smoke=args.smoke)
-        obs_history.append(record, bench_dir / "history.jsonl")
-        print(f"history,0,appended {len(record['metrics'])} metrics "
-              f"sha={record['git_sha'][:12]}")
 
     if args.gate and failures:
         raise SystemExit(f"acceptance failed: {failures}")

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from repro.config.base import SolverConfig
 from repro.core import selection, stepsize
 from repro.core.surrogate import best_response, curvature
+from repro.obs import trace as obs
 from repro.problems.base import Problem
 from repro.core.result import SolverResult
 
@@ -117,68 +118,81 @@ def flexa_iteration(problem: Problem, cfg: SolverConfig,
     keeps its full fixed shape.  ``None`` (the default) is bit-identical
     to the unmasked iteration; a mask of all-ones multiplies by exact
     fp32 1.0s, so it is bit-identical too.
+
+    Each step runs under a ``jax.named_scope`` (``grad``,
+    ``best_response``, ``select``, ``update``, ``objective``, ``tau``):
+    the scope names the step in the compiled operations' metadata, which
+    a profiler trace carries, and adds no operation.
     """
     x = state.x
     tau = tau_base * state.tau_scale
-    grad = problem.grad_f(x)
-    d = curvature(problem, tau, cfg.surrogate)
+    with jax.named_scope("grad"):
+        grad = problem.grad_f(x)
+    with jax.named_scope("best_response"):
+        d = curvature(problem, tau, cfg.surrogate)
     if active is not None:
         active = jnp.asarray(active, jnp.float32)
         active_b = active if problem.block_size == 1 \
             else problem.blockify(active)[:, 0]
 
     # (S.2) best response; optionally inexact with the Thm-1(v) schedule.
-    if cfg.inexact_alpha1 > 0 and problem.block_size > 1:
-        inner = 5  # few inner prox-grad steps; cert recorded in info
-        zhat, cert = best_response(problem, x, grad, d,
-                                   inner_iters=inner, eps=0.0)
-    else:
-        zhat = best_response(problem, x, grad, d)
-        cert = jnp.asarray(0.0)
+    with jax.named_scope("best_response"):
+        if cfg.inexact_alpha1 > 0 and problem.block_size > 1:
+            inner = 5  # few inner prox-grad steps; cert recorded in info
+            zhat, cert = best_response(problem, x, grad, d,
+                                       inner_iters=inner, eps=0.0)
+        else:
+            zhat = best_response(problem, x, grad, d)
+            cert = jnp.asarray(0.0)
 
     # (S.3) error bound + selection rule (greedy by default; random/hybrid/
     # cyclic per cfg.selection — see repro.core.selection.make_mask).
     # Screened-out blocks contribute E = 0, so the greedy threshold ρ·M is
     # measured over the surviving subproblem, and the final mask multiply
     # keeps them out of Sᵏ whatever the rule picked.
-    E = problem.block_norms(zhat - x)
-    if active is not None:
-        E = E * active_b
-    M = jnp.max(E)
-    if selection.needs_key(cfg.selection) and not cfg.jacobi:
-        key, sub = jax.random.split(state.key)
-    else:
-        key, sub = state.key, state.key
-    mask_b = selection.make_mask(E, cfg, sub, state.k, M=M)
-    if active is not None:
-        mask_b = mask_b * active_b
-    mask = mask_b if problem.block_size == 1 \
-        else jnp.repeat(mask_b, problem.block_size)
+    with jax.named_scope("select"):
+        E = problem.block_norms(zhat - x)
+        if active is not None:
+            E = E * active_b
+        M = jnp.max(E)
+        if selection.needs_key(cfg.selection) and not cfg.jacobi:
+            key, sub = jax.random.split(state.key)
+        else:
+            key, sub = state.key, state.key
+        mask_b = selection.make_mask(E, cfg, sub, state.k, M=M)
+        if active is not None:
+            mask_b = mask_b * active_b
+        mask = mask_b if problem.block_size == 1 \
+            else jnp.repeat(mask_b, problem.block_size)
 
     # (S.4) damped, masked update.
-    xnew = x + state.gamma * mask * (zhat - x)
-    v_new = problem.v(xnew)
+    with jax.named_scope("update"):
+        xnew = x + state.gamma * mask * (zhat - x)
+    with jax.named_scope("objective"):
+        v_new = problem.v(xnew)
 
     # §4 τ-controller (finitely many changes).
-    can_change = state.n_tau_changes < MAX_TAU_CHANGES
-    adapt = bool(cfg.tau_adapt)
-    increased = (v_new > state.v_prev) & can_change & adapt
-    consec = jnp.where(v_new > state.v_prev, 0, state.consec_dec + 1)
-    halve = (consec >= cfg.tau_patience) & can_change & adapt
-    tau_scale = jnp.where(increased, state.tau_scale * cfg.tau_grow,
-                          state.tau_scale)
-    tau_scale = jnp.where(halve, tau_scale * cfg.tau_shrink, tau_scale)
-    consec = jnp.where(halve, 0, consec)
-    n_changes = state.n_tau_changes + increased.astype(jnp.int32) \
-        + halve.astype(jnp.int32)
+    with jax.named_scope("tau"):
+        can_change = state.n_tau_changes < MAX_TAU_CHANGES
+        adapt = bool(cfg.tau_adapt)
+        increased = (v_new > state.v_prev) & can_change & adapt
+        consec = jnp.where(v_new > state.v_prev, 0, state.consec_dec + 1)
+        halve = (consec >= cfg.tau_patience) & can_change & adapt
+        tau_scale = jnp.where(increased, state.tau_scale * cfg.tau_grow,
+                              state.tau_scale)
+        tau_scale = jnp.where(halve, tau_scale * cfg.tau_shrink, tau_scale)
+        consec = jnp.where(halve, 0, consec)
+        n_changes = state.n_tau_changes + increased.astype(jnp.int32) \
+            + halve.astype(jnp.int32)
 
     # ‖x̂−x‖∞ termination measure (over surviving coordinates only when a
     # freeze mask is injected — frozen coordinates are certified by the
     # screening KKT recheck, not by the solver).
-    step_err = jnp.abs(zhat - x)
-    if active is not None:
-        step_err = step_err * active
-    stat = jnp.max(step_err)
+    with jax.named_scope("select"):
+        step_err = jnp.abs(zhat - x)
+        if active is not None:
+            step_err = step_err * active
+        stat = jnp.max(step_err)
     new_state = FlexaState(
         x=xnew,
         gamma=stepsize.gamma_next(state.gamma, cfg.theta),
@@ -259,30 +273,46 @@ def solve(problem: Problem, x0=None, cfg: SolverConfig | None = None,
 
     ``active`` restricts the solve to a fixed per-coordinate active set
     (screening support for ``repro.path``); frozen coordinates keep their
-    ``x0`` value untouched."""
-    cfg = cfg or SolverConfig()
-    if x0 is None:
-        x0 = jnp.zeros((problem.n,), jnp.float32)
-    step = make_step(problem, cfg, active=active)
-    state = init_state(problem, x0, cfg)
+    ``x0`` value untouched.
 
-    hist: dict[str, list] = {k: [] for k in
-                             ("V", "stat", "E_max", "sel_frac", "gamma",
-                              "time", "tau_scale")}
-    t0 = time.perf_counter()
-    converged = False
-    for it in range(cfg.max_iters):
-        state, info = step(state)
-        stat = float(info["stat"])
-        for key in ("V", "stat", "E_max", "sel_frac", "gamma", "tau_scale"):
-            hist[key].append(float(info[key]))
-        hist["time"].append(time.perf_counter() - t0)
-        if callback is not None:
-            callback(it, state, info)
-        if stat <= cfg.tol:
-            converged = True
-            break
-    return SolverResult(x=state.x, iters=int(state.k), converged=converged,
+    Traced (``repro.obs``): one ``solo.solve`` span (args ``iters``,
+    ``converged``) holding ``solo.prepare`` (the step's build and the
+    initial state) and, per iteration ``it``, ``solo.dispatch`` (the
+    step call; the first one traces the fresh step), ``solo.sync`` (the
+    first readback, where the host waits for the device) and
+    ``solo.readback`` (the other scalar reads and the history)."""
+    cfg = cfg or SolverConfig()
+    with obs.span("solo.solve", cat="solo") as whole:
+        if x0 is None:
+            x0 = jnp.zeros((problem.n,), jnp.float32)
+        with obs.span("solo.prepare", cat="solo"):
+            step = make_step(problem, cfg, active=active)
+            state = init_state(problem, x0, cfg)
+
+        hist: dict[str, list] = {k: [] for k in
+                                 ("V", "stat", "E_max", "sel_frac", "gamma",
+                                  "time", "tau_scale")}
+        t0 = time.perf_counter()
+        converged = False
+        for it in range(cfg.max_iters):
+            with obs.span("solo.dispatch", cat="solo", it=it):
+                state, info = step(state)
+            with obs.span("solo.sync", cat="solo", it=it):
+                stat = float(info["stat"])
+            with obs.span("solo.readback", cat="solo", it=it):
+                for key in ("V", "stat", "E_max", "sel_frac", "gamma",
+                            "tau_scale"):
+                    hist[key].append(float(info[key]))
+                hist["time"].append(time.perf_counter() - t0)
+            if callback is not None:
+                callback(it, state, info)
+            if stat <= cfg.tol:
+                converged = True
+                break
+        iters = int(state.k)
+        if whole is not None:
+            whole.args.update(iters=iters, converged=converged)
+    return SolverResult(x=state.x, iters=iters, converged=converged,
                         state=state, history=hist, method="flexa")
 
 

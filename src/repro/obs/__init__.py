@@ -1,9 +1,9 @@
 """Unified observability layer: span tracing, cost ledger, live ops view,
-numerical-health watchdog, windowed SLOs, and the perf-history tracker.
+numerical-health watchdog, and windowed SLOs.
 
 ``repro.obs`` spans the whole stack — client submit/run/step, backend
 dispatch, wave/continuous/mesh serve engines, path-driver KKT rounds and
-compaction repacks, and compile-cache hits/misses — with six pieces:
+compaction repacks, and compile-cache hits/misses — with five pieces:
 
 * :mod:`repro.obs.trace` — deterministic injectable-clock span recorder
   exporting JSONL and Chrome trace-event JSON (Perfetto-loadable).
@@ -26,11 +26,6 @@ compaction repacks, and compile-cache hits/misses — with six pieces:
   injectable clock (:class:`MetricWindows`): per-window p50/p99/rate
   for latency, occupancy, throughput and health events, opt-in via
   ``ServeTelemetry(window_s=...)``.
-* :mod:`repro.obs.history` — schema-versioned perf-history records
-  appended to ``results/bench/history.jsonl`` by every
-  ``benchmarks/run.py --gate`` run; ``python -m repro.obs.history``
-  compares the latest record against a committed baseline and exits
-  nonzero on metric regressions (a CI step).
 
 See ``docs/observability.md`` for the span model, ledger key semantics,
 and the determinism contract (gated by ``benchmarks/obs_bench.py``).

@@ -22,8 +22,7 @@ Keys (all integers, all additive):
 ======================  ==================================================
 
 Conservation: ``row_iters == live_iters + padding_iters + freeze_iters``
-whenever the producer can attribute waste (engines that cannot split
-freeze from padding fold the remainder into ``padding_iters``).
+for every producer.
 """
 from __future__ import annotations
 

@@ -7,6 +7,11 @@ under a virtual clock (``TickClock``-style callables) is run-to-run
 deterministic: span ids are sequence numbers, timestamps come from the
 injected clock, and no wall-clock state leaks into the record.
 
+Each span also enters a ``jax.profiler.TraceAnnotation`` of its name, so
+a profiler capture taken while a tracer is installed holds every span on
+the profiler's own clock, beside the device's operations (a no-op when
+no capture is running; ``jax`` is imported on the first span only).
+
 Instrumentation sites call the module-level helpers::
 
     from repro.obs import trace as obs
@@ -126,7 +131,8 @@ class Tracer:
         self.spans.append(s)
         self._stack.append(s)
         try:
-            yield s
+            with _annotation(name):
+                yield s
         finally:
             self._stack.pop()
             s.t1 = float(self.clock())
@@ -202,6 +208,12 @@ class Tracer:
             with open(path, "w") as f:
                 json.dump(doc, f, sort_keys=True)
         return doc
+
+
+def _annotation(name: str):
+    """The profiler's host annotation of ``name``."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
 
 
 # -- module-level active tracer -------------------------------------------

@@ -199,6 +199,15 @@ class _SlotSlab:
         self._stage_tol = np.full(S, self.cfg.tol, np.float32)
         self._stage_ids = np.zeros(S, np.int32)
         self._admit = np.zeros(S, bool)
+        # Bytes one admission stages (its data rows, x0 and freeze mask)
+        # and bytes one admitting tick uploads: the span args of
+        # serve.stage and serve.upload.
+        self._row_bytes = sum(b[0].nbytes for b in self._stage_data) \
+            + self._stage_x0[0].nbytes + self._stage_active[0].nbytes
+        self._payload_bytes = sum(b.nbytes for b in self._stage_data) \
+            + sum(b.nbytes for b in (self._stage_c, self._stage_x0,
+                                     self._stage_ids, self._stage_active,
+                                     self._stage_tol, self._admit))
         # Device-resident copy of the last shipped stage, reused on
         # ticks without admissions (no re-upload).  The .copy() matters
         # even here: jnp.asarray zero-copies aligned host buffers on
@@ -247,6 +256,12 @@ class _SlotSlab:
                                     chunk_iters=self.chunk_iters,
                                     wall_s=wall,
                                     flops=self._chunk_flops(self.capacity))
+
+    def _record_advanced(self, slot: int, iters: int) -> None:
+        """Iterations an evicted request advanced — the ledger's live
+        work.  The mesh slab overrides this to record on the owning
+        device's telemetry child."""
+        self.telemetry.record_advanced(iters)
 
     def _record_quarantine(self, slot: int, status: str) -> None:
         """Watchdog quarantine counter — the mesh slab overrides this to
@@ -375,14 +390,16 @@ class _SlotSlab:
     def _stage(self, slot: int, entry: QueueEntry, x0, audit: list,
                tick: int) -> None:
         r = entry.request
-        for buf, arr in zip(self._stage_data,
-                            r.data_arrays(self.spec)):
-            buf[slot] = np.asarray(arr, np.float32)
-        self._stage_c[slot] = r.c
-        self._stage_x0[slot] = 0.0 if x0 is None \
-            else np.asarray(x0, np.float32)
-        self._stage_active[slot] = 1.0 if r.active_mask is None \
-            else np.asarray(r.active_mask, np.float32)
+        with obs.span("serve.stage", cat="continuous", req_id=entry.req_id,
+                      slot=slot, bytes=self._row_bytes):
+            for buf, arr in zip(self._stage_data,
+                                r.data_arrays(self.spec)):
+                buf[slot] = np.asarray(arr, np.float32)
+            self._stage_c[slot] = r.c
+            self._stage_x0[slot] = 0.0 if x0 is None \
+                else np.asarray(x0, np.float32)
+            self._stage_active[slot] = 1.0 if r.active_mask is None \
+                else np.asarray(r.active_mask, np.float32)
         tol = self.cfg.tol if r.tol is None else float(r.tol)
         self._stage_tol[slot] = tol
         self._stage_ids[slot] = entry.req_id
@@ -443,8 +460,10 @@ class _SlotSlab:
         # async chunk dispatch (observed as admissions silently reading
         # all-False masks under load).
         if self._admit.any():
-            self._payload = self._stage_payload()
-            admit = self._to_device(self._admit.copy())
+            with obs.span("serve.upload", cat="continuous", tick=tick,
+                          bytes=self._payload_bytes):
+                self._payload = self._stage_payload()
+                admit = self._to_device(self._admit.copy())
             self._admit[:] = False
         else:
             admit = self._no_admit
@@ -495,10 +514,12 @@ class _SlotSlab:
             # Pull the whole (S, ·) result arrays and index on the host:
             # device-side fancy indexing would compile a fresh gather per
             # distinct eviction count.
-            state = self.slab.state
-            xs = np.asarray(state.x)[finished]
-            ks = np.asarray(state.k)[finished]
-            stats = np.asarray(state.stat)[finished]
+            with obs.span("serve.collect", cat="continuous", tick=tick,
+                          evicted=int(finished.size)):
+                state = self.slab.state
+                xs = np.asarray(state.x)[finished]
+                ks = np.asarray(state.k)[finished]
+                stats = np.asarray(state.stat)[finished]
             for j, slot in enumerate(finished):
                 req_id = int(self.slot_req[slot])
                 # Quarantine verdicts ("diverged"/"stalled") ride the
@@ -515,6 +536,7 @@ class _SlotSlab:
                 self.telemetry.record_completion(
                     req_id, iters=resp.iters, converged=resp.converged,
                     status=verdict)
+                self._record_advanced(int(slot), resp.iters)
                 if verdict != "ok":
                     self._record_quarantine(int(slot), verdict)
                     obs.instant("serve.quarantine", cat="continuous",
@@ -596,6 +618,7 @@ class _SlotSlab:
                         x=xs[slot], iters=int(ks[slot]), converged=False,
                         stat=float(stats[slot]), bucket=self.capacity,
                         status="timeout")
+                    self._record_advanced(slot, resp.iters)
                 out.append((req_id, resp))
                 self.telemetry.record_completion(
                     req_id, iters=resp.iters, converged=False,

@@ -159,6 +159,10 @@ class _MeshSlab(_SlotSlab):
                 wall_s=wall / self.n_devices,
                 flops=self._chunk_flops(per))
 
+    def _record_advanced(self, slot: int, iters: int) -> None:
+        self.telemetry.device(
+            slot // self.per_device_capacity).record_advanced(iters)
+
     def _record_quarantine(self, slot: int, status: str) -> None:
         # Record on the owning device's telemetry child: slot s lives on
         # device s // per_device_capacity.  MeshTelemetry.rollup() sums

@@ -11,7 +11,9 @@ latency at all.  :class:`ServeTelemetry` records
 * **chunk-level** counters for the continuous engine — chunks executed,
   FLEXA iterations per second of device wall, slot occupancy (live slots /
   slab capacity, weighted per chunk), padding waste (idle-slot row
-  iterations);
+  iterations), and the iterations evicted requests actually advanced,
+  which split the occupied slots' row iterations into live work and
+  freeze (a slot held after it converged inside a chunk);
 * **wave-level** counters for the bucketed engine — bucket occupancy
   (real requests / padded bucket), padding waste (row iterations spent on
   padding clones) and freeze waste (row iterations spent stepping
@@ -98,8 +100,9 @@ def _chunk_summary(t: "ServeTelemetry") -> dict:
     snapshot dict.  Used both for the global ``"continuous"`` section
     and for each per-device entry of :class:`MeshTelemetry`, so the two
     views can never drift: the raw counters (``chunks``,
-    ``chunk_iters``, ``row_iters``, ``live_iters``, ``chunk_wall_s``)
-    are additive across devices — the conservation law the mesh rollup
+    ``chunk_iters``, ``row_iters``, ``live_iters`` (the occupied slots'
+    rows), ``advanced_iters``, ``chunk_wall_s``) are additive across
+    devices — the conservation law the mesh rollup
     property tests pin — while the occupancy/waste ratios derive from
     them per view."""
     row = t.chunk_row_iters
@@ -108,10 +111,13 @@ def _chunk_summary(t: "ServeTelemetry") -> dict:
         "chunk_iters": t.chunk_iters,
         "row_iters": row,
         "live_iters": t.chunk_live_iters,
+        "advanced_iters": t.chunk_advanced_iters,
         "device_flops": t.chunk_flops,
         "occupancy_mean": t.chunk_live_iters / row if row else 0.0,
         "padding_waste": ((row - t.chunk_live_iters) / row
                           if row else 0.0),
+        "freeze_waste": ((t.chunk_live_iters - t.chunk_advanced_iters)
+                         / row if row else 0.0),
         "chunk_wall_s": t.chunk_wall,
         "iters_per_s": (t.chunk_live_iters / t.chunk_wall
                         if t.chunk_wall > 0 else None),
@@ -129,7 +135,8 @@ class ServeTelemetry:
     chunks: int = 0
     chunk_iters: int = 0            # Σ K over chunks (per-slot iterations)
     chunk_row_iters: int = 0        # Σ K·capacity (device row iterations)
-    chunk_live_iters: int = 0       # Σ K·live     (useful row iterations)
+    chunk_live_iters: int = 0       # Σ K·live     (occupied-slot rows)
+    chunk_advanced_iters: int = 0   # Σ iters of evicted requests
     chunk_flops: int = 0            # Σ K·capacity·m·n (matvec currency)
     chunk_wall: float = 0.0
     migrations: int = 0             # drain-tail slab capacity changes
@@ -255,6 +262,10 @@ class ServeTelemetry:
             w.add("occupancy", self.now(),
                   live / capacity if capacity else 0.0)
 
+    def record_advanced(self, iters: int) -> None:
+        """Iterations one evicted request advanced (its ``k``)."""
+        self.chunk_advanced_iters += int(iters)
+
     def record_migration(self, *, from_capacity: int,
                          to_capacity: int) -> None:
         """One drain-tail slab migration (capacities for dashboards only;
@@ -299,16 +310,22 @@ class ServeTelemetry:
         """Unified :class:`~repro.obs.ledger.CostLedger` over everything
         this telemetry recorded.
 
-        Continuous chunks cannot split freeze from padding (a slot that
-        converges mid-chunk stays frozen inside the fused dispatch), so
-        their whole ``row - live`` remainder lands in ``padding_iters``;
-        waves attribute both exactly.  ``compiles`` counts the
-        process-wide compile-cache misses (``cache_stats``) — the same
-        source the snapshot's ``compile_cache`` section reports."""
+        Continuous chunks: ``live_iters`` is what evicted requests
+        advanced (Σ of their ``k``), ``freeze_iters`` the rest of the
+        occupied slots' rows (K·occupied − advanced: slots held after
+        converging inside a chunk), ``padding_iters`` the empty slots'
+        rows (K·(capacity − occupied)).  A request still in a slot has
+        its rows in ``freeze_iters`` until its eviction moves them to
+        ``live_iters``, so the split is exact once the engine has
+        drained.  Waves attribute both exactly too.  ``compiles`` counts
+        the process-wide compile-cache misses (``cache_stats``) — the
+        same source the snapshot's ``compile_cache`` section reports."""
         led = CostLedger()
+        occupied = self.chunk_live_iters
         led.add(row_iters=self.chunk_row_iters,
-                live_iters=self.chunk_live_iters,
-                padding_iters=self.chunk_row_iters - self.chunk_live_iters,
+                live_iters=self.chunk_advanced_iters,
+                freeze_iters=occupied - self.chunk_advanced_iters,
+                padding_iters=self.chunk_row_iters - occupied,
                 device_flops=self.chunk_flops)
         for w in self.waves:
             pad = w["padded"] * w["iters_max"]
@@ -436,6 +453,8 @@ class MeshTelemetry(ServeTelemetry):
                                    for t in self.per_device)
         self.chunk_live_iters = sum(t.chunk_live_iters
                                     for t in self.per_device)
+        self.chunk_advanced_iters = sum(t.chunk_advanced_iters
+                                        for t in self.per_device)
         self.chunk_flops = sum(t.chunk_flops for t in self.per_device)
         self.chunk_wall = sum(t.chunk_wall for t in self.per_device)
         # Health events are recorded on the owning device's child (the

@@ -381,25 +381,26 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
         # quantities are computed for every row and selected by the
         # mask — cheaper than dynamic gathers at slab widths, and stale
         # payload rows are finite so no NaNs can leak through the
-        # select.
-        data = tuple(
-            jnp.where(_bmask(admit, d.ndim), nd.astype(d.dtype), d)
-            for d, nd in zip(slab.data, new_data))
-        csq_new = jax.vmap(fam.col_sq)(*new_data)
-        init = vinit(new_data, new_c, new_x0, new_ids)
-        state = jax.tree_util.tree_map(
-            lambda s, v: jnp.where(_bmask(admit, s.ndim),
-                                   v.astype(s.dtype), s),
-            slab.state, init)
-        return SlabState(
-            data=data,
-            c=jnp.where(admit, new_c, slab.c),
-            col_sq=jnp.where(admit[:, None], csq_new, slab.col_sq),
-            tau_base=jnp.where(admit[:, None], vtau(csq_new),
-                               slab.tau_base),
-            state=state,
-            active=jnp.where(admit[:, None], new_active, slab.active),
-            tol=jnp.where(admit, new_tol, slab.tol))
+        # select.  Its operations carry the ``splice`` scope.
+        with jax.named_scope("splice"):
+            data = tuple(
+                jnp.where(_bmask(admit, d.ndim), nd.astype(d.dtype), d)
+                for d, nd in zip(slab.data, new_data))
+            csq_new = jax.vmap(fam.col_sq)(*new_data)
+            init = vinit(new_data, new_c, new_x0, new_ids)
+            state = jax.tree_util.tree_map(
+                lambda s, v: jnp.where(_bmask(admit, s.ndim),
+                                       v.astype(s.dtype), s),
+                slab.state, init)
+            return SlabState(
+                data=data,
+                c=jnp.where(admit, new_c, slab.c),
+                col_sq=jnp.where(admit[:, None], csq_new, slab.col_sq),
+                tau_base=jnp.where(admit[:, None], vtau(csq_new),
+                                   slab.tau_base),
+                state=state,
+                active=jnp.where(admit[:, None], new_active, slab.active),
+                tol=jnp.where(admit, new_tol, slab.tol))
 
     def core(slab: SlabState, stop, admit, new_data, new_c, new_x0,
              new_ids, new_active, new_tol):

@@ -14,6 +14,7 @@ Three things are pinned here:
    warns once per process, and the client's own backends never trigger
    the warnings (they run under ``deprecation.internal_use``).
 """
+import os
 import warnings
 
 import pytest
@@ -92,11 +93,20 @@ def test_remote_package_stays_lazy():
     """``import repro.remote`` exposes only policy + protocol; the
     server and the registered backend are imported on demand (the
     client registry pulls ``repro.remote.backend`` the first time
-    ``backend="remote"`` is requested)."""
+    ``backend="remote"`` is requested).  Checked in a fresh interpreter:
+    this process may already hold both from another test file."""
+    import subprocess
     import sys
-    import repro.remote  # noqa: F401
-    assert "repro.remote.server" not in sys.modules
-    assert "repro.remote.backend" not in sys.modules
+    code = ("import sys, repro.remote; "
+            "print(sorted(m for m in ('repro.remote.server', "
+            "'repro.remote.backend') if m in sys.modules))")
+    src = os.path.dirname(os.path.dirname(os.path.dirname(
+        repro.client.__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": os.pathsep.join(
+        p for p in (src, os.environ.get("PYTHONPATH")) if p)}
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, check=True, timeout=300, env=env)
+    assert out.stdout.strip() == "[]", out.stdout + out.stderr
 
 
 # ------------------------------------------------------------------ #

@@ -1,4 +1,4 @@
-"""Numerical-health watchdog + windowed SLOs + perf history.
+"""Numerical-health watchdog + windowed SLOs.
 
 Pins the PR's contracts:
 
@@ -10,9 +10,7 @@ Pins the PR's contracts:
   construction); watchdog on leaves a healthy workload bit-identical;
 * **windows** — sliding-window SLO aggregation prunes by horizon under
   an injected clock, empty windows report ``None`` percentiles, and
-  health-event counters survive drain-tail slab migration;
-* **history** — bench records append schema-versioned and the compare
-  tool flags synthetic regressions (and only those) via exit codes.
+  health-event counters survive drain-tail slab migration.
 """
 import json
 import warnings
@@ -410,106 +408,3 @@ def test_dashboard_sections_absent_without_sources():
     from repro.obs.dashboard import render_snapshot
     out = render_snapshot({"requests": 1, "completed": 1})
     assert "health" not in out and "windows" not in out
-
-
-# ------------------------------------------------------------------ #
-# Perf history (tentpole piece 3)                                    #
-# ------------------------------------------------------------------ #
-def _bench_dir(tmp_path, row_iters=9600, flop_ratio=2.054, smoke=True):
-    d = tmp_path / "bench"
-    d.mkdir(exist_ok=True)
-    (d / "BENCH_obs.json").write_text(json.dumps({
-        "smoke": smoke, "row_iters": row_iters,
-        "overhead_frac": -0.01,
-        "solver_cfg": {"max_iters": 1200, "tol": 1e-7},
-        "serve_cfg": {"slab_capacity": 8, "chunk_iters": 100},
-        "ledger": {"row_iters": row_iters, "live_iters": 4900,
-                   "utilization": 0.51},
-    }))
-    (d / "BENCH_compaction.json").write_text(json.dumps({
-        "path": {"accept": {"flop_ratio": flop_ratio}}}))
-    return d
-
-
-def test_history_collect_append_load(tmp_path):
-    from repro.obs import history
-    d = _bench_dir(tmp_path)
-    rec = history.collect(d, t=123.0)
-    assert rec["schema"] == history.SCHEMA_VERSION
-    assert rec["t"] == 123.0 and rec["smoke"] is True
-    assert rec["metrics"]["obs.row_iters"] == 9600
-    assert rec["metrics"]["compaction.flop_ratio"] == 2.054
-    assert "serve.poisson.row_iters_x" not in rec["metrics"]  # absent art
-    assert rec["ledger"]["utilization"] == 0.51
-    assert rec["config_digest"]
-
-    h = tmp_path / "history.jsonl"
-    history.append(rec, h)
-    history.append(history.collect(d, t=124.0), h)
-    records = history.load_history(h)
-    assert [r["t"] for r in records] == [123.0, 124.0]
-    assert records[0]["config_digest"] == records[1]["config_digest"]
-
-
-def test_history_compare_flags_synthetic_regression(tmp_path):
-    from repro.obs import history
-    base = history.collect(_bench_dir(tmp_path), t=1.0)
-    same = history.collect(_bench_dir(tmp_path), t=2.0)
-    regs, warns = history.compare(same, base)
-    assert regs == [] and warns == []
-
-    # Deterministic counter changed → exact-metric regression.
-    worse = history.collect(
-        _bench_dir(tmp_path, row_iters=9999), t=3.0)
-    regs, _ = history.compare(worse, base)
-    assert [r["metric"] for r in regs] == ["obs.row_iters"]
-
-    # Ratio within tolerance → clean; beyond tolerance → regression.
-    close = history.collect(
-        _bench_dir(tmp_path, flop_ratio=2.054 * 0.96), t=4.0)
-    regs, _ = history.compare(close, base)
-    assert regs == []
-    bad = history.collect(
-        _bench_dir(tmp_path, flop_ratio=2.054 * 0.90), t=5.0)
-    regs, _ = history.compare(bad, base)
-    assert [r["metric"] for r in regs] == ["compaction.flop_ratio"]
-
-
-def test_history_compare_skips_mismatched_workloads(tmp_path):
-    from repro.obs import history
-    base = history.collect(_bench_dir(tmp_path, smoke=True), t=1.0)
-    full = history.collect(
-        _bench_dir(tmp_path, smoke=False, row_iters=999999), t=2.0)
-    regs, warns = history.compare(full, base)
-    assert regs == [] and any("smoke" in w for w in warns)
-
-
-def test_history_cli_exit_codes(tmp_path):
-    from repro.obs import history
-    d = _bench_dir(tmp_path)
-    h = tmp_path / "history.jsonl"
-
-    assert history.main(["append", "--bench-dir", str(d),
-                         "--history", str(h)]) == 0
-    assert len(history.load_history(h)) == 1
-    # One record, no baseline file: nothing to compare against.
-    assert history.main(["compare", "--history", str(h)]) == 0
-
-    baseline = tmp_path / "baseline.json"
-    baseline.write_text(json.dumps(history.load_history(h)[0]))
-
-    # Identical second run: clean compare.
-    assert history.main(["append", "--bench-dir", str(d),
-                         "--history", str(h)]) == 0
-    assert history.main(["compare", "--history", str(h),
-                         "--baseline", str(baseline)]) == 0
-
-    # Synthetic regression appended: nonzero exit.
-    history.append(history.collect(
-        _bench_dir(tmp_path, flop_ratio=1.0), t=9.0), h)
-    assert history.main(["compare", "--history", str(h),
-                         "--baseline", str(baseline)]) == 1
-
-    # Missing history: explicit error code.
-    assert history.main(["compare", "--history",
-                         str(tmp_path / "nope.jsonl")]) == 1

@@ -178,16 +178,18 @@ def test_telemetry_ledger_from_chunks_and_waves():
     tele = ServeTelemetry(clock=FakeClock())
     tele.record_chunk(live=3, capacity=4, chunk_iters=10, wall_s=0.1,
                       flops=10 * 4 * 24 * 64)
+    for iters in (10, 10, 4):               # the three slots' requests
+        tele.record_advanced(iters)
     tele.record_wave(bucket=8, n_real=5, iters=[7, 7, 3, 2, 1],
                      wall_s=0.1, flops=8 * 7 * 24 * 64)
     led = tele.ledger()
     assert led.conserved()
-    # chunk: row 40, live 30, remainder → padding (freeze inseparable)
+    # chunk: row 40, occupied 30, advanced 24 → freeze 6, padding 10
     # wave:  row 56, live 20, padding 3·7=21, freeze 56−20−21=15
     assert led.row_iters == 40 + 56
-    assert led.live_iters == 30 + 20
+    assert led.live_iters == 24 + 20
     assert led.padding_iters == 10 + 21
-    assert led.freeze_iters == 15
+    assert led.freeze_iters == 6 + 15
     assert led.device_flops == (40 + 56) * 24 * 64
     snap = tele.snapshot()
     assert snap["ledger"]["row_iters"] == led.row_iters

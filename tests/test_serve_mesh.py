@@ -129,7 +129,7 @@ def test_steal_victim_contract(qlens, thief, threshold):
 # Telemetry rollup conservation (pure)                               #
 # ------------------------------------------------------------------ #
 ADDITIVE_KEYS = ("chunks", "chunk_iters", "row_iters", "live_iters",
-                 "chunk_wall_s", "device_flops")
+                 "advanced_iters", "chunk_wall_s", "device_flops")
 
 
 def _conservation_holds(snap):
@@ -149,11 +149,13 @@ def test_mesh_telemetry_rollup_is_sum_of_parts(seed):
         d = int(rng.integers(n_dev))
         cap = int(rng.integers(1, 6))
         K = int(rng.integers(1, 64))
+        live = int(rng.integers(0, cap + 1))
         tele.device(d).record_chunk(
-            live=int(rng.integers(0, cap + 1)), capacity=cap,
+            live=live, capacity=cap,
             chunk_iters=K,
             wall_s=float(rng.uniform(0.0, 1e-2)),
             flops=K * cap * 24 * 64)
+        tele.device(d).record_advanced(int(rng.integers(0, K * live + 1)))
         if rng.uniform() < 0.3:
             tele.record_steal()
         tele.record_route(int(rng.integers(0, 3)))
@@ -467,8 +469,13 @@ SUBPROC_SRC = textwrap.dedent("""
     rm, rc = em.drain(), ec.drain()
     snap = em.telemetry.snapshot()
     per = snap["mesh"]["per_device"]
-    keys = ("chunks", "chunk_iters", "row_iters", "live_iters")
+    keys = ("chunks", "chunk_iters", "row_iters", "live_iters",
+            "advanced_iters")
+    led = em.telemetry.ledger()
     print(json.dumps({
+        "ledger_exact": (led.conserved() and led.live_iters == sum(
+            rm[a].iters for a in im) and led.freeze_iters ==
+            snap["continuous"]["live_iters"] - led.live_iters),
         "max_diff": max(float(np.abs(np.asarray(rm[a].x) -
                                      np.asarray(rc[b].x)).max())
                         for a, b in zip(im, ic)),
@@ -501,6 +508,7 @@ def test_mesh_four_device_subprocess():
     assert rec["max_diff"] <= 1e-5
     assert rec["iters_equal"] and rec["one_service"]
     assert rec["conservation"]
+    assert rec["ledger_exact"]
 
 
 def test_mesh_slab_never_migrates():
