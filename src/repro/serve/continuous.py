@@ -22,12 +22,17 @@ schedule:
   converged slots and **backfills** them in place from an **admission
   queue** with FIFO / priority / earliest-deadline policies — so
   throughput is bounded by slot occupancy, not by the slowest request in
-  a wave.  Admissions are staged host-side and spliced by the chunk
-  program itself (``make_chunk_stepper``'s fused admit phase — a masked
-  in-place row write), so a tick is one device dispatch however many
-  requests enter; the standalone single-slot splice
-  (:func:`repro.solvers.batched.make_slot_writer`) remains the building
-  block for packing slabs outside the engine.
+  a wave.  An admitted request's data rows (its A and b) go to the
+  device alone and are written in place into the donated slab at its
+  slot (:func:`repro.solvers.batched.make_row_writer`: one program per
+  signature, a traced slot index); the small per-slot vectors (c, x0,
+  freeze mask, tol, request ids, admit mask) ride with the chunk call,
+  whose fused admit phase splices the rest of the row (column norms,
+  base τ, a fresh state) from the slab's own data.  So a tick ships the
+  admitted rows and nothing else of the data, and stays one chunk
+  program however many requests enter; the standalone single-slot
+  splice (:func:`repro.solvers.batched.make_slot_writer`) remains the
+  building block for packing slabs outside the engine.
 
 Per-request PRNG streams fold the *request id* (not the slot) into
 ``PRNGKey(cfg.seed)``, so a request's randomized-selection trajectory is
@@ -62,8 +67,8 @@ from repro.serve.engine import SolveRequest, SolveResponse, validate_request
 from repro.serve.pathstate import PathRequest, PathState
 from repro.serve.metrics import ServeTelemetry
 from repro.solvers.batched import (BatchedProblemSpec, make_chunk_stepper,
-                                   slab_alloc, slab_data_shapes,
-                                   slab_migrate)
+                                   make_row_writer, slab_alloc,
+                                   slab_data_shapes, slab_migrate)
 from repro.solvers.compaction import bucket_capacity
 
 
@@ -138,12 +143,14 @@ class AdmissionQueue:
 class _SlotSlab:
     """Host-side bookkeeping around one device slab (one signature).
 
-    Admissions are *staged*: :meth:`backfill` writes request payloads
-    into reusable host buffers and flags the slot in an admit mask; the
-    next :meth:`step` ships the whole stage with the chunk call and the
-    fused program splices + iterates in one dispatch.  A tick therefore
-    costs one device program + one (S,) mask readback regardless of how
-    many requests were admitted or evicted.
+    Admissions are *staged*: :meth:`backfill` keeps each admitted
+    request's data rows by reference, writes its per-slot vectors into
+    reusable host buffers and flags the slot in an admit mask; the next
+    :meth:`step` writes each admitted slot's rows into the donated slab
+    (one small program per admission), ships the per-slot vectors with
+    the chunk call, and the fused program splices + iterates in one
+    dispatch.  A tick therefore costs one chunk program + one (S,) mask
+    readback, plus one row write per admitted request.
     """
 
     def __init__(self, spec: BatchedProblemSpec, cfg: SolverConfig,
@@ -164,6 +171,7 @@ class _SlotSlab:
         self.slab = self._to_device(slab_alloc(spec, cfg, self.capacity))
         self._health_carry = self._fresh_health(self.capacity)
         self._chunk = self._make_chunk()
+        self._row_writer = make_row_writer(spec)
         # warm_from resolver: req_id -> finished solution (None = still
         # in flight, defer admission).  Injected by the engine.
         self._resolve_x0 = resolve_x0 or (lambda req_id: None)
@@ -186,43 +194,43 @@ class _SlotSlab:
         capacity — called once at construction and again by
         :meth:`_resize` whenever a drain-tail migration changes S.
 
-        Staging host buffers are reused across ticks; stale rows are
-        fine — the chunk program masks them out.
+        The per-slot vector buffers are reused across ticks; stale rows
+        are fine — the chunk program masks them out.  Data rows are not
+        staged in a buffer: ``_stage_rows`` holds each admitted slot's
+        rows by reference until :meth:`step` writes them into the slab.
         """
         S = self.capacity
         spec = self.spec
-        self._stage_data = [np.zeros((S,) + shp, np.float32)
-                            for shp in slab_data_shapes(spec)]
+        self._stage_rows: dict[int, tuple] = {}
         self._stage_c = np.zeros(S, np.float32)
         self._stage_x0 = np.zeros((S, spec.n), np.float32)
         self._stage_active = np.ones((S, spec.n), np.float32)
         self._stage_tol = np.full(S, self.cfg.tol, np.float32)
         self._stage_ids = np.zeros(S, np.int32)
         self._admit = np.zeros(S, bool)
-        # Bytes one admission stages (its data rows, x0 and freeze mask)
-        # and bytes one admitting tick uploads: the span args of
-        # serve.stage and serve.upload.
-        self._row_bytes = sum(b[0].nbytes for b in self._stage_data) \
-            + self._stage_x0[0].nbytes + self._stage_active[0].nbytes
-        self._payload_bytes = sum(b.nbytes for b in self._stage_data) \
-            + sum(b.nbytes for b in (self._stage_c, self._stage_x0,
-                                     self._stage_ids, self._stage_active,
-                                     self._stage_tol, self._admit))
-        # Device-resident copy of the last shipped stage, reused on
-        # ticks without admissions (no re-upload).  The .copy() matters
-        # even here: jnp.asarray zero-copies aligned host buffers on
-        # CPU, so without it these device arrays alias the staging
-        # buffers _stage() mutates — same race class as the per-tick
-        # payload below, just waiting for a code path that reads the
-        # initial payload after an admission.
+        # Bytes of one admission's data rows, and of the per-slot
+        # vectors an admitting tick ships: serve.stage's ``bytes`` is
+        # the former, serve.upload's is rows × the former + the latter.
+        self._row_bytes = 4 * sum(math.prod(shp)
+                                  for shp in slab_data_shapes(spec))
+        self._vector_bytes = sum(
+            b.nbytes for b in (self._stage_c, self._stage_x0,
+                               self._stage_ids, self._stage_active,
+                               self._stage_tol, self._admit))
+        # Device-resident copy of the last shipped per-slot vectors,
+        # reused on ticks without admissions (no re-upload).  The
+        # .copy() matters even here: jnp.asarray zero-copies aligned
+        # host buffers on CPU, so without it these device arrays alias
+        # the staging buffers _stage() mutates — same race class as the
+        # per-tick payload below, just waiting for a code path that
+        # reads the initial payload after an admission.
         self._payload = self._stage_payload()
         self._no_admit = self._to_device(np.zeros(S, bool))
 
     def _stage_payload(self):
-        """The staging buffers as device arrays (copies — see the
-        .copy() note in :meth:`step`)."""
+        """The per-slot vector buffers as device arrays (copies — see
+        the .copy() note in :meth:`step`)."""
         return self._to_device((
-            tuple(a.copy() for a in self._stage_data),
             self._stage_c.copy(), self._stage_x0.copy(),
             self._stage_ids.copy(), self._stage_active.copy(),
             self._stage_tol.copy()))
@@ -243,6 +251,13 @@ class _SlotSlab:
         them: the default device.  The mesh slab shards the slot axis
         over its devices instead."""
         return jax.tree_util.tree_map(jnp.asarray, tree)
+
+    def _write_rows(self, slot: int, rows: tuple) -> None:
+        """Ship one admitted slot's data rows to the device and write
+        them into the donated slab.  The mesh slab overrides this to
+        write into the owning device's shard alone."""
+        self.slab = self._row_writer(self.slab, np.int32(slot),
+                                     *self._to_device(rows))
 
     def _slab_capacity(self, serve: ServeConfig) -> int:
         return serve.slab_capacity
@@ -392,9 +407,7 @@ class _SlotSlab:
         r = entry.request
         with obs.span("serve.stage", cat="continuous", req_id=entry.req_id,
                       slot=slot, bytes=self._row_bytes):
-            for buf, arr in zip(self._stage_data,
-                                r.data_arrays(self.spec)):
-                buf[slot] = np.asarray(arr, np.float32)
+            self._stage_rows[slot] = r.data_arrays(self.spec)
             self._stage_c[slot] = r.c
             self._stage_x0[slot] = 0.0 if x0 is None \
                 else np.asarray(x0, np.float32)
@@ -454,29 +467,34 @@ class _SlotSlab:
         if not self.active.any():
             return []
         t0 = time.perf_counter()
-        # NOTE the .copy() on every numpy→device crossing: jnp.asarray
-        # zero-copies aligned host buffers on CPU, and these staging
-        # buffers are mutated on later ticks — an alias would race the
-        # async chunk dispatch (observed as admissions silently reading
-        # all-False masks under load).
+        # NOTE the .copy() on every staging-buffer→device crossing:
+        # jnp.asarray zero-copies aligned host buffers on CPU, and these
+        # staging buffers are mutated on later ticks — an alias would
+        # race the async chunk dispatch (observed as admissions silently
+        # reading all-False masks under load).  Data rows need none: the
+        # row writer copies them into the slab, and the engine never
+        # writes to a request's arrays.
         if self._admit.any():
+            slots = [int(s) for s in np.flatnonzero(self._admit)]
             with obs.span("serve.upload", cat="continuous", tick=tick,
-                          bytes=self._payload_bytes):
+                          rows=len(slots),
+                          bytes=len(slots) * self._row_bytes
+                          + self._vector_bytes):
+                for slot in slots:
+                    self._write_rows(slot, self._stage_rows.pop(slot))
                 self._payload = self._stage_payload()
                 admit = self._to_device(self._admit.copy())
             self._admit[:] = False
         else:
             admit = self._no_admit
-        new_data, new_c, new_x0, new_ids, new_active, new_tol = \
-            self._payload
+        new_c, new_x0, new_ids, new_active, new_tol = self._payload
         with obs.span("serve.chunk", cat="continuous", tick=tick,
                       live=self.live, capacity=self.capacity,
                       chunk_iters=self.chunk_iters):
             if self._health_cfg is None:
                 self.slab, stop_dev = self._chunk(
                     self.slab, self._to_device(self.stop.copy()), admit,
-                    new_data, new_c, new_x0, new_ids, new_active,
-                    new_tol)
+                    new_c, new_x0, new_ids, new_active, new_tol)
                 # The one per-chunk host sync (copy: host mirror is
                 # mutated).
                 stop = np.array(stop_dev)
@@ -488,8 +506,8 @@ class _SlotSlab:
                 # 3=stalled).  The health carry stays device-resident.
                 self.slab, status_dev, prev_stat, stall = self._chunk(
                     self.slab, self._to_device(self.stop.copy()), admit,
-                    new_data, new_c, new_x0, new_ids, new_active,
-                    new_tol, *self._health_carry)
+                    new_c, new_x0, new_ids, new_active, new_tol,
+                    *self._health_carry)
                 self._health_carry = (prev_stat, stall)
                 status = np.array(status_dev)
                 stop = status != STATUS_RUNNING
@@ -609,6 +627,7 @@ class _SlotSlab:
                     # row still holds a previous request's state, so
                     # answer with the staged x0 and cancel the admit.
                     self._admit[slot] = False
+                    self._stage_rows.pop(slot, None)
                     resp = SolveResponse(
                         x=self._stage_x0[slot].copy(), iters=0,
                         converged=False, stat=float("inf"),

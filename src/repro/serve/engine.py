@@ -188,14 +188,25 @@ class SolveRequest:
         family calls it; ``b`` supplies the observation vector.  Families
         with additional per-instance arrays need a richer request type —
         fail loudly rather than guessing.
+
+        The arrays come back as float32 where they already are: host
+        arrays stay on the host (no copy when already float32), device
+        arrays on their device.  The caller places them, so the
+        continuous slab can ship a row straight to the device that owns
+        its slot.
         """
+        def f32(a):
+            if isinstance(a, jax.Array):
+                return a.astype(jnp.float32)
+            return np.asarray(a, np.float32)
+
         keys = get_family(spec.family).data_keys
         out = []
         for j, k in enumerate(keys):
             if j == 0:
-                out.append(jnp.asarray(self.A, jnp.float32))
+                out.append(f32(self.A))
             elif k == "b":
-                out.append(jnp.asarray(self.b, jnp.float32))
+                out.append(f32(self.b))
             else:
                 raise NotImplementedError(
                     f"SolveRequest has no field for data key {k!r} of "

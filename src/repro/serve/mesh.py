@@ -41,11 +41,14 @@ Determinism contract (pinned by ``tests/test_serve_mesh.py``):
 
 Host→device discipline: the mesh slab inherits the staged-admission
 buffers of ``_SlotSlab`` unchanged, including the ``.copy()`` on every
-numpy→device crossing — ``jnp.asarray`` zero-copies aligned host
-buffers on CPU, and with per-device queues *partial* slab re-stages are
-the common case, so an aliased buffer mutated by the next tick's
-routing would race the still-in-flight sharded dispatch (the PR-3 race
-class; regression-tested under multi-device admission load).
+staging-buffer→device crossing — ``jnp.asarray`` zero-copies aligned
+host buffers on CPU, and with per-device queues *partial* slab
+re-stages are the common case, so an aliased buffer mutated by the next
+tick's routing would race the still-in-flight sharded dispatch (a race
+regression-tested under multi-device admission load).  An admitted
+request's data rows go to the device that owns its slot and are written
+into that device's shard of the slab alone (:meth:`_MeshSlab.
+_write_rows`): no all-gather, and no copy through device 0.
 """
 from __future__ import annotations
 
@@ -114,7 +117,8 @@ class _MeshSlab(_SlotSlab):
     block ``shard_map`` places on mesh device d — so every host-side
     per-device view is a constant-stride slice of the inherited
     mirrors.  Everything else (staging buffers, the fused step, the
-    eviction readback) is the parent's, byte for byte.
+    eviction readback) is the parent's, byte for byte, but for where an
+    admitted request's rows are written (:meth:`_write_rows`).
     """
 
     def __init__(self, spec: BatchedProblemSpec, cfg: SolverConfig,
@@ -140,6 +144,27 @@ class _MeshSlab(_SlotSlab):
         # Straight to each device's slot block: no full-slab copy on
         # device 0 on the way.
         return jax.device_put(tree, self._rows)
+
+    def _write_rows(self, slot: int, rows: tuple) -> None:
+        # Slot s lives on device s // S_dev: the rows go to that device
+        # alone, and the single-device row writer runs on its shard of
+        # the slab (local slot s % S_dev).  The other shards are handed
+        # back untouched when the global arrays are reassembled.
+        d, local = divmod(slot, self.per_device_capacity)
+        dev = self._rows.mesh.devices.flat[d]
+        leaves, tree = jax.tree_util.tree_flatten(self.slab)
+        shards = [{s.device: s.data for s in leaf.addressable_shards}
+                  for leaf in leaves]
+        out = self._row_writer(
+            tree.unflatten([sh[dev] for sh in shards]),
+            jax.device_put(np.int32(local), dev),
+            *jax.device_put(rows, dev))
+        for sh, new in zip(shards, jax.tree_util.tree_leaves(out)):
+            sh[dev] = new
+        self.slab = tree.unflatten([
+            jax.make_array_from_single_device_arrays(
+                leaf.shape, leaf.sharding, list(sh.values()))
+            for leaf, sh in zip(leaves, shards)])
 
     def _slab_capacity(self, serve: ServeConfig) -> int:
         return self.n_devices * self.per_device_capacity

@@ -31,10 +31,12 @@ Besides the run-to-convergence wave program, this module exposes the
 (``repro.serve.continuous``) schedules over: :func:`slab_alloc` packs a
 fixed-capacity stack of instance buffers, :func:`make_slot_writer`
 compiles an in-place ``dynamic_update_slice`` admission of one new
-instance into a slot, and :func:`make_chunk_stepper` compiles "advance
-every live slot by K iterations" with the same freeze-on-convergence
-merge the wave driver uses — so a slot's trajectory is bit-identical
-whichever driver runs it.
+instance into a slot, :func:`make_row_writer` writes one admitted
+request's data rows alone into the donated slab, and
+:func:`make_chunk_stepper` compiles "advance every live slot by K
+iterations" with the same freeze-on-convergence merge the wave driver
+uses — so a slot's trajectory is bit-identical whichever driver runs
+it.
 
 γ, τ, the PRNG key of the randomized selection rules, and the selection
 mask are per-instance state, so each instance follows the identical
@@ -319,6 +321,32 @@ def _build_slot_writer(spec: BatchedProblemSpec, cfg: SolverConfig):
 make_slot_writer = CompileCache("slot_writer", _build_slot_writer)
 
 
+def _build_row_writer(spec: BatchedProblemSpec):
+    """Compile ``write_rows(slab, slot, *rows) -> slab``: one admitted
+    request's family data rows (``(A, b)`` for the quadratic families,
+    ``(Z,)`` for logreg/svm) written in place into slot ``slot`` of
+    ``slab.data``.
+
+    Admission's data path: the continuous slab ships each admitted
+    request's rows alone, and the chunk program's splice then reads them
+    from the slab.  ``slot`` is a traced int32 scalar and the slab is
+    donated, so one program per signature serves every slot and every
+    admission count, and the write is a ``dynamic_update_slice`` into
+    the resident buffer.  Every other slot's data and every non-data
+    buffer pass through unchanged.
+    """
+    @partial(jax.jit, donate_argnums=(0,))
+    def write_rows(slab: SlabState, slot, *rows):
+        return slab._replace(data=tuple(
+            d.at[slot].set(r.astype(d.dtype))
+            for d, r in zip(slab.data, rows)))
+
+    return write_rows
+
+
+make_row_writer = CompileCache("row_writer", _build_row_writer)
+
+
 def _bmask(mask, ndim: int):
     """Broadcast a (S,) bool mask against an (S, ...) array."""
     return mask.reshape((-1,) + (1,) * (ndim - 1))
@@ -329,8 +357,8 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
     """The (un-jitted) fused tick body shared by the single-device and
     mesh-sharded chunk steppers:
 
-        core(slab, stop, admit, new_data, new_c, new_x0, new_ids,
-             new_active) -> (slab, stop)
+        core(slab, stop, admit, new_c, new_x0, new_ids, new_active,
+             new_tol) -> (slab, stop)
 
     or, with the numerical-health watchdog enabled (``health`` a
     :class:`repro.obs.health.HealthConfig`):
@@ -349,13 +377,16 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
     ``health=None`` this function builds the exact legacy program.
 
     Phase 1 — **admission splice**: slots flagged in ``admit`` (an (S,)
-    bool mask) are overwritten in place from the staged full-slab
-    payload: family data rows, regularization weight, a freshly computed
-    column-norm / base-τ row, and a fresh :class:`FlexaState` initialized
-    exactly as a solo solve would (``_instance_init`` with the *request
-    id* folded into the PRNG stream, so a request's trajectory never
-    depends on its slot or neighbours).  Non-admitted payload rows are
-    ignored (masked select), so the host can leave stale bytes there.
+    bool mask) already hold their new family data rows in ``slab.data``
+    (written before the tick by :func:`make_row_writer`, one row per
+    admitted request); the splice overwrites the rest of each admitted
+    row in place from the per-slot vectors: regularization weight, a
+    column-norm / base-τ row computed from the slab's data, and a fresh
+    :class:`FlexaState` initialized exactly as a solo solve would
+    (``_instance_init`` with the *request id* folded into the PRNG
+    stream, so a request's trajectory never depends on its slot or
+    neighbours).  Non-admitted rows are ignored (masked select), so the
+    host can leave stale bytes in the per-slot vectors.
 
     Phase 2 — **K iterations** on every unstopped slot, with the wave
     driver's exact freeze-on-convergence merge: a slot flips its own
@@ -375,25 +406,23 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
     vinit = jax.vmap(partial(_instance_init, spec, cfg))
     vtau = jax.vmap(lambda csq: _tau_base(fam.half_curv(csq), cfg, spec.n))
 
-    def splice(slab: SlabState, admit, new_data, new_c, new_x0,
-               new_ids, new_active, new_tol) -> SlabState:
-        # Masked in-place splice of admitted rows.  The fresh per-row
+    def splice(slab: SlabState, admit, new_c, new_x0, new_ids,
+               new_active, new_tol) -> SlabState:
+        # Masked in-place splice of admitted rows, whose data the row
+        # writer has already put in the slab.  The fresh per-row
         # quantities are computed for every row and selected by the
-        # mask — cheaper than dynamic gathers at slab widths, and stale
-        # payload rows are finite so no NaNs can leak through the
-        # select.  Its operations carry the ``splice`` scope.
+        # mask — cheaper than dynamic gathers at slab widths, and every
+        # slab row is finite (data or zero placeholders) so no NaNs can
+        # leak through the select.  Its operations carry the ``splice``
+        # scope.
         with jax.named_scope("splice"):
-            data = tuple(
-                jnp.where(_bmask(admit, d.ndim), nd.astype(d.dtype), d)
-                for d, nd in zip(slab.data, new_data))
-            csq_new = jax.vmap(fam.col_sq)(*new_data)
-            init = vinit(new_data, new_c, new_x0, new_ids)
+            csq_new = jax.vmap(fam.col_sq)(*slab.data)
+            init = vinit(slab.data, new_c, new_x0, new_ids)
             state = jax.tree_util.tree_map(
                 lambda s, v: jnp.where(_bmask(admit, s.ndim),
                                        v.astype(s.dtype), s),
                 slab.state, init)
-            return SlabState(
-                data=data,
+            return slab._replace(
                 c=jnp.where(admit, new_c, slab.c),
                 col_sq=jnp.where(admit[:, None], csq_new, slab.col_sq),
                 tau_base=jnp.where(admit[:, None], vtau(csq_new),
@@ -402,8 +431,8 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
                 active=jnp.where(admit[:, None], new_active, slab.active),
                 tol=jnp.where(admit, new_tol, slab.tol))
 
-    def core(slab: SlabState, stop, admit, new_data, new_c, new_x0,
-             new_ids, new_active, new_tol):
+    def core(slab: SlabState, stop, admit, new_c, new_x0, new_ids,
+             new_active, new_tol):
         # Phase 1 under a cond: the steady-state tick between evictions
         # admits nothing, and the splice's fresh-state/column-norm work
         # (~one iteration's worth of matvecs) should not be paid then.
@@ -411,7 +440,7 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
         # admitting nothing this tick skips its splice independently.
         slab = jax.lax.cond(
             jnp.any(admit),
-            lambda s: splice(s, admit, new_data, new_c, new_x0, new_ids,
+            lambda s: splice(s, admit, new_c, new_x0, new_ids,
                              new_active, new_tol),
             lambda s: s,
             slab)
@@ -438,9 +467,8 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
 
     H = int(health.stall_window)
 
-    def core_health(slab: SlabState, stop, admit, new_data, new_c,
-                    new_x0, new_ids, new_active, new_tol,
-                    prev_stat, stall):
+    def core_health(slab: SlabState, stop, admit, new_c, new_x0,
+                    new_ids, new_active, new_tol, prev_stat, stall):
         # Slots that iterate this chunk: not stopped at entry, or being
         # (re)admitted right now.  Empty slots arrive with stop=True and
         # hold +inf/NaN placeholders, so every verdict below is masked
@@ -449,8 +477,8 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
         prev_stat = jnp.where(admit, jnp.inf, prev_stat)
         stall = jnp.where(admit, 0, stall)
 
-        slab, stop_out = core(slab, stop, admit, new_data, new_c,
-                              new_x0, new_ids, new_active, new_tol)
+        slab, stop_out = core(slab, stop, admit, new_c, new_x0,
+                              new_ids, new_active, new_tol)
 
         stat = slab.state.stat
         finite = (jnp.all(jnp.isfinite(slab.state.x), axis=-1)
@@ -482,45 +510,47 @@ def _build_chunk_stepper(spec: BatchedProblemSpec, cfg: SolverConfig,
     """Compile one fused scheduler tick (see :func:`_chunk_core` for the
     phase-by-phase contract):
 
-        chunk(slab, stop, admit, new_data, new_c, new_x0, new_ids)
-            -> (slab, stop)
+        chunk(slab, stop, admit, new_c, new_x0, new_ids, new_active,
+              new_tol) -> (slab, stop)
 
     Fusing admission into the step matters operationally: a scheduler
-    tick is ONE device program and one (S,) mask readback, however many
+    tick is ONE chunk program and one (S,) mask readback, however many
     requests were admitted — separate per-slot splice calls would pay
     dispatch per admission and dominate the serving makespan at small
-    instance sizes.  The slab and stop mask are donated (in-place
-    advance).
+    instance sizes.  Only the admitted data rows travel apart
+    (:func:`make_row_writer`), so the chunk's arguments hold one data
+    slab and (S,)/(S, n) per-slot vectors.  The slab and stop mask are
+    donated (in-place advance).
 
     With ``health`` set, the tick takes and returns the device-resident
     per-slot health carry and the readback widens to an int32 status
     vector (still exactly one transfer per tick):
 
-        chunk(slab, stop, admit, ..., new_active, prev_stat, stall)
+        chunk(slab, stop, admit, ..., new_tol, prev_stat, stall)
             -> (slab, status, prev_stat, stall)
     """
     core = _chunk_core(spec, cfg, chunk_iters, health)
 
     if health is None:
         @partial(jax.jit, donate_argnums=(0, 1))
-        def chunk(slab: SlabState, stop, admit, new_data, new_c, new_x0,
-                  new_ids, new_active=None, new_tol=None):
+        def chunk(slab: SlabState, stop, admit, new_c, new_x0, new_ids,
+                  new_active=None, new_tol=None):
             if new_active is None:
                 new_active = jnp.ones_like(slab.active)
             if new_tol is None:
                 new_tol = jnp.full_like(slab.c, cfg.tol)
-            return core(slab, stop, admit, new_data, new_c, new_x0,
-                        new_ids, new_active, new_tol)
+            return core(slab, stop, admit, new_c, new_x0, new_ids,
+                        new_active, new_tol)
     else:
-        @partial(jax.jit, donate_argnums=(0, 1, 9, 10))
-        def chunk(slab: SlabState, stop, admit, new_data, new_c, new_x0,
-                  new_ids, new_active, new_tol, prev_stat, stall):
+        @partial(jax.jit, donate_argnums=(0, 1, 8, 9))
+        def chunk(slab: SlabState, stop, admit, new_c, new_x0, new_ids,
+                  new_active, new_tol, prev_stat, stall):
             if new_active is None:
                 new_active = jnp.ones_like(slab.active)
             if new_tol is None:
                 new_tol = jnp.full_like(slab.c, cfg.tol)
-            return core(slab, stop, admit, new_data, new_c, new_x0,
-                        new_ids, new_active, new_tol, prev_stat, stall)
+            return core(slab, stop, admit, new_c, new_x0, new_ids,
+                        new_active, new_tol, prev_stat, stall)
 
     return chunk
 
@@ -559,8 +589,7 @@ def _build_sharded_chunk_stepper(spec: BatchedProblemSpec,
         c=row, col_sq=row, tau_base=row,
         state=FlexaState(*([row] * len(FlexaState._fields))),
         active=row, tol=row)
-    payload_specs = (tuple(row for _ in slab_data_shapes(spec)),
-                     row, row, row, row, row)
+    payload_specs = (row,) * 5         # c, x0, ids, active, tol
     if health is None:
         in_specs = (slab_specs, row, row) + payload_specs
         out_specs = (slab_specs, row)
@@ -574,24 +603,24 @@ def _build_sharded_chunk_stepper(spec: BatchedProblemSpec,
 
     if health is None:
         @partial(jax.jit, donate_argnums=(0, 1))
-        def chunk(slab: SlabState, stop, admit, new_data, new_c, new_x0,
-                  new_ids, new_active=None, new_tol=None):
+        def chunk(slab: SlabState, stop, admit, new_c, new_x0, new_ids,
+                  new_active=None, new_tol=None):
             if new_active is None:
                 new_active = jnp.ones_like(slab.active)
             if new_tol is None:
                 new_tol = jnp.full_like(slab.c, cfg.tol)
-            return sharded(slab, stop, admit, new_data, new_c, new_x0,
-                           new_ids, new_active, new_tol)
+            return sharded(slab, stop, admit, new_c, new_x0, new_ids,
+                           new_active, new_tol)
     else:
-        @partial(jax.jit, donate_argnums=(0, 1, 9, 10))
-        def chunk(slab: SlabState, stop, admit, new_data, new_c, new_x0,
-                  new_ids, new_active, new_tol, prev_stat, stall):
+        @partial(jax.jit, donate_argnums=(0, 1, 8, 9))
+        def chunk(slab: SlabState, stop, admit, new_c, new_x0, new_ids,
+                  new_active, new_tol, prev_stat, stall):
             if new_active is None:
                 new_active = jnp.ones_like(slab.active)
             if new_tol is None:
                 new_tol = jnp.full_like(slab.c, cfg.tol)
-            return sharded(slab, stop, admit, new_data, new_c, new_x0,
-                           new_ids, new_active, new_tol, prev_stat, stall)
+            return sharded(slab, stop, admit, new_c, new_x0, new_ids,
+                           new_active, new_tol, prev_stat, stall)
 
     return chunk
 

@@ -10,6 +10,7 @@ elsewhere (interpret-mode tests here, ``chip_smoke.py`` on the chip).
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
+import math
 import os
 
 import jax
@@ -89,8 +90,7 @@ def test_continuous_chunk_program_fits_one_chip_at_fig1b(one_chip):
     cfg = SolverConfig(tol=1e-7, max_iters=20_000, tau_adapt=False)
     S = 8
     slab = jax.eval_shape(lambda: slab_alloc(spec, cfg, S))
-    payload = (tuple(_s((S,) + shp) for shp in slab_data_shapes(spec)),
-               _s((S,)), _s((S, spec.n)), _s((S,), jnp.int32),
+    payload = (_s((S,)), _s((S, spec.n)), _s((S,), jnp.int32),
                _s((S, spec.n)), _s((S,)))
     args = (slab, _s((S,), jnp.bool_), _s((S,), jnp.bool_)) + payload
     placed = jax.tree_util.tree_map(
@@ -101,3 +101,7 @@ def test_continuous_chunk_program_fits_one_chip_at_fig1b(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used <= V5E_HBM_BYTES, used
+    # The arguments hold one data slab (admitted rows are written into
+    # it before the tick), not a second staged copy of it.
+    data = sum(4 * S * math.prod(shp) for shp in slab_data_shapes(spec))
+    assert data < mem.argument_size_in_bytes < 2 * data

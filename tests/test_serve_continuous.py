@@ -287,6 +287,76 @@ def test_slot_writer_packs_one_instance():
     assert np.isinf(np.asarray(slab.state.stat)[0])
 
 
+def test_row_writer_leaves_other_slots_bitwise_unchanged():
+    """Admission's row write touches the admitted slot's data rows and
+    nothing else: every other slot's data and every non-data buffer
+    (state included, mid-solve) come back bitwise as they were."""
+    import jax
+
+    probs = FAMILY_BATCHES["lasso"]()
+    cfg = SolverConfig(max_iters=400, tol=1e-7, tau_adapt=False)
+    eng = ContinuousSolverEngine(
+        cfg, ServeConfig(slab_capacity=4, chunk_iters=8))
+    for p in probs[:4]:
+        eng.submit(to_request(p))
+    eng.step()                                  # four slots mid-solve
+    slab, = eng._slabs.values()
+    before = jax.tree_util.tree_map(np.array, slab.slab)
+    new = probs[4]
+    rows = (np.asarray(new.data["A"]), np.asarray(new.data["b"]))
+    after = slab._row_writer(slab.slab, np.int32(2), *rows)
+    for j, (d0, d1) in enumerate(zip(before.data, after.data)):
+        d1 = np.asarray(d1)
+        np.testing.assert_array_equal(d1[2], rows[j])
+        np.testing.assert_array_equal(np.delete(d1, 2, axis=0),
+                                      np.delete(d0, 2, axis=0))
+    rest = lambda t: jax.tree_util.tree_leaves(t._replace(data=()))
+    for a, b in zip(rest(before), rest(after)):
+        np.testing.assert_array_equal(a, np.asarray(b))
+
+
+def test_row_writer_compiles_once_across_admission_counts():
+    """One row-writer program per signature, however many requests a
+    tick admits (1..S): the slot is a traced scalar, so admission counts
+    never reach the compile cache or jit's trace cache."""
+    S = 4
+    spec_probs = [nesterov_instance(m=12, n=40, nnz_frac=0.2, c=1.0,
+                                    seed=s) for s in range(S)]
+    cfg = SolverConfig(max_iters=50, tol=1e-6)
+    misses0 = B.make_row_writer.stats()["misses"]
+    writers = set()
+    for k in range(1, S + 1):
+        eng = ContinuousSolverEngine(
+            cfg, ServeConfig(slab_capacity=S, chunk_iters=8))
+        for p in spec_probs[:k]:
+            eng.submit(to_request(p))
+        eng.step()
+        slab, = eng._slabs.values()
+        assert int(slab.active.sum()) == k
+        writers.add(slab._row_writer)
+    assert B.make_row_writer.stats()["misses"] == misses0 + 1
+    (write,) = writers
+    assert write._cache_size() == 1
+
+
+@pytest.mark.parametrize("watchdog", [False, True])
+def test_row_admission_matches_solo(watchdog):
+    """Requests admitted through the row writer match their solo
+    solves, with the health watchdog's chunk program and without."""
+    probs = FAMILY_BATCHES["lasso"]()
+    cfg = SolverConfig(max_iters=150, tol=-1.0, tau_adapt=False)
+    eng = ContinuousSolverEngine(
+        cfg, ServeConfig(slab_capacity=2, chunk_iters=16,
+                         watchdog=watchdog))
+    ids = [eng.submit(to_request(p)) for p in probs]
+    resps = eng.drain()
+    for i, p in zip(ids, probs):
+        assert resps[i].iters == 150 and resps[i].status == "ok"
+        solo = solve(p, method="flexa", cfg=cfg)
+        np.testing.assert_allclose(np.asarray(resps[i].x),
+                                   np.asarray(solo.x), atol=1e-5)
+
+
 # ------------------------------------------------------------------ #
 # Compile caches: bounded + instrumented                             #
 # ------------------------------------------------------------------ #
@@ -316,7 +386,8 @@ def test_compile_cache_bounded_by_env(monkeypatch):
     assert cache.maxsize() == cache.default_maxsize
 
     snap = cache_stats()
-    for name in ("batched_solver", "chunk_stepper", "slot_writer"):
+    for name in ("batched_solver", "chunk_stepper", "slot_writer",
+                 "row_writer"):
         assert {"hits", "misses", "evictions", "size",
                 "maxsize"} <= set(snap[name])
 

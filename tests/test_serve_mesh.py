@@ -257,25 +257,30 @@ def test_client_mesh_backend_matches_inline():
 
 
 def test_staging_payload_never_aliases_host_buffers():
-    """Regression for the PR-3 race class: jnp.asarray zero-copies
-    aligned numpy buffers on CPU, so a device payload aliasing a staging
-    buffer would let the next tick's admission scribble over data an
-    async dispatch is still reading.  Admit under load (queue > slots,
-    every visible device), then check no payload array shares memory
-    with any staging buffer."""
+    """Regression for a staging race: jnp.asarray zero-copies aligned
+    numpy buffers on CPU, so a device payload aliasing a staging buffer
+    would let the next tick's admission scribble over data an async
+    dispatch is still reading.  Admit under load (queue > slots, every
+    visible device), then check no payload array shares memory with any
+    staging buffer, and the slab's data with no request's arrays (the
+    rows are written into the slab, not referenced)."""
     probs = FAMILY_BATCHES["lasso"]()
     eng = MeshServeEngine(CFG, mesh_serve(slab_capacity=1,
                                           mesh_devices=0))
-    ids = [eng.submit(to_request(p)) for p in probs]
+    reqs = [to_request(p) for p in probs]
+    ids = [eng.submit(r) for r in reqs]
     eng.step()                               # admissions staged + shipped
     for slab in eng._slabs.values():
-        host = list(slab._stage_data) + [
-            slab._stage_c, slab._stage_x0, slab._stage_ids,
-            slab._stage_active]
-        dev = list(slab._payload[0]) + list(slab._payload[1:])
-        for arr in dev:
+        assert not slab._stage_rows          # every staged row shipped
+        host = [slab._stage_c, slab._stage_x0, slab._stage_ids,
+                slab._stage_active, slab._stage_tol, slab._admit]
+        for arr in slab._payload:
             view = np.asarray(arr)           # zero-copy view on CPU
             assert not any(np.shares_memory(view, h) for h in host)
+        given = [a for r in reqs for a in (r.A, r.b)]
+        for arr in slab.slab.data:
+            view = np.asarray(arr)
+            assert not any(np.shares_memory(view, g) for g in given)
     resps = eng.drain()
     assert sorted(resps) == sorted(ids)      # load run still completes
 
@@ -332,6 +337,45 @@ def test_mesh_matches_single_device_continuous_all_families(family):
         np.testing.assert_allclose(np.asarray(rm[a].x),
                                    np.asarray(rc[b].x), atol=1e-5,
                                    err_msg=f"{family} request {a}")
+
+
+@multi_device
+def test_admitted_rows_land_on_their_owning_device_only():
+    """An admission writes its rows into the shard of the device that
+    owns its slot (slot // S_dev) and nowhere else: the other devices'
+    shards are bitwise unchanged and every shard stays on its device."""
+    probs = FAMILY_BATCHES["lasso"]()
+    eng = MeshServeEngine(CFG, ServeConfig(
+        slab_capacity=2, chunk_iters=16, mesh_devices=4,
+        mesh_routing="round_robin"))
+    reqs = [to_request(p) for p in probs[:2]]
+    for r in reqs:
+        eng.submit(r)
+    eng.step()                       # round robin: device 0, device 1
+    assert [rec["device"] for rec in eng.audit] == [0, 1]
+    slab, = eng._slabs.values()
+    devices = list(slab._rows.mesh.devices.flat)
+    before = {sh.device: np.array(sh.data)
+              for sh in slab.slab.data[0].addressable_shards}
+    new = probs[2]
+    rows = (np.asarray(new.data["A"]), np.asarray(new.data["b"]))
+    slot = 2 * 2 + 1                 # device 2, local slot 1
+    slab._write_rows(slot, rows)
+    for j, arr in enumerate(slab.slab.data):
+        assert arr.sharding == slab._rows
+        shards = arr.addressable_shards
+        assert [sh.device for sh in shards] == devices
+        np.testing.assert_array_equal(np.asarray(shards[2].data)[1],
+                                      rows[j])
+    for sh in slab.slab.data[0].addressable_shards:
+        got = np.asarray(sh.data)
+        if sh.device == devices[2]:
+            np.testing.assert_array_equal(got[0], before[sh.device][0])
+        else:
+            np.testing.assert_array_equal(got, before[sh.device])
+    np.testing.assert_array_equal(
+        np.asarray(slab.slab.data[0].addressable_shards[0].data)[0],
+        reqs[0].A)
 
 
 @multi_device
@@ -466,6 +510,15 @@ SUBPROC_SRC = textwrap.dedent("""
                                                  chunk_iters=16))
     im = [em.submit(r) for r in reqs]
     ic = [ec.submit(r) for r in reqs]
+    em.step()
+    # first tick: request i admitted to device i, its rows on that
+    # device's shard alone
+    A = next(iter(em._slabs.values())).slab.data[0]
+    rows_on_owner = all(
+        np.array_equal(np.asarray(sh.data)[0], reqs[d].A)
+        for d, sh in enumerate(A.addressable_shards)) and [
+        sh.device for sh in A.addressable_shards] == list(
+        A.sharding.mesh.devices.flat)
     rm, rc = em.drain(), ec.drain()
     snap = em.telemetry.snapshot()
     per = snap["mesh"]["per_device"]
@@ -487,6 +540,7 @@ SUBPROC_SRC = textwrap.dedent("""
             snap["continuous"][k] == sum(p[k] for p in per)
             for k in keys),
         "devices": snap["mesh"]["devices"],
+        "rows_on_owner": rows_on_owner,
     }))
 """)
 
@@ -509,6 +563,7 @@ def test_mesh_four_device_subprocess():
     assert rec["iters_equal"] and rec["one_service"]
     assert rec["conservation"]
     assert rec["ledger_exact"]
+    assert rec["rows_on_owner"]
 
 
 def test_mesh_slab_never_migrates():

@@ -5,7 +5,8 @@
   iteration, and its results are bitwise the same traced or not;
 * **admission** — the continuous engine emits one ``serve.stage`` per
   admission and one ``serve.upload`` per admitting tick (none on a
-  plain tick), and ``serve.chunk`` no longer holds the upload;
+  plain tick) that ships the tick's admitted rows alone, and
+  ``serve.chunk`` no longer holds the upload;
 * **ledger** — the continuous and mesh engines split their row
   iterations exactly: live = Σ iterations of the answers, freeze =
   occupied rows − live, padding = empty rows;
@@ -153,12 +154,37 @@ def test_continuous_stage_and_upload_spans():
         names = by_tick.get(t.span_id, [])
         assert names.count("serve.upload") == (t in admitting)
     assert len(uploads) == len(admitting) >= 2
-    assert all(u.args["bytes"] == slab._payload_bytes for u in uploads)
+    # each upload ships the rows its tick staged, and the vectors
+    for u in uploads:
+        k = by_tick[u.parent_id].count("serve.stage")
+        assert u.args["rows"] == k >= 1
+        assert u.args["bytes"] == k * slab._row_bytes + slab._vector_bytes
     # serve.chunk starts after the upload it follows has ended
     for u in uploads:
         chunk = next(s for s in tr.spans if s.name == "serve.chunk"
                      and s.span_id > u.span_id)
         assert chunk.t0 >= u.t1
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_upload_ships_only_the_admitted_rows(k):
+    """A tick that admits k of S = 4 requests ships k data rows: the
+    upload's ``rows`` is k and its ``bytes`` k rows (A and b) plus the
+    per-slot vectors, not the whole (S, m, n) slab."""
+    S, m, n = 4, 20, 64
+    eng = ContinuousSolverEngine(SolverConfig(max_iters=400, tol=1e-5),
+                                 ServeConfig(slab_capacity=S,
+                                             chunk_iters=8))
+    for s in range(k):
+        eng.submit(_request(_lasso(s, m=m, n=n)))
+    tr = Tracer()
+    with tracing(tr):
+        eng.step()
+    (up,) = _named(tr, "serve.upload")
+    row = 4 * (m * n + m)
+    vectors = 4 * (S + S * n + S + S * n + S) + S   # c x0 ids active tol admit
+    assert up.args["rows"] == k
+    assert up.args["bytes"] == k * row + vectors
 
 
 def test_continuous_collect_span_per_evicting_tick():
@@ -251,7 +277,7 @@ def test_iteration_scopes_name_the_compiled_operations():
     """The scopes reach the solo step's optimized HLO as op metadata,
     and the chunk program's splice carries its own scope."""
     from repro.solvers.batched import (BatchedProblemSpec, make_chunk_stepper,
-                                       slab_alloc, slab_data_shapes)
+                                       slab_alloc)
     p = _lasso(3)
     cfg = SolverConfig()
     step = flexa.make_step(p, cfg)
@@ -262,8 +288,6 @@ def test_iteration_scopes_name_the_compiled_operations():
     spec = BatchedProblemSpec.of(p)
     S = 2
     args = (slab_alloc(spec, cfg, S), np.ones(S, bool), np.zeros(S, bool),
-            tuple(np.zeros((S,) + shp, np.float32)
-                  for shp in slab_data_shapes(spec)),
             np.zeros(S, np.float32), np.zeros((S, p.n), np.float32),
             np.zeros(S, np.int32), np.ones((S, p.n), np.float32),
             np.full(S, cfg.tol, np.float32))
