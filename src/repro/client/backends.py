@@ -62,13 +62,15 @@ def _dims(problem) -> tuple[int, int]:
     currency every ledger uses.  (0, 0) for ad-hoc problems whose
     leading data array is not a 2-D operator (their device cost is not
     expressible in the shared currency, so it is reported as zero
-    rather than guessed)."""
+    rather than guessed).  Read from the design's shape alone: a
+    device-resident design is not copied, a sparse one not densified."""
     try:
         fam = infer_family(problem)
-        A = np.asarray(problem.data[get_family(fam).data_keys[0]])
+        A = problem.data[get_family(fam).data_keys[0]]
     except (ValueError, KeyError):
         return 0, 0
-    return (int(A.shape[0]), int(A.shape[1])) if A.ndim == 2 else (0, 0)
+    shape = A.shape if hasattr(A, "shape") else np.shape(A)
+    return (int(shape[0]), int(shape[1])) if len(shape) == 2 else (0, 0)
 
 
 def _request_ledger(iter_counts, problems) -> CostLedger:

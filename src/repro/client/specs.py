@@ -29,12 +29,13 @@ from typing import Callable, Sequence
 
 import numpy as np
 
-from repro.client.errors import SpecError
+from repro.client.errors import SpecError, UnsupportedWorkloadError
 from repro.obs.ledger import CostLedger
 from repro.path.driver import PathResult
 from repro.path.screening import DEFAULT_KKT_SLACK
 from repro.problems.base import Problem
 from repro.problems.families import get_family, infer_family
+from repro.problems.sparse import is_sparse
 from repro.serve.engine import SolveRequest
 
 #: Families a *serving* backend can carry (its request payload is the
@@ -241,7 +242,8 @@ def solve_request_of(problem: Problem, *, x0=None, active=None,
     """
     family = infer_family(problem)
     keys = get_family(family).data_keys
-    arrays = [np.asarray(problem.data[k], np.float32) for k in keys]
+    arrays = [problem.data[k] if is_sparse(problem.data[k])
+              else np.asarray(problem.data[k], np.float32) for k in keys]
     return SolveRequest(
         A=arrays[0], b=arrays[1] if len(arrays) > 1 else None,
         c=float(problem.g_weight), block_size=int(problem.block_size),
@@ -259,6 +261,15 @@ def mse_score(validation: Sequence) -> Callable:
         r = np.asarray(Av) @ np.asarray(x) - np.asarray(bv)
         return float(r @ r) / np.asarray(Av).shape[0]
     return score
+
+
+def _require_dense(problems, kind: str) -> None:
+    """Paths and CV sweeps screen and rescale dense designs: a sparse
+    design is served as a solo or a batch."""
+    if any(is_sparse(v) for p in problems for v in p.data.values()):
+        raise UnsupportedWorkloadError(
+            f"a {kind} over a sparse design is not supported; sparse "
+            "designs run as SoloSpec or BatchSpec")
 
 
 def normalize(spec, ticket: int) -> WorkItem:
@@ -286,6 +297,7 @@ def normalize(spec, ticket: int) -> WorkItem:
         if not isinstance(spec.problem, Problem):
             raise SpecError(f"PathSpec.problem must be a Problem, got "
                             f"{type(spec.problem).__name__}")
+        _require_dense([spec.problem], "path")
         return WorkItem(ticket=ticket, kind="path", spec=spec,
                         problems=[spec.problem],
                         family=_family_of(spec.problem))
@@ -312,6 +324,7 @@ def normalize(spec, ticket: int) -> WorkItem:
                 "CVSpec.tol_coarse and CVSpec.tol_schedule are mutually "
                 "exclusive: an explicit per-point schedule would "
                 "silently override the coarse sweep tolerance")
+        _require_dense(probs, "CV sweep")
         fams = {_family_of(p) for p in probs}
         return WorkItem(ticket=ticket, kind="cv", spec=spec,
                         problems=probs,

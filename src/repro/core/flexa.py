@@ -31,6 +31,7 @@ from repro.core import selection, stepsize
 from repro.core.surrogate import best_response, curvature
 from repro.obs import trace as obs
 from repro.problems.base import Problem
+from repro.problems.sparse import capacity_bucket, is_sparse, stack_designs
 from repro.core.result import SolverResult
 
 
@@ -247,7 +248,7 @@ def make_step(problem: Problem, cfg: SolverConfig, active=None):
     # A batch of one, vmapped like the batched engine's iteration: the
     # products then reduce in the order they do there, which keeps solo
     # and batched trajectories together.
-    arrays = tuple(jnp.asarray(problem.data[k])[None] for k in fam.data_keys)
+    arrays = tuple(_batch_of_one(problem.data[k]) for k in fam.data_keys)
     col_sq = jax.vmap(fam.col_sq)(*arrays)
 
     def instance_step(arrays, col_sq, state, tau_base, active):
@@ -265,6 +266,14 @@ def make_step(problem: Problem, cfg: SolverConfig, active=None):
 
     return lambda state: family_step(arrays, col_sq, tau_base, active,
                                      state)
+
+
+def _batch_of_one(a):
+    """One instance's data array (or sparse design, in the stored
+    layout) with a leading batch axis of one."""
+    if is_sparse(a):
+        return stack_designs([a], capacity_bucket(a.capacity, a.m, a.n))
+    return jnp.asarray(a)[None]
 
 
 def solve(problem: Problem, x0=None, cfg: SolverConfig | None = None,

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.kernels import flash_attention as _fa
 from repro.kernels import flexa_prox as _fp
 from repro.kernels import ref
+from repro.kernels import spmv as _spmv
 from repro.kernels import ssd_scan as _ssd
 
 
@@ -193,6 +194,39 @@ def compact_best_response(x, g, d, c, idx, *, force=None):
     z2, e2 = _fp.compact_best_response(x2, g2, d2, c, idx,
                                        interpret=interp)
     return z2[:, :C], e2
+
+
+def blocked_product(values, gidx, sidx, tile_g, tile_s, table, n_out, *,
+                    force=None):
+    """One product of a blocked sparse design (``problems.sparse.
+    BlockedDesign``): out[sidx[k]] += values[k]·table[gidx[k]], (n_out,).
+
+    ``values``, ``gidx``, ``sidx``: (L,) stored entries, L a multiple of
+    ``spmv.TILE``; ``tile_g`` / ``tile_s``: (L / TILE,) int32, the block
+    of ``table`` / of the output that tile t's entries index.  On the
+    kernel path each tile's window of ``table`` is cut by a one-hot
+    product, the kernel forms each tile's partial output window, and a
+    second one-hot product adds the windows into the output; both at
+    ``Precision.HIGHEST``, so they move float32 values exactly.
+    """
+    mode = _mode(force)
+    if mode == "ref":
+        return ref.blocked_product_ref(values, gidx, sidx, table, n_out)
+    B, nt = _spmv.BLOCK, values.shape[0] // _spmv.TILE
+    n_g = -(-table.shape[0] // B)
+    n_s = -(-n_out // B)
+    hi = jax.lax.Precision.HIGHEST
+    tab = jnp.pad(table.astype(jnp.float32),
+                  (0, n_g * B - table.shape[0])).reshape(n_g, B)
+    win = jnp.dot(jax.nn.one_hot(tile_g, n_g, dtype=jnp.float32), tab,
+                  precision=hi)
+    part = _spmv.tile_products(
+        values.reshape(nt, 8, 128), gidx.reshape(nt, 8, 128),
+        sidx.reshape(nt, 8, 128), win.reshape(nt, B // 128, 128),
+        interpret=mode == "interpret")
+    out = jnp.dot(jax.nn.one_hot(tile_s, n_s, dtype=jnp.float32).T,
+                  part.reshape(nt, B), precision=hi)
+    return out.reshape(-1)[:n_out]
 
 
 def flash_attention(q, k, v, *, causal=True, scale=None, force=None,

@@ -12,6 +12,16 @@ import jax.numpy as jnp
 
 
 # ------------------------------------------------------------------ #
+# Sparse-design products                                              #
+# ------------------------------------------------------------------ #
+def blocked_product_ref(values, gidx, sidx, table, n_out):
+    """out[sidx[k]] += values[k]·table[gidx[k]] over every stored entry
+    k: A·x with (gidx, sidx) = (cols, rows), Aᵀ·r with (rows, cols)."""
+    return jax.ops.segment_sum(values * table[gidx], sidx,
+                               num_segments=n_out)
+
+
+# ------------------------------------------------------------------ #
 # FLEXA fused prox (the paper's hot spot)                             #
 # ------------------------------------------------------------------ #
 def flexa_best_response_ref(x, g, d, c):

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from repro.problems.base import Problem
 from repro.problems.lasso import quadratic_fns
 from repro.problems.logreg import logistic_fns
+from repro.problems.sparse import design_col_sq
 from repro.problems.svm import squared_hinge_fns
 
 
@@ -58,9 +59,9 @@ class ProblemFamily:
         return self.screen_scores is not None
 
     def col_sq(self, *arrays) -> jnp.ndarray:
-        """‖column‖² of the (m, n) design matrix (arrays[0]) — traceable."""
-        A = arrays[0]
-        return jnp.sum(A * A, axis=0)
+        """‖column‖² of the (m, n) design matrix (arrays[0]), dense or
+        sparse — traceable."""
+        return design_col_sq(arrays[0])
 
     def half_curv(self, col_sq) -> jnp.ndarray:
         """diag_curv/2 — what the §4 default τ rule reduces over (matches

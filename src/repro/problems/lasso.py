@@ -11,6 +11,8 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.problems.base import Problem, mv
+from repro.problems.sparse import (CSCDesign, design_col_sq,
+                                   design_matvec, design_rmatvec, is_sparse)
 
 
 def quadratic_fns(A, b, col_sq=None):
@@ -20,17 +22,22 @@ def quadratic_fns(A, b, col_sq=None):
     ∇F = 2Aᵀ(Ax−b) and ∂²F/∂xᵢ² = 2‖aᵢ‖² (exact for quadratics —
     surrogate choice (6)).  Traceable, so the batched engine can call it
     with per-instance traced slices of (A, b); ``col_sq`` may be
-    precomputed to avoid re-reducing ‖aᵢ‖² inside a solve loop.
+    precomputed to avoid re-reducing ‖aᵢ‖² inside a solve loop.  ``A``
+    is a dense (m, n) array or a sparse design (a :class:`~repro.
+    problems.sparse.BlockedDesign`; a ``CSCDesign`` is laid out on the
+    device first): only the products come from its layout.
     """
+    if isinstance(A, CSCDesign):
+        A = A.blocked()
     if col_sq is None:
-        col_sq = jnp.sum(A * A, axis=0)      # ‖aᵢ‖² per column
+        col_sq = design_col_sq(A)            # ‖aᵢ‖² per column
 
     def f(x):
-        r = mv(A, x) - b
+        r = design_matvec(A, x) - b
         return mv(r, r)
 
     def grad_f(x):
-        return 2.0 * mv(A.T, mv(A, x) - b)
+        return 2.0 * design_rmatvec(A, design_matvec(A, x) - b)
 
     def diag_curv(_):
         return 2.0 * col_sq
@@ -40,12 +47,19 @@ def quadratic_fns(A, b, col_sq=None):
 
 def make_lasso(A, b, c: float, block_size: int = 1,
                v_star=None, x_star=None, name: str = "lasso") -> Problem:
-    A = jnp.asarray(A)
+    """``A`` dense, or a :class:`~repro.problems.sparse.CSCDesign` (kept
+    as given: host arrays stay on the host)."""
+    if not is_sparse(A):
+        A = jnp.asarray(A)
     b = jnp.asarray(b)
-    f, grad_f, diag_curv = quadratic_fns(A, b)
+    # a sparse design's closures read it in the stored layout, laid out
+    # on the device once here
+    stored = A.blocked() if is_sparse(A) else A
+    f, grad_f, diag_curv = quadratic_fns(stored, b)
 
     # L_F = 2·λmax(AᵀA): cheap power-iteration estimate.
-    L = float(2.0 * _power_iter_sq(np.asarray(A)))
+    L = float(2.0 * (_power_iter_sq_sparse(stored) if is_sparse(A)
+                     else _power_iter_sq(np.asarray(A))))
     return Problem(
         name=name, n=A.shape[1], block_size=block_size,
         f=f, grad_f=grad_f, diag_curv=diag_curv,
@@ -70,6 +84,19 @@ def _power_iter_sq(A: np.ndarray, iters: int = 50, seed: int = 0) -> float:
     for _ in range(iters):
         w = M @ v
         lam = float(np.linalg.norm(w))
+        v = w / max(lam, 1e-30)
+    return lam
+
+
+def _power_iter_sq_sparse(A, iters: int = 50, seed: int = 0) -> float:
+    """λmax(AᵀA) of a sparse design (stored layout), by its own
+    products."""
+    v = np.random.default_rng(seed).standard_normal(A.n).astype(np.float32)
+    v = jnp.asarray(v / np.linalg.norm(v))
+    lam = 0.0
+    for _ in range(iters):
+        w = A.rmatvec(A.matvec(v))
+        lam = float(jnp.linalg.norm(w))
         v = w / max(lam, 1e-30)
     return lam
 

@@ -82,8 +82,14 @@ def encode_problem(p) -> dict:
     so the remote backend loses no capability the server could honor.
     """
     from repro.problems.families import get_family, infer_family
+    from repro.problems.sparse import is_sparse
     family = infer_family(p)
     keys = get_family(family).data_keys
+    if any(is_sparse(p.data[k]) for k in keys):
+        raise ProtocolError(
+            f"problem {p.name!r} has a sparse design: the wire carries "
+            "dense arrays only, and a sparse design is not densified on "
+            "its way; serve it in process (backend 'continuous')")
     return {"family": family,
             "g_kind": p.g_kind,
             "block_size": int(p.block_size),

@@ -67,8 +67,8 @@ from repro.serve.engine import SolveRequest, SolveResponse, validate_request
 from repro.serve.pathstate import PathRequest, PathState
 from repro.serve.metrics import ServeTelemetry
 from repro.solvers.batched import (BatchedProblemSpec, make_chunk_stepper,
-                                   make_row_writer, slab_alloc,
-                                   slab_data_shapes, slab_migrate)
+                                   make_row_writer, shipped_row_bytes,
+                                   slab_alloc, slab_migrate)
 from repro.solvers.compaction import bucket_capacity
 
 
@@ -202,17 +202,18 @@ class _SlotSlab:
         S = self.capacity
         spec = self.spec
         self._stage_rows: dict[int, tuple] = {}
+        self._stage_nnz: dict[int, int] = {}     # sparse slabs only
         self._stage_c = np.zeros(S, np.float32)
         self._stage_x0 = np.zeros((S, spec.n), np.float32)
         self._stage_active = np.ones((S, spec.n), np.float32)
         self._stage_tol = np.full(S, self.cfg.tol, np.float32)
         self._stage_ids = np.zeros(S, np.int32)
         self._admit = np.zeros(S, bool)
-        # Bytes of one admission's data rows, and of the per-slot
-        # vectors an admitting tick ships: serve.stage's ``bytes`` is
-        # the former, serve.upload's is rows × the former + the latter.
-        self._row_bytes = 4 * sum(math.prod(shp)
-                                  for shp in slab_data_shapes(spec))
+        # Bytes of one admission's data rows (a sparse design padded to
+        # the slab's nnz capacity), and of the per-slot vectors an
+        # admitting tick ships: serve.stage's ``bytes`` is the former,
+        # serve.upload's is rows × the former + the latter.
+        self._row_bytes = shipped_row_bytes(spec)
         self._vector_bytes = sum(
             b.nbytes for b in (self._stage_c, self._stage_x0,
                                self._stage_ids, self._stage_active,
@@ -408,6 +409,11 @@ class _SlotSlab:
         with obs.span("serve.stage", cat="continuous", req_id=entry.req_id,
                       slot=slot, bytes=self._row_bytes):
             self._stage_rows[slot] = r.data_arrays(self.spec)
+            if self.spec.layout != "dense":
+                nnz = r.A.nnz
+                self._stage_nnz[slot] = nnz
+                self.telemetry.record_nnz(stored=nnz,
+                                          capacity=self.spec.nnz_cap)
             self._stage_c[slot] = r.c
             self._stage_x0[slot] = 0.0 if x0 is None \
                 else np.asarray(x0, np.float32)
@@ -476,10 +482,13 @@ class _SlotSlab:
         # writes to a request's arrays.
         if self._admit.any():
             slots = [int(s) for s in np.flatnonzero(self._admit)]
+            sparse = {} if self.spec.layout == "dense" else {
+                "nnz": sum(self._stage_nnz.pop(s) for s in slots),
+                "nnz_cap": len(slots) * self.spec.nnz_cap}
             with obs.span("serve.upload", cat="continuous", tick=tick,
                           rows=len(slots),
                           bytes=len(slots) * self._row_bytes
-                          + self._vector_bytes):
+                          + self._vector_bytes, **sparse):
                 for slot in slots:
                     self._write_rows(slot, self._stage_rows.pop(slot))
                 self._payload = self._stage_payload()
@@ -628,6 +637,7 @@ class _SlotSlab:
                     # answer with the staged x0 and cancel the admit.
                     self._admit[slot] = False
                     self._stage_rows.pop(slot, None)
+                    self._stage_nnz.pop(slot, None)
                     resp = SolveResponse(
                         x=self._stage_x0[slot].copy(), iters=0,
                         converged=False, stat=float("inf"),

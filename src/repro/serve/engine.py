@@ -44,8 +44,10 @@ from repro.models import io as IO
 from repro.obs import trace as obs_trace
 from repro.models import transformer as T
 from repro.problems.families import get_family
+from repro.problems.sparse import is_sparse
 from repro.serve.metrics import ServeTelemetry
-from repro.solvers.batched import BatchedProblemSpec, make_batched_solver
+from repro.solvers.batched import (BatchedProblemSpec, make_batched_solver,
+                                   stack_data)
 
 
 @dataclass
@@ -138,7 +140,10 @@ class SolveRequest:
     ``family`` picks F (``repro.problems.families``): the quadratic
     families ("lasso"/"group_lasso") read ``A`` as the design matrix and
     need ``b``; "logreg"/"svm" read ``A`` as the label-signed feature
-    matrix Z = diag(a)·Y and take no ``b``.
+    matrix Z = diag(a)·Y and take no ``b``.  The quadratic families'
+    ``A`` may be a :class:`~repro.problems.sparse.CSCDesign` (host
+    arrays, as a tenant sends it): such a request is served in a slab of
+    sparse designs of its nnz bucket (``BatchedProblemSpec.nnz_cap``).
 
     ``priority``/``deadline`` are scheduling hints consumed by the
     continuous runtime's admission queue (``repro.serve.continuous``);
@@ -153,7 +158,8 @@ class SolveRequest:
     ``repro.path``): zero coordinates are excluded from selection,
     updates and the termination measure.
     """
-    A: np.ndarray               # (m, n) design / signed-feature matrix
+    A: np.ndarray               # (m, n) design / signed-feature matrix,
+    #                             or a CSCDesign (quadratic families)
     b: np.ndarray | None = None  # (m,) observations (quadratic families)
     c: float = 1.0              # regularization weight
     block_size: int = 1         # 1 ⇒ ℓ1; >1 ⇒ group-ℓ2 blocks
@@ -175,9 +181,8 @@ class SolveRequest:
     def spec(self) -> BatchedProblemSpec:
         family = self.family or (
             "lasso" if self.block_size == 1 else "group_lasso")
-        return BatchedProblemSpec(
-            m=int(self.A.shape[0]), n=int(self.A.shape[1]),
-            block_size=self.block_size,
+        return BatchedProblemSpec.for_design(
+            self.A, n=int(self.A.shape[1]), block_size=self.block_size,
             g_kind="l1" if self.block_size == 1 else "group_l2",
             family=family)
 
@@ -193,9 +198,12 @@ class SolveRequest:
         arrays stay on the host (no copy when already float32), device
         arrays on their device.  The caller places them, so the
         continuous slab can ship a row straight to the device that owns
-        its slot.
+        its slot.  A sparse design comes back padded to the slab's nnz
+        capacity.
         """
         def f32(a):
+            if is_sparse(a):
+                return a.padded(spec.nnz_cap)
             if isinstance(a, jax.Array):
                 return a.astype(jnp.float32)
             return np.asarray(a, np.float32)
@@ -237,6 +245,11 @@ def validate_request(i: "int | None", r: SolveRequest,
     request's position within a wave (``None`` for single-request
     submission paths, where an index would mislead)."""
     where = "request" if i is None else f"request {i}"
+    if is_sparse(r.A):
+        try:
+            r.A.check()
+        except ValueError as e:
+            raise ValueError(f"{where}: {e}") from None
     needs_b = "b" in get_family(spec.family).data_keys
     if needs_b and np.shape(r.b) != (spec.m,):
         raise ValueError(
@@ -392,8 +405,7 @@ class SolverServeEngine:
                 rows = [requests[i] for i in chunk] \
                     + [requests[chunk[0]]] * pad
                 per_req = [r.data_arrays(spec) for r in rows]
-                data = tuple(jnp.stack([arrs[j] for arrs in per_req])
-                             for j in range(len(per_req[0])))
+                data = stack_data(per_req, spec)
                 c = jnp.asarray([float(r.c) for r in rows], jnp.float32)
                 x0 = jnp.stack([
                     jnp.zeros((spec.n,), jnp.float32) if r.x0 is None

@@ -140,6 +140,11 @@ class ServeTelemetry:
     chunk_flops: int = 0            # Σ K·capacity·m·n (matvec currency)
     chunk_wall: float = 0.0
     migrations: int = 0             # drain-tail slab capacity changes
+    # sparse-design admissions: nonzeros stored, and the slab capacity
+    # they occupied (their nnz bucket); 1 − stored/capacity is the
+    # layout's padding
+    nnz_stored: int = 0
+    nnz_capacity: int = 0
     # wave-engine per-bucket records
     waves: list = field(default_factory=list)
     # opt-in per-chunk residual sampling (dashboard sparklines); off by
@@ -266,6 +271,12 @@ class ServeTelemetry:
         """Iterations one evicted request advanced (its ``k``)."""
         self.chunk_advanced_iters += int(iters)
 
+    def record_nnz(self, *, stored: int, capacity: int) -> None:
+        """One sparse design admitted: its nonzeros, and the nnz
+        capacity of the slot it occupies."""
+        self.nnz_stored += int(stored)
+        self.nnz_capacity += int(capacity)
+
     def record_migration(self, *, from_capacity: int,
                          to_capacity: int) -> None:
         """One drain-tail slab migration (capacities for dashboards only;
@@ -376,6 +387,11 @@ class ServeTelemetry:
             out["windows"] = w.snapshot(self.now())
         if self.chunks:
             out["continuous"] = _chunk_summary(self)
+        if self.nnz_capacity:
+            out["sparse"] = {
+                "nnz_stored": self.nnz_stored,
+                "nnz_capacity": self.nnz_capacity,
+                "nnz_pad_share": 1.0 - self.nnz_stored / self.nnz_capacity}
         if self.waves:
             row = sum(w["row_iters"] for w in self.waves)
             useful = sum(w["useful_row_iters"] for w in self.waves)

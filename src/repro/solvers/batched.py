@@ -55,6 +55,7 @@ bit-identical paths.
 """
 from __future__ import annotations
 
+import math
 import time
 from dataclasses import dataclass
 from functools import partial
@@ -72,6 +73,9 @@ from repro.obs.health import (HealthConfig, STATUS_RUNNING,
                               STATUS_STOPPED, STATUS_DIVERGED,
                               STATUS_STALLED)
 from repro.problems.families import build_problem, get_family, infer_family
+from repro.problems.sparse import (TILE, BlockedDesign, CSCDesign,
+                                   block_layout, design_layout, is_sparse,
+                                   stack_designs)
 from repro.solvers.cache import CompileCache
 from repro.solvers.result import SolverResult
 
@@ -82,14 +86,35 @@ class BatchedProblemSpec:
 
     Shapes must match for vmap/stacking; ``family`` selects the F closures
     and the G structure selects the prox (soft-threshold vs group
-    shrinkage) baked into the compiled program.  Hashable on purpose: it is
-    the compile-cache key.
+    shrinkage) baked into the compiled program.  ``layout`` is how the
+    design is stored: ``"dense"`` (m, n), or ``"csc"``: sent
+    column-compressed (:class:`~repro.problems.sparse.CSCDesign`) and
+    stored blocked (:class:`~repro.problems.sparse.BlockedDesign`), in
+    ``nnz_cap`` stored entries, a power-of-two bucket, so designs of
+    nearly equal nnz share one slab.  Hashable on purpose: it is the
+    compile-cache key.
     """
     m: int
     n: int
     block_size: int = 1
     g_kind: str = "l1"
     family: str = "lasso"
+    layout: str = "dense"
+    nnz_cap: int = 0
+
+    @classmethod
+    def for_design(cls, design, *, n: int, block_size: int, g_kind: str,
+                   family: str) -> "BatchedProblemSpec":
+        """The signature of a design (dense array or sparse), read from
+        its shapes alone: nothing is copied."""
+        layout, nnz_cap = design_layout(design)
+        if layout != "dense" and family not in SPARSE_FAMILIES:
+            raise ValueError(
+                f"family {family!r} takes a dense design; sparse designs "
+                f"serve the families {SPARSE_FAMILIES}")
+        return cls(m=int(design.shape[0]), n=int(n),
+                   block_size=int(block_size), g_kind=str(g_kind),
+                   family=family, layout=layout, nnz_cap=nnz_cap)
 
     @classmethod
     def of(cls, problem: Problem) -> "BatchedProblemSpec":
@@ -100,10 +125,14 @@ class BatchedProblemSpec:
             raise ValueError(
                 f"batched FLEXA on family {family!r} needs problem data "
                 f"{fam.data_keys} (got {problem.name!r} missing {missing})")
-        design = problem.data[fam.data_keys[0]]
-        return cls(m=int(design.shape[0]), n=int(problem.n),
-                   block_size=int(problem.block_size),
-                   g_kind=str(problem.g_kind), family=family)
+        return cls.for_design(problem.data[fam.data_keys[0]],
+                              n=problem.n, block_size=problem.block_size,
+                              g_kind=problem.g_kind, family=family)
+
+
+#: Families whose design may be sparse: the quadratic ones, whose F
+#: reads its design through the layout's products alone.
+SPARSE_FAMILIES = ("lasso", "group_lasso")
 
 
 def family_problem(arrays, c, spec: BatchedProblemSpec,
@@ -237,21 +266,58 @@ class SlabState(NamedTuple):
         return int(self.c.shape[0])
 
 
-def slab_data_shapes(spec: BatchedProblemSpec) -> tuple:
-    """Per-instance shapes of the family data arrays, in ``data_keys``
-    order: the leading key is the (m, n) design/feature matrix, ``b`` is
-    the (m,) observation vector."""
-    shapes = []
+def slab_data_template(spec: BatchedProblemSpec) -> tuple:
+    """Per-instance family data as a slab stores it, in ``data_keys``
+    order, as ``jax.ShapeDtypeStruct`` leaves: the leading key is the
+    design — a dense (m, n) array, or a
+    :class:`~repro.problems.sparse.BlockedDesign` of ``nnz_cap`` stored
+    entries — and ``b`` is the (m,) observation vector."""
+    f32, i32 = jnp.float32, jnp.int32
+    out = []
     for j, key in enumerate(get_family(spec.family).data_keys):
-        if j == 0:
-            shapes.append((spec.m, spec.n))
+        if j == 0 and spec.layout == "dense":
+            out.append(jax.ShapeDtypeStruct((spec.m, spec.n), f32))
+        elif j == 0 and spec.layout == "csc":
+            L, T = spec.nnz_cap, spec.nnz_cap // TILE
+            out.append(BlockedDesign(
+                jax.ShapeDtypeStruct((L,), f32),
+                jax.ShapeDtypeStruct((L,), i32),
+                jax.ShapeDtypeStruct((L,), i32),
+                jax.ShapeDtypeStruct((T,), i32),
+                jax.ShapeDtypeStruct((T,), i32), (spec.m, spec.n)))
         elif key == "b":
-            shapes.append((spec.m,))
+            out.append(jax.ShapeDtypeStruct((spec.m,), f32))
         else:
             raise NotImplementedError(
                 f"no slab layout for data key {key!r} of family "
-                f"{spec.family!r}")
-    return tuple(shapes)
+                f"{spec.family!r} (layout {spec.layout!r})")
+    return tuple(out)
+
+
+def shipped_row_bytes(spec: BatchedProblemSpec) -> int:
+    """Bytes one admitted request's data rows take on their way to the
+    slab: the dense arrays as the slab stores them, or a sparse design
+    column-compressed (values and rows padded to ``nnz_cap``, n + 1
+    column pointers), which the row writer lays out on the device."""
+    if spec.layout == "dense":
+        return sum(math.prod(t.shape) * t.dtype.itemsize
+                   for t in slab_data_template(spec))
+    return 8 * spec.nnz_cap + 4 * (spec.n + 1) + 4 * spec.m
+
+
+def _stored(rows: tuple) -> tuple:
+    """Shipped rows as the slab stores them: a column-compressed design
+    in the blocked layout (traceable)."""
+    return tuple(block_layout(r.values, r.rows, r.col_ptr, r.shape)
+                 if isinstance(r, CSCDesign) else r for r in rows)
+
+
+def _set_rows(data: tuple, slot, rows: tuple) -> tuple:
+    """``data`` with slot ``slot`` of every leaf set from ``rows``."""
+    return tuple(
+        jax.tree_util.tree_map(lambda d, r: d.at[slot].set(r.astype(d.dtype)),
+                               d, r)
+        for d, r in zip(data, _stored(rows)))
 
 
 def slab_alloc(spec: BatchedProblemSpec, cfg: SolverConfig,
@@ -266,8 +332,9 @@ def slab_alloc(spec: BatchedProblemSpec, cfg: SolverConfig,
     per request (the multi-tenant mixed-tolerance path).
     """
     S = int(capacity)
-    data = tuple(jnp.zeros((S,) + shp, jnp.float32)
-                 for shp in slab_data_shapes(spec))
+    data = jax.tree_util.tree_map(
+        lambda t: jnp.zeros((S,) + t.shape, t.dtype),
+        slab_data_template(spec))
     c = jnp.ones((S,), jnp.float32)
     col_sq = jnp.ones((S, spec.n), jnp.float32)
     tau_base = jnp.ones((S, spec.n), jnp.float32)
@@ -294,6 +361,7 @@ def _build_slot_writer(spec: BatchedProblemSpec, cfg: SolverConfig):
     @partial(jax.jit, donate_argnums=(0,))
     def write(slab: SlabState, slot, new_data, new_c, new_x0, key,
               new_active=None, new_tol=None):
+        new_data = _stored(new_data)
         problem = family_problem(new_data, new_c, spec)
         inst = _flexa.init_state(problem, new_x0, cfg, key=key)
         csq = fam.col_sq(*new_data)
@@ -303,8 +371,7 @@ def _build_slot_writer(spec: BatchedProblemSpec, cfg: SolverConfig):
         if new_tol is None:
             new_tol = jnp.float32(cfg.tol)
         return SlabState(
-            data=tuple(d.at[slot].set(nd.astype(d.dtype))
-                       for d, nd in zip(slab.data, new_data)),
+            data=_set_rows(slab.data, slot, new_data),
             c=slab.c.at[slot].set(new_c),
             col_sq=slab.col_sq.at[slot].set(csq),
             tau_base=slab.tau_base.at[slot].set(tb),
@@ -333,13 +400,14 @@ def _build_row_writer(spec: BatchedProblemSpec):
     donated, so one program per signature serves every slot and every
     admission count, and the write is a ``dynamic_update_slice`` into
     the resident buffer.  Every other slot's data and every non-data
-    buffer pass through unchanged.
+    buffer pass through unchanged.  A sparse design arrives padded to
+    the slab's nnz capacity (``SolveRequest.data_arrays``), so every design of
+    one bucket shares the program; the column of each entry is filled
+    in here, once per admission.
     """
     @partial(jax.jit, donate_argnums=(0,))
     def write_rows(slab: SlabState, slot, *rows):
-        return slab._replace(data=tuple(
-            d.at[slot].set(r.astype(d.dtype))
-            for d, r in zip(slab.data, rows)))
+        return slab._replace(data=_set_rows(slab.data, slot, rows))
 
     return write_rows
 
@@ -585,7 +653,8 @@ def _build_sharded_chunk_stepper(spec: BatchedProblemSpec,
     mesh = make_mesh((int(n_devices),), ("serve",))
     row = PartitionSpec("serve")       # shard dim 0, replicate the rest
     slab_specs = SlabState(
-        data=tuple(row for _ in slab_data_shapes(spec)),
+        data=jax.tree_util.tree_map(lambda _: row,
+                                    slab_data_template(spec)),
         c=row, col_sq=row, tau_base=row,
         state=FlexaState(*([row] * len(FlexaState._fields))),
         active=row, tol=row)
@@ -666,7 +735,7 @@ def slab_migrate(slab: SlabState, slots, spec: BatchedProblemSpec,
         return dst.at[:k].set(jnp.take(src, sel, axis=0).astype(dst.dtype))
 
     return SlabState(
-        data=tuple(move(d, s) for d, s in zip(fresh.data, slab.data)),
+        data=jax.tree_util.tree_map(move, fresh.data, slab.data),
         c=move(fresh.c, slab.c),
         col_sq=move(fresh.col_sq, slab.col_sq),
         tau_base=move(fresh.tau_base, slab.tau_base),
@@ -685,11 +754,21 @@ def _stack_instances(problems: Sequence[Problem]):
                 f"all instances in a batch must share one shape signature; "
                 f"got {spec} and {other}")
     fam = get_family(spec.family)
-    data = tuple(
-        jnp.stack([jnp.asarray(p.data[k], jnp.float32) for p in problems])
-        for k in fam.data_keys)
+    data = stack_data([tuple(p.data[k] for k in fam.data_keys)
+                       for p in problems], spec)
     c = jnp.asarray([float(p.g_weight) for p in problems], jnp.float32)
     return spec, data, c
+
+
+def stack_data(per_instance: Sequence[tuple],
+               spec: BatchedProblemSpec) -> tuple:
+    """Stack per-instance family data tuples along a new leading axis:
+    float32 arrays, or sparse designs padded to ``spec.nnz_cap`` with
+    their columns filled in."""
+    return tuple(
+        stack_designs(col, spec.nnz_cap) if is_sparse(col[0])
+        else jnp.stack([jnp.asarray(a, jnp.float32) for a in col])
+        for col in zip(*per_instance))
 
 
 def _solve_batched(problems: Sequence[Problem], x0=None,

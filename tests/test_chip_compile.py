@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.config.base import SolverConfig
 from repro.kernels import flexa_prox, ssd_scan
 from repro.solvers.batched import (BatchedProblemSpec, make_chunk_stepper,
-                                   slab_alloc, slab_data_shapes)
+                                   slab_alloc, slab_data_template)
 
 V5E_HBM_BYTES = 16 * 1024 ** 3
 
@@ -103,5 +103,41 @@ def test_continuous_chunk_program_fits_one_chip_at_fig1b(one_chip):
     assert used <= V5E_HBM_BYTES, used
     # The arguments hold one data slab (admitted rows are written into
     # it before the tick), not a second staged copy of it.
-    data = sum(4 * S * math.prod(shp) for shp in slab_data_shapes(spec))
+    data = _slab_data_bytes(spec, S)
     assert data < mem.argument_size_in_bytes < 2 * data
+
+
+def _slab_data_bytes(spec, S: int) -> int:
+    """Bytes of an S-slot slab's family data."""
+    return S * sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                   for leaf in jax.tree_util.tree_leaves(
+                       slab_data_template(spec)))
+
+
+def test_sparse_chunk_program_fits_one_chip_at_rcv1(one_chip, monkeypatch):
+    """The served sparse cell's chunk program (rcv1-shaped designs in a
+    2²¹-entry slab, S = 32) compiles for the chip, with the products'
+    Pallas kernel, and fits it; its arguments hold the sparse slab (12
+    bytes a stored entry), a fifth of one dense design of that shape."""
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")   # as on the chip
+    spec = BatchedProblemSpec(m=20_242, n=47_236, layout="csc",
+                              nnz_cap=2 ** 21)
+    cfg = SolverConfig(tol=2e-3, max_iters=2000)
+    S = 32
+    slab = jax.eval_shape(lambda: slab_alloc(spec, cfg, S))
+    payload = (_s((S,)), _s((S, spec.n)), _s((S,), jnp.int32),
+               _s((S, spec.n)), _s((S,)))
+    args = (slab, _s((S,), jnp.bool_), _s((S,), jnp.bool_)) + payload
+    placed = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    compiled = make_chunk_stepper(spec, cfg, 16).lower(*placed).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used <= V5E_HBM_BYTES, used
+    data = _slab_data_bytes(spec, S)
+    assert data == S * (12 * 2 ** 21 + 8 * 2 ** 21 // 1024 + 4 * spec.m)
+    assert data < mem.argument_size_in_bytes < 2 * data
+    assert data < 4 * spec.m * spec.n
