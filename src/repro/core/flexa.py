@@ -37,6 +37,7 @@ from repro.core.result import SolverResult
 
 class FlexaState(NamedTuple):
     x: jnp.ndarray
+    u: jnp.ndarray              # the design product at x (Problem.product)
     gamma: jnp.ndarray          # scalar γᵏ
     tau_scale: jnp.ndarray      # scalar multiplier on the base τ vector
     v_prev: jnp.ndarray         # V(xᵏ)
@@ -86,11 +87,13 @@ def init_state(problem: Problem, x0, cfg: SolverConfig,
     x0 = jnp.asarray(x0, dtype=jnp.float32)
     if key is None:
         key = jax.random.PRNGKey(cfg.seed)
+    u0 = problem.product(x0)
     return FlexaState(
         x=x0,
+        u=u0,
         gamma=jnp.asarray(cfg.gamma0, jnp.float32),
         tau_scale=jnp.asarray(1.0, jnp.float32),
-        v_prev=jnp.asarray(problem.v(x0), jnp.float32),
+        v_prev=jnp.asarray(problem.v_at(u0, x0), jnp.float32),
         consec_dec=jnp.asarray(0, jnp.int32),
         n_tau_changes=jnp.asarray(0, jnp.int32),
         k=jnp.asarray(0, jnp.int32),
@@ -120,6 +123,12 @@ def flexa_iteration(problem: Problem, cfg: SolverConfig,
     to the unmasked iteration; a mask of all-ones multiplies by exact
     fp32 1.0s, so it is bit-identical too.
 
+    The state carries ``u``, the problem's design product at ``x``
+    (``Problem.product``: A·x − b, or Z·x).  The gradient reads it, and
+    the objective computes it at the new iterate, which the next
+    iteration's gradient reads: an iteration makes one transpose product
+    (under ``grad``) and one forward product (under ``objective``).
+
     Each step runs under a ``jax.named_scope`` (``grad``,
     ``best_response``, ``select``, ``update``, ``objective``, ``tau``):
     the scope names the step in the compiled operations' metadata, which
@@ -128,7 +137,7 @@ def flexa_iteration(problem: Problem, cfg: SolverConfig,
     x = state.x
     tau = tau_base * state.tau_scale
     with jax.named_scope("grad"):
-        grad = problem.grad_f(x)
+        grad = problem.loss_grad(state.u)
     with jax.named_scope("best_response"):
         d = curvature(problem, tau, cfg.surrogate)
     if active is not None:
@@ -170,7 +179,8 @@ def flexa_iteration(problem: Problem, cfg: SolverConfig,
     with jax.named_scope("update"):
         xnew = x + state.gamma * mask * (zhat - x)
     with jax.named_scope("objective"):
-        v_new = problem.v(xnew)
+        u_new = problem.product(xnew)
+        v_new = problem.v_at(u_new, xnew)
 
     # §4 τ-controller (finitely many changes).
     with jax.named_scope("tau"):
@@ -196,6 +206,7 @@ def flexa_iteration(problem: Problem, cfg: SolverConfig,
         stat = jnp.max(step_err)
     new_state = FlexaState(
         x=xnew,
+        u=u_new,
         gamma=stepsize.gamma_next(state.gamma, cfg.theta),
         tau_scale=tau_scale,
         v_prev=v_new,

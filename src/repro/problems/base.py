@@ -5,11 +5,16 @@ per-coordinate curvature majorizer used by exact-block/Newton surrogates) and
 the block-separable nonsmooth part ``G`` (kind + weight).  All callables are
 pure jnp functions of the flat variable vector, so they can be jitted,
 differentiated, and sharded.
+
+F is also held as a loss of one product of x (:class:`SmoothF`): every
+registered family reads x through one product with its design, u = A·x − b
+or u = Z·x, and the iteration carries u from one step to the next, so that
+it reads the design twice a step.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +31,32 @@ def mv(a, b):
     design matrix goes through here.
     """
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+class SmoothF(NamedTuple):
+    """A smooth F as a loss of one product of x: u = ``product(x)``,
+    F = ``loss(u)``, ∇F = ``loss_grad(u)`` (which holds the one
+    transpose product).  ``f`` and ``grad_f`` are the compositions, so
+    F has one definition; ``Problem(**fns._asdict())`` installs all
+    six."""
+    f: Callable                 # x -> F(x)
+    grad_f: Callable            # x -> ∇F(x)
+    diag_curv: Callable         # x -> per-coordinate curvature majorizer
+    product: Callable           # x -> u
+    loss: Callable              # u -> F
+    loss_grad: Callable         # u -> ∇F
+
+
+def smooth_f(product, loss, loss_grad, diag_curv) -> SmoothF:
+    """The :class:`SmoothF` of ``F(x) = loss(product(x))``."""
+    return SmoothF(f=lambda x: loss(product(x)),
+                   grad_f=lambda x: loss_grad(product(x)),
+                   diag_curv=diag_curv, product=product, loss=loss,
+                   loss_grad=loss_grad)
+
+
+def _identity(x):
+    return x
 
 
 @dataclass
@@ -48,6 +79,17 @@ class Problem:
     x_star: Optional[jnp.ndarray] = None
     lipschitz: Optional[float] = None   # L_F estimate (FISTA etc.)
     data: dict = field(default_factory=dict)
+    # F as a loss of one product u = product(x) (:class:`SmoothF`); the
+    # iteration carries u.  A problem built from its own ``f`` and
+    # ``grad_f`` alone reads x itself: u = x, loss = f, loss_grad = grad_f.
+    product: Optional[Callable] = None  # x -> u
+    loss: Optional[Callable] = None     # u -> F
+    loss_grad: Optional[Callable] = None  # u -> ∇F
+
+    def __post_init__(self):
+        if self.product is None:
+            self.product, self.loss, self.loss_grad = (
+                _identity, self.f, self.grad_f)
 
     # ------------------------------------------------------------------ #
     @property
@@ -76,7 +118,11 @@ class Problem:
 
     def v(self, x: jnp.ndarray):
         """Full objective V = F + G."""
-        return self.f(x) + self.g(x)
+        return self.v_at(self.product(x), x)
+
+    def v_at(self, u: jnp.ndarray, x: jnp.ndarray):
+        """V(x) from the product ``u = product(x)`` already in hand."""
+        return self.loss(u) + self.g(x)
 
     def prox(self, w: jnp.ndarray, t) -> jnp.ndarray:
         """Blockwise prox of ``t·g`` at ``w`` (t broadcastable over coords)."""

@@ -9,9 +9,11 @@ instance's F closures from *traced* data slices inside the vmap.  A
 * ``data_keys``  — which arrays of ``Problem.data`` vary per instance and
   get stacked along a leading batch dimension (the first one is the (m, n)
   design/feature matrix that fixes the shape signature);
-* ``make_fns``   — the traceable ``(*arrays, col_sq=None) -> (f, grad_f,
-  diag_curv)`` closure builder.  These are the *same* builders the solo
-  constructors install (``lasso.quadratic_fns``, ``logreg.logistic_fns``,
+* ``make_fns``   — the traceable ``(*arrays, col_sq=None) ->``
+  :class:`~repro.problems.base.SmoothF` closure builder: F as a loss of
+  one design product, with ``f`` and ``grad_f`` its compositions.  These
+  are the *same* builders the solo constructors install
+  (``lasso.quadratic_fns``, ``logreg.logistic_fns``,
   ``svm.squared_hinge_fns``), so batched and solo solves share one
   definition of the math;
 * ``curv_scale`` — the constant in ``diag_curv = curv_scale·‖columns‖²``,
@@ -43,7 +45,7 @@ from repro.problems.svm import squared_hinge_fns
 class ProblemFamily:
     name: str
     data_keys: tuple            # Problem.data arrays stacked per instance
-    make_fns: Callable          # (*arrays, col_sq=None) -> (f, grad, curv)
+    make_fns: Callable          # (*arrays, col_sq=None) -> SmoothF
     curv_scale: float           # diag_curv == curv_scale * col_sq
     # Safe-screening hook (``repro.path.screening``): maps the gradient of
     # F at a reference point to the per-block dual-correlation scores the
@@ -175,9 +177,8 @@ def build_problem(family: str, arrays, c, *, n: int, block_size: int,
     being per-instance traced slices and ``c`` a traced scalar.
     """
     fam = get_family(family)
-    f, grad_f, diag_curv = fam.make_fns(*arrays, col_sq=col_sq)
     return Problem(
         name=f"batched_{family}", n=n, block_size=block_size,
-        f=f, grad_f=grad_f, diag_curv=diag_curv,
+        **fam.make_fns(*arrays, col_sq=col_sq)._asdict(),
         g_kind=g_kind, g_weight=c, family=family,
         data=dict(zip(fam.data_keys, arrays)))

@@ -10,13 +10,14 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
-from repro.problems.base import Problem, mv
+from repro.problems.base import Problem, SmoothF, mv, smooth_f
 from repro.problems.sparse import (CSCDesign, design_col_sq,
                                    design_matvec, design_rmatvec, is_sparse)
 
 
-def quadratic_fns(A, b, col_sq=None):
-    """The F = ‖Ax−b‖² closure triple (f, grad_f, diag_curv).
+def quadratic_fns(A, b, col_sq=None) -> SmoothF:
+    """F = ‖Ax−b‖² as a loss of the residual u = A·x − b
+    (:class:`~repro.problems.base.SmoothF`): F = u·u, ∇F = 2Aᵀu.
 
     The single definition of the factor-2 convention used everywhere:
     ∇F = 2Aᵀ(Ax−b) and ∂²F/∂xᵢ² = 2‖aᵢ‖² (exact for quadratics —
@@ -32,17 +33,19 @@ def quadratic_fns(A, b, col_sq=None):
     if col_sq is None:
         col_sq = design_col_sq(A)            # ‖aᵢ‖² per column
 
-    def f(x):
-        r = design_matvec(A, x) - b
+    def product(x):
+        return design_matvec(A, x) - b
+
+    def loss(r):
         return mv(r, r)
 
-    def grad_f(x):
-        return 2.0 * design_rmatvec(A, design_matvec(A, x) - b)
+    def loss_grad(r):
+        return 2.0 * design_rmatvec(A, r)
 
     def diag_curv(_):
         return 2.0 * col_sq
 
-    return f, grad_f, diag_curv
+    return smooth_f(product, loss, loss_grad, diag_curv)
 
 
 def make_lasso(A, b, c: float, block_size: int = 1,
@@ -55,14 +58,14 @@ def make_lasso(A, b, c: float, block_size: int = 1,
     # a sparse design's closures read it in the stored layout, laid out
     # on the device once here
     stored = A.blocked() if is_sparse(A) else A
-    f, grad_f, diag_curv = quadratic_fns(stored, b)
+    fns = quadratic_fns(stored, b)
 
     # L_F = 2·λmax(AᵀA): cheap power-iteration estimate.
     L = float(2.0 * (_power_iter_sq_sparse(stored) if is_sparse(A)
                      else _power_iter_sq(np.asarray(A))))
     return Problem(
         name=name, n=A.shape[1], block_size=block_size,
-        f=f, grad_f=grad_f, diag_curv=diag_curv,
+        **fns._asdict(),
         g_kind="l1" if block_size == 1 else "group_l2", g_weight=float(c),
         family="lasso" if block_size == 1 else "group_lasso",
         v_star=v_star, x_star=x_star, lipschitz=L,

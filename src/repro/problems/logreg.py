@@ -12,12 +12,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.problems.base import Problem, mv
+from repro.problems.base import Problem, SmoothF, mv, smooth_f
 from repro.problems.lasso import _power_iter_sq
 
 
-def logistic_fns(Z, col_sq=None):
-    """The F = Σⱼ log(1+exp(−zⱼᵀx)) closure triple (f, grad_f, diag_curv).
+def logistic_fns(Z, col_sq=None) -> SmoothF:
+    """F = Σⱼ log(1+exp(−zⱼᵀx)) as a loss of the margins t = Z·x
+    (:class:`~repro.problems.base.SmoothF`): ∇F = −Zᵀσ(−t).
 
     ``Z = diag(a)·Y`` is the label-signed feature matrix.  Traceable, so
     the batched engine can call it with per-instance traced slices of Z;
@@ -26,13 +27,14 @@ def logistic_fns(Z, col_sq=None):
     if col_sq is None:
         col_sq = jnp.sum(Z * Z, axis=0)
 
-    def f(x):
-        t = mv(Z, x)
+    def product(x):
+        return mv(Z, x)
+
+    def loss(t):
         # log(1+e^{−t}) computed stably
         return jnp.sum(jnp.logaddexp(0.0, -t))
 
-    def grad_f(x):
-        t = mv(Z, x)
+    def loss_grad(t):
         sig = jax.nn.sigmoid(-t)       # = e^{−t}/(1+e^{−t})
         return -mv(Z.T, sig)
 
@@ -40,7 +42,7 @@ def logistic_fns(Z, col_sq=None):
         # Global bound: σ(t)σ(−t) ≤ 1/4  ⇒  diag(∇²F) ≤ 0.25·Σ zⱼᵢ².
         return 0.25 * col_sq
 
-    return f, grad_f, diag_curv
+    return smooth_f(product, loss, loss_grad, diag_curv)
 
 
 def make_logreg(Y, a, c: float, block_size: int = 1) -> Problem:
@@ -48,12 +50,12 @@ def make_logreg(Y, a, c: float, block_size: int = 1) -> Problem:
     Y = jnp.asarray(Y)
     a = jnp.asarray(a)
     Z = Y * a[:, None]                 # margins are z = Zx
-    f, grad_f, diag_curv = logistic_fns(Z)
+    fns = logistic_fns(Z)
 
     L = float(0.25 * _power_iter_sq(np.asarray(Z)))
     return Problem(
         name="sparse_logreg", n=Y.shape[1], block_size=block_size,
-        f=f, grad_f=grad_f, diag_curv=diag_curv,
+        **fns._asdict(),
         g_kind="l1" if block_size == 1 else "group_l2", g_weight=float(c),
         family="logreg", lipschitz=L, data={"Z": Z},
     )

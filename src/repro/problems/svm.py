@@ -11,12 +11,14 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
-from repro.problems.base import Problem, mv
+from repro.problems.base import Problem, SmoothF, mv, smooth_f
 from repro.problems.lasso import _power_iter_sq
 
 
-def squared_hinge_fns(Z, col_sq=None):
-    """The F = ‖max(0, 1−Zx)‖² closure triple (f, grad_f, diag_curv).
+def squared_hinge_fns(Z, col_sq=None) -> SmoothF:
+    """F = ‖max(0, 1−Zx)‖² as a loss of the margins t = Z·x
+    (:class:`~repro.problems.base.SmoothF`): with h = max(0, 1 − t),
+    F = h·h and ∇F = −2Zᵀh.
 
     ``Z = diag(a)·Y``.  Traceable (batched-engine compatible); ``col_sq``
     may be precomputed to avoid re-reducing ‖zᵢ‖² inside a solve loop.
@@ -24,30 +26,33 @@ def squared_hinge_fns(Z, col_sq=None):
     if col_sq is None:
         col_sq = jnp.sum(Z * Z, axis=0)
 
-    def f(x):
-        h = jnp.maximum(0.0, 1.0 - mv(Z, x))
+    def product(x):
+        return mv(Z, x)
+
+    def loss(t):
+        h = jnp.maximum(0.0, 1.0 - t)
         return mv(h, h)
 
-    def grad_f(x):
-        h = jnp.maximum(0.0, 1.0 - mv(Z, x))
+    def loss_grad(t):
+        h = jnp.maximum(0.0, 1.0 - t)
         return -2.0 * mv(Z.T, h)
 
     def diag_curv(x):
         return 2.0 * col_sq
 
-    return f, grad_f, diag_curv
+    return smooth_f(product, loss, loss_grad, diag_curv)
 
 
 def make_svm(Y, a, c: float, block_size: int = 1) -> Problem:
     Y = jnp.asarray(Y)
     a = jnp.asarray(a)
     Z = Y * a[:, None]
-    f, grad_f, diag_curv = squared_hinge_fns(Z)
+    fns = squared_hinge_fns(Z)
 
     L = float(2.0 * _power_iter_sq(np.asarray(Z)))
     return Problem(
         name="l1_l2_svm", n=Y.shape[1], block_size=block_size,
-        f=f, grad_f=grad_f, diag_curv=diag_curv,
+        **fns._asdict(),
         g_kind="l1", g_weight=float(c), family="svm",
         lipschitz=L, data={"Z": Z},
     )

@@ -184,7 +184,8 @@ def _instance_init(spec: BatchedProblemSpec, cfg: SolverConfig,
 
 
 def _freeze_done(done, new_state: FlexaState, old_state: FlexaState):
-    """Keep the old state on instances already finished (their k stops)."""
+    """Keep the old state on instances already finished (their k stops;
+    their x and the design product ``u`` at it stay together)."""
     def merge(new, old):
         keep = done.reshape((-1,) + (1,) * (new.ndim - 1))
         return jnp.where(keep, old, new)
