@@ -2,7 +2,7 @@
 
 The seconds-scale CI gate for ``repro.client``: one tiny spec of each
 workload kind (solo, batch, path, CV) runs through each registered
-backend (inline, wave, continuous), and every backend's answer is
+backend (inline, continuous, mesh), and every backend's answer is
 checked against the inline reference on *deterministic* criteria only —
 max |Δx| within the stack's 1e-5 tol-stopping envelope (bitwise is
 asserted nowhere here; that is the test suite's job) plus convergence
@@ -31,7 +31,7 @@ RESULTS = Path(__file__).resolve().parent.parent / "results" / "bench"
 
 TOL = 1e-5
 CFG = SolverConfig(tol=1e-7, max_iters=3000, tau_adapt=False)
-SERVE = ServeConfig(max_batch=4, slab_capacity=4, chunk_iters=50)
+SERVE = ServeConfig(slab_capacity=4, chunk_iters=50)
 
 
 def _specs() -> dict:

@@ -10,7 +10,8 @@ Prints ``name,us_per_call,derived`` CSV rows:
   * fig1 groups  — per-algorithm wall time; derived = time-to-1e-4 rel err
   * batched      — multi-instance engine; derived = warm speedup vs loop
   * ablations    — per-variant wall time; derived = final rel err
-  * serve_load   — continuous vs wave scheduling; derived = speedups
+  * serve_load   — continuous engine under arrival traces; derived =
+                   p99 latency and device row iterations
   * path         — λ-path engine; derived = row-iteration ratio vs cold
   * lm_step      — per-arch train-step time; derived = decode-step time
 
@@ -18,7 +19,7 @@ Full JSON artifacts land in ``results/bench/`` and every ``BENCH_*.json``
 is aggregated into the CSV: ``BENCH_solvers.json`` (written by
 ``fig1.main`` — full per-iteration (V, time) trajectories, summary rows,
 the ``batched`` amortization record), ``BENCH_serve.json``
-(``serve_load.main`` — arrival-trace scheduling races),
+(``serve_load.main`` — arrival-trace replays),
 ``BENCH_path.json`` (``path_bench.main`` — regularization-path columns +
 the CV-over-serve scenario), ``BENCH_compaction.json``
 (``compaction_bench.main`` — masked-dense vs capacity-bucketed compacted
@@ -103,19 +104,19 @@ def main() -> None:
                   f"rel={'n/a' if rel is None else f'{rel:.2e}'}")
 
     if not args.skip_serve:
-        # Continuous-vs-wave scheduling race (writes BENCH_serve.json).
+        # Continuous engine under arrival traces (writes
+        # BENCH_serve.json).
         from benchmarks import serve_load
         art = serve_load.main(smoke=args.smoke)
         failures += [f"serve:{k}" for k in art["gate"]
                      if not art["acceptance"][k]]
         for trace, rec in art["traces"].items():
-            s = rec["speedup"]
             cont = rec["continuous"]
             wall = cont.get("makespan_s") or 0.0
             per_req = wall * 1e6 / max(1, cont.get("requests") or 1)
             print(f"serve/{trace},{per_req:.0f},"
-                  f"makespan_x={s['makespan']} p99_x={s['p99_latency']} "
-                  f"row_iters_x={s['row_iters']}")
+                  f"p99={cont['latency_p99_s']} "
+                  f"row_iters={cont['row_iters']}")
 
         # Observability overhead + determinism gates (writes
         # BENCH_obs.json; --smoke gates the deterministic criteria only,

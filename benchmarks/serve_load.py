@@ -1,4 +1,4 @@
-"""Serve load generator: wave vs continuous batching under arrival traces.
+"""Serve load generator: the continuous engine under arrival traces.
 
 The ROADMAP's serving scenario is heavy concurrent solver traffic.  This
 benchmark generates seeded request traces —
@@ -9,34 +9,28 @@ benchmark generates seeded request traces —
   separated by idle gaps), uniform difficulty;
 * ``heavy_tail`` — Poisson arrivals whose solve *difficulty* is
   Pareto-distributed (most requests are easy, a few need 10–50× the
-  iterations — the fig1d "hard Lasso" regime that makes wave batching
-  pathological, cf. the selective-update analysis of arXiv:1402.5521);
+  iterations — the fig1d "hard Lasso" regime, cf. the selective-update
+  analysis of arXiv:1402.5521);
 
 difficulty maps to the Nesterov instance's support density (``nnz_frac``
-— measured on this container: ~60 iterations at 0.05 up to the
-``max_iters`` cap near 0.35), and replays each trace through
-
-* the **wave** engine (``SolverServeEngine``): every request that has
-  arrived when the server goes idle is packed into padded power-of-two
-  buckets; a bucket runs to the convergence of its *slowest* member;
-* the **continuous** engine (``ContinuousSolverEngine``): slot-slab
-  scheduling with chunked compiled steps and eviction/backfill.
+— measured on a CPU host: ~60 iterations at 0.05 up to the
+``max_iters`` cap near 0.35), and replays each trace through the
+**continuous** engine (``ContinuousSolverEngine``): slot-slab scheduling
+with chunked compiled steps and eviction/backfill.
 
 Time is a simulated clock that flows at real (wall) rate while device
-work runs and jumps over idle gaps, so both engines see the identical
-arrival timeline and latency percentiles are comparable.  Each replay is
-preceded by an untimed warmup replay so compile time never pollutes the
-comparison.  Alongside wall-clock metrics the benchmark records **device
-row iterations** (slots × iterations actually executed) — a fully
-deterministic work measure the CI smoke gate checks, immune to timer
-noise.
+work runs and jumps over idle gaps.  Each replay is preceded by an
+untimed warmup replay so compile time never pollutes the numbers.
+Alongside wall-clock metrics the benchmark records **device row
+iterations** (slots × iterations actually executed) — a fully
+deterministic work measure, immune to timer noise.
 
-Artifact: ``results/bench/BENCH_serve.json`` — per-trace wave/continuous
-summaries (makespan, latency p50/p99, throughput, occupancy, padding
-waste, row iterations), the per-request equivalence check against solo
-``solve()`` (must agree within 1e-5), and the acceptance block (the
-continuous engine must beat the wave engine on makespan and p99 latency
-on the heavy-tail trace).
+Artifact: ``results/bench/BENCH_serve.json`` — per-trace summaries
+(makespan, latency p50/p99, throughput, occupancy, padding waste, row
+iterations) and the per-request equivalence check against solo
+``solve()`` on the heavy-tail trace (must agree within 1e-5), which is
+the acceptance gate.  ``--devices N`` runs the mesh bench instead
+(:func:`main_mesh`).
 
 Run: ``PYTHONPATH=src python benchmarks/serve_load.py`` (≈ a minute at
 the default miniature scale; ``--smoke`` is the seconds-scale CI step;
@@ -92,7 +86,7 @@ RESULTS = Path(__file__).resolve().parent.parent / "results" / "bench"
 #: easy high-sparsity regime (~60–100 iterations at the benchmark
 #: scales); 0.18 is the hardest density whose instances still converge
 #: comfortably under the iteration cap (~10–15× the easy iteration
-#: count — the straggler a wave bucket cannot shed).  Harder instances
+#: count).  Harder instances
 #: would hit the cap unconverged, whose iterates are schedule-noise
 #: chaotic and would break the solo-equivalence contract.
 NNZ_EASY, NNZ_HARD = 0.05, 0.18
@@ -157,8 +151,8 @@ def bursty_trace(n: int, *, burst: int, gap: float,
 # Mean request cost is a few hundred iterations against a slab that
 # serves ``slab_capacity`` slots concurrently (~20 units/request at full
 # occupancy), so these gaps put the offered load past saturation: the
-# queue builds over the trace, buckets/slabs stay full, and the
-# scheduling policy — not idle waiting — decides every metric.
+# queue builds over the trace, slabs stay full, and the scheduling
+# policy — not idle waiting — decides every metric.
 TRACES = {
     "poisson": lambda n, seed: poisson_trace(n, mean_gap=12.0, seed=seed),
     "bursty": lambda n, seed: bursty_trace(n, burst=12, gap=150.0,
@@ -222,32 +216,6 @@ class SimClock:
 # ------------------------------------------------------------------ #
 # Replay drivers                                                     #
 # ------------------------------------------------------------------ #
-def replay_wave(trace, problems, cfg: SolverConfig,
-                serve: ServeConfig) -> ServeTelemetry:
-    """Wave policy: when the server goes idle, everything that has
-    arrived forms the next wave (padded power-of-two buckets inside).
-    The client buffers submissions and ``step()`` dispatches one wave —
-    exactly the old hand-rolled loop, now through the front door."""
-    clock = SimClock()
-    tele = ServeTelemetry(clock=clock)
-    client = FlexaClient(backend="wave", solver=cfg, serve=serve,
-                         telemetry=tele)
-    i = 0
-    while i < len(trace):
-        clock.advance_to(trace[i].arrival)
-        now = clock()
-        while i < len(trace) and trace[i].arrival <= now:
-            # True trace arrivals: a request that queued up while the
-            # previous wave held the device arrived before this submit
-            # — its latency must include that wait (same definition as
-            # the continuous side).
-            client.submit(SoloSpec(problem=problems[i]),
-                          arrival=trace[i].arrival)
-            i += 1
-        client.step()                # clock flows during the wave
-    return tele
-
-
 def replay_continuous(trace, problems, cfg: SolverConfig,
                       serve: ServeConfig):
     """Continuous policy: admit on arrival, chunk-step, evict, backfill.
@@ -272,13 +240,13 @@ def replay_continuous(trace, problems, cfg: SolverConfig,
     return (client, tickets), tele
 
 
-def summarize(tele: ServeTelemetry, engine: str) -> dict:
+def summarize(tele: ServeTelemetry) -> dict:
     snap = tele.snapshot()
     completions = [r.completed for r in tele.requests.values()
                    if r.completed is not None]
     arrivals = [r.arrival for r in tele.requests.values()]
     makespan = (max(completions) - min(arrivals)) if completions else None
-    side = snap.get(engine, {})
+    side = snap.get("continuous", {})
     return {
         "requests": snap["requests"],
         "converged": snap["converged"],
@@ -293,7 +261,7 @@ def summarize(tele: ServeTelemetry, engine: str) -> dict:
         "row_iters": side.get("row_iters"),
         "occupancy_mean": side.get("occupancy_mean"),
         "padding_waste": side.get("padding_waste"),
-        "freeze_waste": side.get("freeze_waste"),  # wave only
+        "freeze_waste": side.get("freeze_waste"),
     }
 
 
@@ -395,7 +363,7 @@ def main_mesh(devices: int, requests: int = 48, seed: int = 0,
             "(is XLA_FLAGS already set in the environment without "
             "xla_force_host_platform_device_count?)")
     if smoke:
-        # More requests than the wave/continuous smoke and a lower
+        # More requests than the continuous smoke and a lower
         # iteration cap: the ratio compares saturated schedules, and the
         # slowest single request floors the mesh's tick count at
         # max_iters/chunk_iters whatever the device count — total work
@@ -488,7 +456,7 @@ def main_mesh(devices: int, requests: int = 48, seed: int = 0,
 
 
 # ------------------------------------------------------------------ #
-# Main comparison                                                    #
+# Main run                                                           #
 # ------------------------------------------------------------------ #
 def run_trace(name: str, n_requests: int, seed: int, m: int, n: int,
               cfg: SolverConfig, serve: ServeConfig, unit: float,
@@ -499,30 +467,17 @@ def run_trace(name: str, n_requests: int, seed: int, m: int, n: int,
     trace = [dataclasses.replace(t, arrival=t.arrival * unit)
              for t in raw]
 
-    # Untimed warmup replays populate every compile cache (fused chunk
-    # stepper, per-bucket wave programs) so the timed replays compare
-    # schedules, not compilation.
-    replay_wave(trace, problems, cfg, serve)
+    # An untimed warmup replay populates the compile caches (fused chunk
+    # stepper, row writer) so the timed replay measures the schedule,
+    # not compilation.
     replay_continuous(trace, problems, cfg, serve)
-
-    wave_tele = replay_wave(trace, problems, cfg, serve)
     (cont_client, cont_tickets), cont_tele = \
         replay_continuous(trace, problems, cfg, serve)
 
     record = {
         "trace": name, "requests": n_requests, "seed": seed,
         "unit_s": unit,
-        "wave": summarize(wave_tele, "wave"),
-        "continuous": summarize(cont_tele, "continuous"),
-    }
-    w, c = record["wave"], record["continuous"]
-    record["speedup"] = {
-        "makespan": (w["makespan_s"] / c["makespan_s"]
-                     if c["makespan_s"] else None),
-        "p99_latency": (w["latency_p99_s"] / c["latency_p99_s"]
-                        if c["latency_p99_s"] else None),
-        "row_iters": (w["row_iters"] / c["row_iters"]
-                      if c["row_iters"] else None),
+        "continuous": summarize(cont_tele),
     }
 
     if check_solo:
@@ -557,21 +512,19 @@ def run_trace(name: str, n_requests: int, seed: int, m: int, n: int,
 
 def main(requests: int = 48, seed: int = 0, m: int = 64, n: int = 256,
          max_iters: int = 2500, slab_capacity: int = 8,
-         chunk_iters: int = 100, max_batch: int = 8,
-         smoke: bool = False) -> dict:
+         chunk_iters: int = 100, smoke: bool = False) -> dict:
     if smoke:
         # Seconds-scale CI configuration: fewer requests — but still
-        # several× the slab capacity (continuous batching only differs
-        # from wave dispatch under backfill pressure); instances stay at
-        # the default size so the chunked schedule remains
-        # device-work-bound, not dispatch-bound.
+        # several× the slab capacity, so admission runs under backfill
+        # pressure; instances stay at the default size so the chunked
+        # schedule remains device-work-bound, not dispatch-bound.
         requests, max_iters = 24, 2200
     # tol 1e-7 keeps tol-stopped responses within ~1e-6 of the solo
     # solve even on the hardest instances (fp32 reduction-order noise
     # shifts *stopping times* slightly; the tighter ball shrinks the
     # solution gap) — 1e-6 stopping was measured as tight as 1.5e-5.
     cfg = SolverConfig(max_iters=max_iters, tol=1e-7, tau_adapt=False)
-    serve = ServeConfig(max_batch=max_batch, slab_capacity=slab_capacity,
+    serve = ServeConfig(slab_capacity=slab_capacity,
                         chunk_iters=chunk_iters)
 
     artifact = {
@@ -580,8 +533,7 @@ def main(requests: int = 48, seed: int = 0, m: int = 64, n: int = 256,
                      "nnz_hard": NNZ_HARD},
         "solver_cfg": {"max_iters": max_iters, "tol": cfg.tol,
                        "tau_adapt": cfg.tau_adapt},
-        "serve_cfg": {"max_batch": max_batch,
-                      "slab_capacity": slab_capacity,
+        "serve_cfg": {"slab_capacity": slab_capacity,
                       "chunk_iters": chunk_iters, "policy": serve.policy},
         "traces": {},
     }
@@ -592,29 +544,18 @@ def main(requests: int = 48, seed: int = 0, m: int = 64, n: int = 256,
         rec = run_trace(trace_name, requests, seed, m, n, cfg, serve,
                         unit, check_solo=(trace_name == "heavy_tail"))
         artifact["traces"][trace_name] = rec
-        s = rec["speedup"]
-        print(f"[{trace_name:>10}] makespan x{s['makespan']:.2f}  "
-              f"p99 x{s['p99_latency']:.2f}  row_iters x{s['row_iters']:.2f}")
+        c = rec["continuous"]
+        print(f"[{trace_name:>10}] makespan {c['makespan_s']:.3f}s  "
+              f"p99 {c['latency_p99_s']:.3f}s  "
+              f"row_iters {c['row_iters']}")
 
     ht = artifact["traces"]["heavy_tail"]
     artifact["acceptance"] = {
-        "continuous_beats_wave_makespan":
-            bool(ht["speedup"]["makespan"] and ht["speedup"]["makespan"] > 1),
-        "continuous_beats_wave_p99":
-            bool(ht["speedup"]["p99_latency"]
-                 and ht["speedup"]["p99_latency"] > 1),
-        "continuous_does_less_device_work":
-            bool(ht["speedup"]["row_iters"]
-                 and ht["speedup"]["row_iters"] > 1),
         "solo_equivalence_ok": ht["equivalence"]["ok"],
     }
-    # The CI smoke gate checks only the *deterministic* criteria (device
-    # row iterations, solo equivalence) — wall-clock comparisons on a
-    # shared CI runner are timer-noise-flaky by nature; the full run
-    # gates all four.
-    artifact["gate"] = (["continuous_does_less_device_work",
-                         "solo_equivalence_ok"] if smoke
-                        else list(artifact["acceptance"]))
+    # Deterministic criteria only: wall-clock comparisons on a shared
+    # CI runner are timer-noise-flaky by nature.
+    artifact["gate"] = list(artifact["acceptance"])
 
     RESULTS.mkdir(parents=True, exist_ok=True)
     out = RESULTS / "BENCH_serve.json"
@@ -632,7 +573,6 @@ if __name__ == "__main__":
     ap.add_argument("--max-iters", type=int, default=2500)
     ap.add_argument("--slab-capacity", type=int, default=8)
     ap.add_argument("--chunk-iters", type=int, default=100)
-    ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--devices", type=int, default=0,
                     help="run the MESH bench instead: N-device mesh "
                          "engine vs 1-device continuous on the "
@@ -669,8 +609,7 @@ if __name__ == "__main__":
         art = main(requests=args.requests, seed=args.seed, m=args.m,
                    n=args.n, max_iters=args.max_iters,
                    slab_capacity=args.slab_capacity,
-                   chunk_iters=args.chunk_iters, max_batch=args.max_batch,
-                   smoke=args.smoke)
+                   chunk_iters=args.chunk_iters, smoke=args.smoke)
     failed = [k for k in art["gate"] if not art["acceptance"][k]]
     if failed:
         raise SystemExit(f"acceptance failed on {failed}: "

@@ -10,7 +10,7 @@ import jax
 
 from repro.configs.registry import get_reduced
 from repro.models import transformer as T
-from repro.serve.engine import ServeEngine
+from repro.models.generate import ServeEngine
 
 
 def main():

@@ -1,13 +1,13 @@
 """``repro.client`` — one front door to the whole solver stack.
 
 The paper's framework spans "virtually all" update schedules; the repo's
-execution engines span in-process, wave-batched and continuous-batched
-scheduling.  This package is the single API over all of it:
+execution engines span in-process and continuous-batched scheduling,
+on one device or a device mesh.  This package is the single API over all of it:
 
     from repro.client import FlexaClient, SoloSpec, PathSpec
 
     client = FlexaClient(backend="continuous")
-    result = client.run(SoloSpec(problem))        # == inline == wave
+    result = client.run(SoloSpec(problem))        # == inline
 
 * :class:`FlexaClient` — the session (``submit`` / ``run`` / ``step`` /
   ``stream`` / ``drain``), configured by one :class:`~repro.config.base.
@@ -16,7 +16,7 @@ scheduling.  This package is the single API over all of it:
 * typed specs — :class:`SoloSpec`, :class:`BatchSpec`,
   :class:`PathSpec`, :class:`CVSpec` — normalizing onto one internal
   :class:`WorkItem`;
-* the :class:`Backend` protocol + registry (``inline`` / ``wave`` /
+* the :class:`Backend` protocol + registry (``inline`` /
   ``continuous`` / ``mesh`` / ``remote``; :func:`register_backend` to
   extend — ``remote`` runs against a ``repro.remote.server`` process,
   see ``docs/remote.md``);
@@ -30,7 +30,7 @@ deprecation cycle and are **removed**; direct engine construction still
 warns once per process — see ``docs/client.md`` for the migration table.
 """
 from repro.client.backends import (Backend, ContinuousBackend,
-                                   InlineBackend, MeshBackend, WaveBackend,
+                                   InlineBackend, MeshBackend,
                                    available_backends, make_backend,
                                    register_backend)
 from repro.client.errors import (ClientError, SpecError,
@@ -49,7 +49,7 @@ __all__ = [
     "SoloSpec", "BatchSpec", "PathSpec", "CVSpec",
     "SoloResult", "BatchResult", "PathResult", "CVResult",
     "TicketDiagnostics", "WorkItem", "normalize", "solve_request_of",
-    "Backend", "InlineBackend", "WaveBackend", "ContinuousBackend",
+    "Backend", "InlineBackend", "ContinuousBackend",
     "MeshBackend",
     "available_backends", "register_backend", "make_backend",
     "ClientError", "SpecError", "UnknownBackendError",

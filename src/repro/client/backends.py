@@ -1,31 +1,29 @@
 """Pluggable execution backends behind the one client front door.
 
 A backend is *how* a normalized :class:`~repro.client.specs.WorkItem`
-gets executed — never *what* it computes.  All three registered
-backends run the same Algorithm-1 mathematics over the same compiled
-programs, so switching ``ClientConfig.backend`` changes scheduling,
-latency and device utilization, but results agree with the inline
-reference (≤1e-5 under tol-stopping; bit-identical where the very same
-compiled program runs — the equivalence matrix in
-``tests/test_client.py`` pins this):
+gets executed — never *what* it computes.  Every registered backend
+runs the same Algorithm-1 mathematics, so switching
+``ClientConfig.backend`` changes scheduling, latency and device
+utilization, but results agree with the inline reference (≤1e-5 under
+tol-stopping; bit-identical where the very same compiled program runs —
+the equivalence matrix in ``tests/test_client.py`` pins this):
 
 * ``inline``     — in-process: the method registry for solos, the
-  batched vmap+while_loop engine for batches, the homotopy driver for
-  paths/CV.  Lowest latency for one-shot work; no admission control.
-* ``wave``       — :class:`~repro.serve.engine.SolverServeEngine`:
-  buffered submissions are packed into padded power-of-two buckets and
-  dispatched as waves.  Paths/CV run the engine-agnostic
-  :class:`~repro.serve.pathstate.PathState` protocol, one wave per
-  λ-point across every in-flight path (K CV folds share one bucket).
+  batched vmap+while_loop program for batches, the homotopy driver for
+  paths/CV.  No admission control.
 * ``continuous`` — :class:`~repro.serve.continuous.
   ContinuousSolverEngine`: slot-slab continuous batching with
-  eviction/backfill; paths/CV ride the engine's native point-by-point
-  admission.  The backend for sustained concurrent traffic.
+  eviction/backfill; paths/CV ride the engine's point-by-point
+  admission (:class:`~repro.serve.pathstate.PathState`).  The backend
+  for sustained concurrent traffic.
 * ``mesh``       — :class:`~repro.serve.mesh.MeshServeEngine`: the
   continuous runtime sharded over a 1-D device mesh (one slab shard +
   admission queue per device, shared-queue routing, work stealing).
-  Same WorkItem capabilities as ``continuous``; needs > 1 visible jax
-  device to beat it (``ServeConfig.mesh_devices``).
+  Same WorkItem capabilities as ``continuous``
+  (``ServeConfig.mesh_devices``).
+* ``remote``     — a ``repro.remote`` solver service over HTTP
+  (registered by ``repro.remote.backend`` on first use); it validates
+  like the serving backends, since the server runs one.
 
 Backends construct the legacy engines under
 :func:`repro.deprecation.internal_use`, so the client never triggers
@@ -270,33 +268,23 @@ class Backend:
     def close(self) -> None:
         pass
 
-    # -- shared serve-side helpers --------------------------------- #
-    def _sweep_cfg(self, item: WorkItem) -> SolverConfig:
-        """Solver config of a CV sweep (``tol_coarse`` continuation)."""
-        tc = getattr(item.spec, "tol_coarse", None)
-        return (self.config.solver if tc is None
-                else dataclasses.replace(self.config.solver, tol=tc))
-
-    @staticmethod
-    def _path_request(spec, problem, grid, tol=None, priority=0,
-                      deadline=None):
-        """The serve path protocol's request for one instance — the one
-        construction both serve backends share, so a new PathSpec field
-        can never be threaded through only one of them.  ``tol`` is the
-        per-request stopping tolerance (the CV coarse sweep) — only the
-        continuous/mesh engines honor it; the wave backend reaches
-        coarse tolerance through a per-config engine instead."""
-        from repro.serve.pathstate import PathRequest
-        return PathRequest(
-            A=np.asarray(problem.data["A"], np.float32),
-            b=np.asarray(problem.data["b"], np.float32),
-            lambdas=grid, n_points=spec.n_points,
-            lam_min_ratio=spec.lam_min_ratio,
-            block_size=int(problem.block_size), warm=spec.warm,
-            screen=spec.screen, kkt_slack=spec.kkt_slack, tol=tol,
-            priority=priority, deadline=deadline)
-
     # -- shared validation helpers --------------------------------- #
+    def _validate_served(self, item: WorkItem) -> None:
+        """The capability envelope of the serving engines: what the
+        ``continuous`` and ``mesh`` backends accept, and the ``remote``
+        one, whose server runs a serving engine."""
+        if item.kind == "solo":
+            self._require_flexa_solo(item)
+            self._require_registry_family(item)
+        elif item.kind == "batch":
+            self._require_registry_family(item)
+            if item.spec.record_history:
+                raise UnsupportedWorkloadError(
+                    "record_history is an inline-backend feature (the "
+                    "serving engines never sync per iteration)")
+        else:
+            self._require_serveable_path(item)
+
     def _require_registry_family(self, item: WorkItem) -> None:
         if item.family is None:
             raise UnsupportedWorkloadError(
@@ -510,225 +498,6 @@ class InlineBackend(Backend):
 
 
 # ------------------------------------------------------------------ #
-# Serve-side path jobs (wave backend)                                #
-# ------------------------------------------------------------------ #
-class _PathJob:
-    """One path/cv ticket driven through wave submissions.
-
-    Holds one :class:`PathState` per fold; each wave round submits the
-    live folds' current requests together (they share a signature, so
-    they ride one bucket) and feeds the responses back until every fold
-    is done.
-    """
-
-    def __init__(self, item: WorkItem, grid):
-        from repro.serve.pathstate import PathState
-        self.item = item
-        self.states = [
-            PathState(i, Backend._path_request(item.spec, p, grid))
-            for i, p in enumerate(item.problems)]
-        self.pending_req = [st.next_request() for st in self.states]
-        self.resolving = False          # cv winner re-solve in flight
-        self.winner_resps: list = []
-        self.folds = None
-        self.select = None
-
-    @property
-    def done(self) -> bool:
-        return all(st.done for st in self.states)
-
-
-# ------------------------------------------------------------------ #
-# Wave backend                                                       #
-# ------------------------------------------------------------------ #
-@register_backend
-class WaveBackend(Backend):
-    """Buffered wave dispatch over :class:`SolverServeEngine`.
-
-    ``submit`` only buffers; each ``step`` packs everything admissible —
-    buffered solos/batches plus every in-flight path's current λ-point —
-    into ONE engine wave.  ``run``/``result`` loop ``step`` until the
-    ticket completes, so one-shot callers never see the buffering.
-    """
-
-    name = "wave"
-
-    def __init__(self, config, telemetry):
-        super().__init__(config, telemetry)
-        self._engines: dict[SolverConfig, object] = {}
-        self._queue: list[tuple[WorkItem, object]] = []
-        self._jobs: dict[int, _PathJob] = {}
-        self._ticket_rids: dict[int, list[int]] = {}
-
-    def request_ids(self, ticket: int) -> list[int]:
-        return list(self._ticket_rids.get(ticket, []))
-
-    def _engine(self, cfg: SolverConfig):
-        eng = self._engines.get(cfg)
-        if eng is None:
-            from repro.serve.engine import SolverServeEngine
-            with internal_use():
-                eng = SolverServeEngine(cfg, self.config.serve,
-                                        telemetry=self.telemetry)
-            self._engines[cfg] = eng
-        return eng
-
-    # -- protocol -------------------------------------------------- #
-    def validate(self, item: WorkItem) -> None:
-        if item.kind == "solo":
-            self._require_flexa_solo(item)
-            self._require_registry_family(item)
-        elif item.kind == "batch":
-            self._require_registry_family(item)
-            if item.spec.record_history:
-                raise UnsupportedWorkloadError(
-                    "record_history is an inline-backend feature (the "
-                    "serving engines never sync per iteration)")
-        else:
-            self._require_serveable_path(item)
-
-    def submit(self, item: WorkItem, arrival=None) -> list[int]:
-        if item.kind in ("solo", "batch"):
-            self._queue.append((item, arrival))
-        else:
-            spec = item.spec
-            grid = (_resolve_cv_grid(item) if item.kind == "cv"
-                    else spec.lambdas)
-            self._jobs[item.ticket] = _PathJob(item, grid)
-        return []
-
-    @property
-    def pending(self) -> int:
-        return len(self._queue) + len(self._jobs)
-
-    def step(self) -> list[int]:
-        """One wave round: everything admissible rides one submission
-        per solver config (sweeps at coarse tol and full-tol work can
-        coexist; each config has its own engine)."""
-        waves: dict[SolverConfig, list] = {}
-
-        def enqueue(cfg, req, arrival, route):
-            waves.setdefault(cfg, []).append((req, arrival, route))
-
-        queue, self._queue = self._queue, []
-        for item, arrival in queue:
-            if item.kind == "solo":
-                enqueue(self.config.solver,
-                        solve_request_of(item.problems[0],
-                                         x0=item.spec.x0),
-                        arrival, ("solo", item, 0))
-            else:
-                x0 = item.spec.x0
-                act = item.spec.active
-                for i, p in enumerate(item.problems):
-                    enqueue(self.config.solver, solve_request_of(
-                        p, x0=None if x0 is None else x0[i],
-                        active=None if act is None else act[i]),
-                        arrival, ("batch", item, i))
-        for ticket, job in self._jobs.items():
-            cfg = (self.config.solver if job.resolving
-                   else self._sweep_cfg(job.item))
-            for i, req in enumerate(job.pending_req):
-                if req is not None:
-                    enqueue(cfg, req, None, ("path", job, i))
-
-        done = []
-        partial: dict[int, dict] = {}       # batch ticket -> responses
-        for cfg, entries in waves.items():
-            reqs = [e[0] for e in entries]
-            now = self.telemetry.now()
-            arrivals = [now if e[1] is None else e[1] for e in entries]
-            eng = self._engine(cfg)
-            resps = eng.submit(reqs, arrivals=arrivals)
-            rids = getattr(eng, "last_request_ids", [None] * len(resps))
-            for (req, _, route), resp, rid in zip(entries, resps, rids):
-                if rid is not None:
-                    _, obj, _ = route
-                    tkt = (obj.ticket if route[0] != "path"
-                           else obj.item.ticket)
-                    self._ticket_rids.setdefault(tkt, []).append(int(rid))
-                kind = route[0]
-                if kind == "solo":
-                    _, item, _ = route
-                    self._results[item.ticket] = _solo_result(
-                        resp, self.name, item.problems[0])
-                    done.append(item.ticket)
-                elif kind == "batch":
-                    _, item, i = route
-                    partial.setdefault(item.ticket,
-                                       {"item": item, "resps": {}})[
-                        "resps"][i] = resp
-                else:
-                    _, job, i = route
-                    if job.resolving:
-                        job.winner_resps[i] = resp
-                        job.pending_req[i] = None
-                    else:
-                        job.pending_req[i] = \
-                            job.states[i].on_completion(resp)
-
-        for ticket, rec in partial.items():
-            item, resps = rec["item"], rec["resps"]
-            self._results[ticket] = _batch_result(
-                [resps[i] for i in range(len(item.problems))], self.name,
-                item.problems)
-            done.append(ticket)
-
-        for ticket in list(self._jobs):
-            job = self._jobs[ticket]
-            if job.resolving:
-                if all(r is not None for r in job.winner_resps):
-                    folds = job.folds
-                    x_best = np.stack([np.asarray(r.x)
-                                       for r in job.winner_resps])
-                    self._results[ticket] = _finish_cv(
-                        job.item, folds, self.name, x_best, job.select,
-                        meta={"mode": "wave"},
-                        ledger=_cv_ledger(folds, _request_ledger(
-                            [r.iters for r in job.winner_resps],
-                            job.item.problems)))
-                    del self._jobs[ticket]
-                    done.append(ticket)
-                continue
-            if not job.done:
-                continue
-            folds = [_path_result_from_serve(job.item.problems[i],
-                                             st.result(), self.name)
-                     for i, st in enumerate(job.states)]
-            if job.item.kind == "path":
-                self._results[ticket] = folds[0]
-                del self._jobs[ticket]
-                done.append(ticket)
-                continue
-            select = _cv_select(job.item, folds)
-            if select["best_index"] is not None \
-                    and job.item.spec.tol_coarse is not None:
-                # Phase 2: full-tol winner re-solve as one more wave.
-                job.resolving = True
-                job.folds, job.select = folds, select
-                best = select["best_index"]
-                probs = _winner_problems(job.item,
-                                         select["best_lambda"])
-                job.pending_req = [
-                    solve_request_of(p, x0=folds[i].x[best])
-                    for i, p in enumerate(probs)]
-                job.winner_resps = [None] * len(probs)
-            else:
-                self._results[ticket] = _finish_cv(
-                    job.item, folds, self.name, None, select,
-                    meta={"mode": "wave"},
-                    ledger=_cv_ledger(folds, None))
-                del self._jobs[ticket]
-                done.append(ticket)
-        return done
-
-    def stats(self) -> dict:
-        return {"backend": self.name,
-                "engines": [dict(eng.stats)
-                            for eng in self._engines.values()]}
-
-
-# ------------------------------------------------------------------ #
 # Continuous backend                                                 #
 # ------------------------------------------------------------------ #
 class _ContTicket:
@@ -779,7 +548,22 @@ class ContinuousBackend(Backend):
                 self._eng = self._make_engine()
         return self._eng
 
-    validate = WaveBackend.validate
+    validate = Backend._validate_served
+
+    @staticmethod
+    def _path_request(spec, problem, grid, tol=None, priority=0,
+                      deadline=None):
+        """The serve path protocol's request for one instance.  ``tol``
+        is the per-request stopping tolerance (the CV coarse sweep)."""
+        from repro.serve.pathstate import PathRequest
+        return PathRequest(
+            A=np.asarray(problem.data["A"], np.float32),
+            b=np.asarray(problem.data["b"], np.float32),
+            lambdas=grid, n_points=spec.n_points,
+            lam_min_ratio=spec.lam_min_ratio,
+            block_size=int(problem.block_size), warm=spec.warm,
+            screen=spec.screen, kkt_slack=spec.kkt_slack, tol=tol,
+            priority=priority, deadline=deadline)
 
     def submit(self, item: WorkItem, arrival=None) -> list[int]:
         rec = _ContTicket(item)

@@ -9,7 +9,7 @@ distinctions that matter operationally:
   time, before any device work, so rejection is atomic.
 * :class:`UnsupportedWorkloadError` — the spec is well-formed but the
   *selected backend* cannot execute it (e.g. a FISTA solo on a serving
-  engine, a logistic-regression path over the wave scheduler).  The
+  engine, a logistic-regression path over the continuous scheduler).  The
   message names a backend that can.
 * :class:`UnknownBackendError` — ``ClientConfig.backend`` names nothing
   in the registry.

@@ -14,8 +14,8 @@ solver stack.
         ...
 
 ``submit`` validates + normalizes the spec and hands it to the
-configured backend (eager for ``inline``, buffered for ``wave``,
-admitted for ``continuous``); ``run`` is submit-then-wait; ``step``
+configured backend (eager for ``inline``, admitted for
+``continuous``/``mesh``); ``run`` is submit-then-wait; ``step``
 advances asynchronous backends one scheduler round; ``stream`` yields
 ``(ticket, result)`` pairs in completion order until the session is
 drained.  Results are identical across backends (the equivalence matrix
@@ -163,7 +163,7 @@ class FlexaClient:
         request it spawned, as :meth:`RequestTrace.as_dict` dicts (with
         residual-trajectory ``samples`` when
         ``telemetry.sample_progress`` is on) — the dashboard's
-        convergence-sparkline feed.  All backends (serve, wave, inline)
+        convergence-sparkline feed.  All backends (serve, inline)
         keep the ticket → request-id mapping.
         """
         if ticket not in self._items:

@@ -184,7 +184,7 @@ class TicketDiagnostics:
     request the ticket spawned (solo/batch requests, every λ-point of a
     path, CV winner re-solves); the ``samples`` lists inside are
     populated when ``telemetry.sample_progress`` is on.  Every backend
-    (serve, wave, inline) keeps the ticket → request-id mapping, so the
+    (serve, inline) keeps the ticket → request-id mapping, so the
     feed is populated regardless of execution mode.
     """
     ticket: int
@@ -209,8 +209,8 @@ class WorkItem:
     ``priority``/``deadline`` are service-policy annotations (SLO class
     mapped by the remote server, defaults for direct use): the serve
     backends thread them into every engine request the item spawns, so
-    the admission heaps and the timeout sweep see them; the inline and
-    wave backends ignore them.
+    the admission heaps and the timeout sweep see them; the inline
+    backend ignores them.
     """
     ticket: int
     kind: str                       # one of KINDS

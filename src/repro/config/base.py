@@ -242,20 +242,13 @@ class SolverConfig:
 
 @dataclass(frozen=True)
 class ServeConfig:
-    """Settings for the solver serving runtimes (``repro.serve``).
-
-    Both runtimes take this config directly: the continuous-batching
-    engine (``ContinuousSolverEngine``) reads the slab/scheduler knobs,
-    the wave engine (``SolverServeEngine``) reads ``max_batch`` (a plain
-    ``max_batch=`` constructor kwarg remains as a back-compat override).
-    Callers configuring both engines from one place — the client
-    backends, ``benchmarks/serve_load.py`` — just hand the same config
-    to each.  Frozen + hashable so a config can ride inside
-    compile-cache keys if a runtime ever specializes on it.
+    """Settings for the solver serving engines (``repro.serve``): the
+    continuous-batching engine (``ContinuousSolverEngine``) and its
+    mesh-sharded form (``MeshServeEngine``) read the slab/scheduler
+    knobs.  Frozen + hashable so a config can ride inside compile-cache
+    keys if a runtime ever specializes on it.
     """
 
-    # --- wave engine ---
-    max_batch: int = 16         # power-of-two bucket cap per wave
     # --- continuous engine ---
     slab_capacity: int = 8      # live slots per (family × shape) slab
     chunk_iters: int = 16       # FLEXA iterations per compiled chunk step
@@ -319,16 +312,14 @@ class ClientConfig:
     solver hyperparameters/budget (:class:`SolverConfig`) and the
     serving-runtime knobs (:class:`ServeConfig`) — plus the backend
     choice itself, so a workload is fully described by (spec, config)
-    and switching ``backend`` can never change anything else.  This is
-    what retires the old pattern of every caller hand-threading
-    ``ServeConfig.max_batch`` into ``SolverServeEngine(max_batch=...)``.
+    and switching ``backend`` can never change anything else.
     """
 
     solver: SolverConfig = field(default_factory=SolverConfig)
     serve: ServeConfig = field(default_factory=ServeConfig)
     # Execution backend: "inline" (facade / solve_batched in-process) |
-    # "wave" (SolverServeEngine buckets) | "continuous"
-    # (ContinuousSolverEngine slot slabs) | "mesh" (device-mesh slabs) |
+    # "continuous" (ContinuousSolverEngine slot slabs) | "mesh"
+    # (device-mesh slabs) |
     # "remote" (a repro.remote solver-service process over HTTP).
     # repro.client.available_backends() lists the registry.
     backend: str = "inline"

@@ -2,7 +2,7 @@
 numerical-health watchdog, and windowed SLOs.
 
 ``repro.obs`` spans the whole stack — client submit/run/step, backend
-dispatch, wave/continuous/mesh serve engines, path-driver KKT rounds and
+dispatch, continuous/mesh serve engines, path-driver KKT rounds and
 compaction repacks, and compile-cache hits/misses — with five pieces:
 
 * :mod:`repro.obs.trace` — deterministic injectable-clock span recorder

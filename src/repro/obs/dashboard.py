@@ -164,14 +164,6 @@ def render_snapshot(snap: dict, *, queue_depth=None, title: str = "repro.obs",
             f"live {cont.get('live_iters', 0)}   "
             f"iters/s {_fmt(cont.get('iters_per_s'))}")
 
-    wav = snap.get("wave")
-    if wav:
-        lines.append(rule)
-        lines.append(
-            f"waves     {wav.get('waves', 0)} dispatched   "
-            f"row-iters {wav.get('row_iters', 0)}   "
-            f"padding-waste {_fmt(wav.get('padding_waste'))}")
-
     mesh = snap.get("mesh")
     if mesh:
         lines.append(rule)

@@ -28,7 +28,7 @@ from __future__ import annotations
 import urllib.error
 import urllib.request
 
-from repro.client.backends import Backend, WaveBackend, register_backend
+from repro.client.backends import Backend, register_backend
 from repro.client.errors import ClientError, UnsupportedWorkloadError
 from repro.client.specs import WorkItem
 from repro.remote import protocol
@@ -89,7 +89,7 @@ class RemoteBackend(Backend):
     def validate(self, item: WorkItem) -> None:
         # The server executes a continuous backend, so the serve-side
         # capability envelope applies verbatim...
-        WaveBackend.validate(self, item)
+        self._validate_served(item)
         # ...plus wire-only restrictions: closures cannot cross it.
         if item.kind == "cv" and item.spec.score is not None:
             raise UnsupportedWorkloadError(

@@ -1,15 +1,10 @@
-"""Serving layer: LM generation + two solver-serving runtimes.
+"""Serving layer: the solver's serving engines.
 
-The solver engines here are the *backends* behind the client front door
-(``repro.client.FlexaClient`` with ``backend="wave"``/``"continuous"``);
+The engines here are the *backends* behind the client front door
+(``repro.client.FlexaClient`` with ``backend="continuous"``/``"mesh"``);
 constructing them directly still works but emits a one-shot
 ``FutureWarning`` (see ``docs/client.md``).
 
-* :class:`ServeEngine` — LM prefill/decode with static KV-cache buckets.
-* :class:`SolverServeEngine` — wave-batched solver serving (padded
-  power-of-two buckets over cached compiled programs); takes a
-  :class:`ServeConfig` directly (``max_batch=`` kwarg remains as a
-  back-compat override).
 * :class:`ContinuousSolverEngine` — continuous batching: slot slabs,
   chunked compiled steps, eviction/backfill from a policy-ordered
   admission queue (``repro.serve.continuous``).
@@ -18,23 +13,25 @@ constructing them directly still works but emits a one-shot
   the shared queue with work stealing at the drain tail
   (``repro.serve.mesh``); telemetry rolls up per device via
   :class:`MeshTelemetry`.
-* :class:`PathRequest` / :class:`PathState` — the engine-agnostic
-  point-by-point path protocol (``repro.serve.pathstate``), driven by
-  the continuous engine natively and by the client's wave backend.
+* :class:`SolveRequest` / :class:`SolveResponse` — one request and its
+  verdict (``repro.serve.engine``).
+* :class:`PathRequest` / :class:`PathState` — the point-by-point path
+  protocol (``repro.serve.pathstate``) the engines drive a
+  regularization path through.
 * :class:`ServeTelemetry` — shared latency/occupancy/cache telemetry
   (``repro.serve.metrics``).
+
+The LM generator lives in ``repro.models.generate``.
 """
 from repro.serve.continuous import (AdmissionQueue, ContinuousSolverEngine,
                                     QueueEntry)
-from repro.serve.engine import (GenerationResult, ServeEngine, SolveRequest,
-                                SolveResponse, SolverServeEngine)
+from repro.serve.engine import SolveRequest, SolveResponse
 from repro.serve.mesh import MeshServeEngine
 from repro.serve.metrics import MeshTelemetry, RequestTrace, ServeTelemetry
 from repro.serve.pathstate import PathRequest, PathState
 
 __all__ = [
-    "GenerationResult", "ServeEngine",
-    "SolveRequest", "SolveResponse", "SolverServeEngine",
+    "SolveRequest", "SolveResponse",
     "ContinuousSolverEngine", "AdmissionQueue", "QueueEntry",
     "MeshServeEngine", "MeshTelemetry",
     "PathRequest", "PathState",

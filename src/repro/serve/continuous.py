@@ -1,13 +1,9 @@
 """Continuous-batching solver runtime: slot slabs + admission scheduling.
 
-The wave engine (``repro.serve.engine.SolverServeEngine``) dispatches
-*waves*: a padded power-of-two bucket enters one compiled while_loop and
-nothing leaves until the slowest instance converges — one ill-conditioned
-Lasso holds sixteen slots hostage, and every instance that finished early
-keeps burning device iterations frozen-in-place.  The paper's framework
-is explicitly "virtually all possibilities in between" fully-parallel and
-sequential updates; this runtime applies the same idea to the *serving*
-schedule:
+This is the solver's serving engine (the mesh engine,
+``repro.serve.mesh``, shards it over devices).  Requests do not wait for
+a batch to finish: a request leaves the device the tick it converges,
+and a queued one takes its slot on the next tick.
 
 * a **slot slab** per (family × shape) signature
   (:class:`repro.solvers.batched.SlabState`) holds a fixed-capacity stack
@@ -16,23 +12,21 @@ schedule:
 * a compiled, buffer-donated **chunk step**
   (:func:`repro.solvers.batched.make_chunk_stepper`) advances every live
   slot by K FLEXA iterations; a slot that converges mid-chunk freezes
-  exactly as in the wave driver, so its answer is independent of K and
-  identical to a solo ``solve()``;
-* after each chunk the host reads one (S,) bool mask, **evicts**
-  converged slots and **backfills** them in place from an **admission
-  queue** with FIFO / priority / earliest-deadline policies — so
-  throughput is bounded by slot occupancy, not by the slowest request in
-  a wave.  An admitted request's data rows (its A and b) go to the
-  device alone and are written in place into the donated slab at its
-  slot (:func:`repro.solvers.batched.make_row_writer`: one program per
+  exactly as in the batched run-to-convergence program, so its answer
+  is independent of K and identical to a solo ``solve()``;
+* after each chunk the host reads one (S,) mask, **evicts** converged
+  slots and **backfills** them in place from an **admission queue**
+  with FIFO / priority / earliest-deadline policies — so throughput is
+  bounded by slot occupancy, not by the slowest request in the slab.
+  An admitted request's data rows (its A and b) go to the device alone
+  and are written in place into the donated slab at its slot
+  (:func:`repro.solvers.batched.make_row_writer`: one program per
   signature, a traced slot index); the small per-slot vectors (c, x0,
   freeze mask, tol, request ids, admit mask) ride with the chunk call,
   whose fused admit phase splices the rest of the row (column norms,
   base τ, a fresh state) from the slab's own data.  So a tick ships the
   admitted rows and nothing else of the data, and stays one chunk
-  program however many requests enter; the standalone single-slot
-  splice (:func:`repro.solvers.batched.make_slot_writer`) remains the
-  building block for packing slabs outside the engine.
+  program however many requests enter.
 
 Per-request PRNG streams fold the *request id* (not the slot) into
 ``PRNGKey(cfg.seed)``, so a request's randomized-selection trajectory is
@@ -43,8 +37,8 @@ trace (property-tested in ``tests/test_serve_continuous.py``).
 
 Telemetry (latency percentiles, chunk throughput, slot occupancy, padding
 waste, compile-cache counters) flows into ``repro.serve.metrics``;
-``benchmarks/serve_load.py`` races this runtime against the wave engine
-on seeded arrival traces and writes ``results/bench/BENCH_serve.json``.
+``benchmarks/serve_load.py`` replays seeded arrival traces through this
+runtime and writes ``results/bench/BENCH_serve.json``.
 """
 from __future__ import annotations
 
@@ -153,6 +147,9 @@ class _SlotSlab:
     readback, plus one row write per admitted request.
     """
 
+    #: Devices the slot axis is sharded over (the mesh slab sets more).
+    n_devices = 1
+
     def __init__(self, spec: BatchedProblemSpec, cfg: SolverConfig,
                  serve: ServeConfig, telemetry: ServeTelemetry,
                  resolve_x0=None, deadline_of=None):
@@ -162,8 +159,8 @@ class _SlotSlab:
         self._base_capacity = self.capacity
         self._compact_drain = bool(getattr(serve, "compact_drain", False))
         self.chunk_iters = int(serve.chunk_iters)
-        # Numerical-health watchdog (None = off ⇒ the byte-identical
-        # legacy chunk program).  Must be set before _make_chunk() —
+        # Numerical-health watchdog (None = off ⇒ the chunk program
+        # without the health pass).  Must be set before _make_chunk() —
         # it keys the stepper compile cache.
         self._health_cfg = HealthConfig.of(serve)
         self.telemetry = telemetry
@@ -236,13 +233,13 @@ class _SlotSlab:
             self._stage_ids.copy(), self._stage_active.copy(),
             self._stage_tol.copy()))
 
-    def _fresh_health(self, capacity: int):
+    def _fresh_health(self, capacity: int) -> tuple:
         """Device-resident per-slot health carry ``(prev_stat, stall)``
         at quarantine rest: +inf previous stat (any finite first-chunk
-        stat counts as a decrease), zero stall count.  ``None`` when the
-        watchdog is off."""
+        stat counts as a decrease), zero stall count.  Empty when the
+        watchdog is off: the chunk then takes and returns no carry."""
         if self._health_cfg is None:
-            return None
+            return ()
         return self._to_device((np.full((capacity,), np.inf, np.float32),
                                 np.zeros((capacity,), np.int32)))
 
@@ -265,7 +262,7 @@ class _SlotSlab:
 
     def _make_chunk(self):
         return make_chunk_stepper(self.spec, self.cfg, self.chunk_iters,
-                                  self._health_cfg)
+                                  self.n_devices, self._health_cfg)
 
     def _record_chunk(self, wall: float) -> None:
         self.telemetry.record_chunk(live=self.live, capacity=self.capacity,
@@ -318,7 +315,7 @@ class _SlotSlab:
         live_slots = [int(s) for s in np.flatnonzero(self.active)]
         self.slab = slab_migrate(self.slab, live_slots, self.spec,
                                  self.cfg, target)
-        if self._health_carry is not None:
+        if self._health_carry:
             # The health carry migrates with its slots: a stalling
             # straggler keeps its stall count across a drain-tail
             # resize (conservation pinned in tests/test_health.py).
@@ -496,30 +493,20 @@ class _SlotSlab:
             self._admit[:] = False
         else:
             admit = self._no_admit
-        new_c, new_x0, new_ids, new_active, new_tol = self._payload
         with obs.span("serve.chunk", cat="continuous", tick=tick,
                       live=self.live, capacity=self.capacity,
                       chunk_iters=self.chunk_iters):
-            if self._health_cfg is None:
-                self.slab, stop_dev = self._chunk(
-                    self.slab, self._to_device(self.stop.copy()), admit,
-                    new_c, new_x0, new_ids, new_active, new_tol)
-                # The one per-chunk host sync (copy: host mirror is
-                # mutated).
-                stop = np.array(stop_dev)
-                status = None
-            else:
-                # Watchdog on: same single dispatch, and the one
-                # readback widens from a bool stop mask to the int32
-                # verdict vector (0=running / 1=stopped / 2=diverged /
-                # 3=stalled).  The health carry stays device-resident.
-                self.slab, status_dev, prev_stat, stall = self._chunk(
-                    self.slab, self._to_device(self.stop.copy()), admit,
-                    new_c, new_x0, new_ids, new_active, new_tol,
-                    *self._health_carry)
-                self._health_carry = (prev_stat, stall)
-                status = np.array(status_dev)
-                stop = status != STATUS_RUNNING
+            self.slab, flags, *carry = self._chunk(
+                self.slab, self._to_device(self.stop.copy()), admit,
+                *self._payload, *self._health_carry)
+            self._health_carry = tuple(carry)
+            # The one per-chunk host sync (copy: host mirror is
+            # mutated): the bool stop mask, or with the watchdog on the
+            # int32 verdict vector (0=running / 1=stopped / 2=diverged /
+            # 3=stalled) while the health carry stays device-resident.
+            flags = np.array(flags)
+        status = flags if self._health_carry else None
+        stop = flags if status is None else status != STATUS_RUNNING
         wall = time.perf_counter() - t0
         self._record_chunk(wall)
 
@@ -742,7 +729,7 @@ class ContinuousSolverEngine:
                arrival: float | None = None) -> int:
         """Enqueue one request; returns its request id."""
         spec = request.spec
-        validate_request(None, request, spec)
+        validate_request(request, spec)
         if request.warm_from is not None:
             ref_spec = self._spec_of.get(request.warm_from)
             if ref_spec is None:

@@ -6,10 +6,12 @@ runtime across a device mesh" — built from the same parts:
 
 * the slot slab grows to ``mesh_devices × slab_capacity`` slots and its
   chunk program runs under ``shard_map`` over a 1-D ``("serve",)`` mesh
-  (:func:`repro.solvers.batched.make_sharded_chunk_stepper`): device d
-  owns the contiguous slot block ``[d·S_dev, (d+1)·S_dev)`` and advances
-  it with the *identical* per-slot math — the chunk core is
-  collective-free, so sharding adds no communication;
+  (:func:`repro.solvers.batched.make_chunk_stepper` with the slab's
+  ``n_devices``; at one device it is the continuous engine's plain
+  program): device d owns the contiguous slot block
+  ``[d·S_dev, (d+1)·S_dev)`` and advances it with the *identical*
+  per-slot math — the chunk core is collective-free, so sharding adds
+  no communication;
 * admission becomes two-level: the engine's shared policy-ordered
   :class:`~repro.serve.continuous.AdmissionQueue` feeds per-device
   queues through a routing policy (``ServeConfig.mesh_routing``), and
@@ -63,8 +65,7 @@ from repro.serve.continuous import (AdmissionQueue, ContinuousSolverEngine,
                                     QueueEntry, _SlotSlab)
 from repro.launch.mesh import make_mesh
 from repro.serve.metrics import MeshTelemetry
-from repro.solvers.batched import (BatchedProblemSpec,
-                                   make_sharded_chunk_stepper)
+from repro.solvers.batched import BatchedProblemSpec
 
 #: Shared-queue → device-queue routing policies (``ServeConfig.
 #: mesh_routing``).
@@ -168,12 +169,6 @@ class _MeshSlab(_SlotSlab):
 
     def _slab_capacity(self, serve: ServeConfig) -> int:
         return self.n_devices * self.per_device_capacity
-
-    def _make_chunk(self):
-        return make_sharded_chunk_stepper(self.spec, self.cfg,
-                                          self.chunk_iters,
-                                          self.n_devices,
-                                          self._health_cfg)
 
     def _record_chunk(self, wall: float) -> None:
         per = self.per_device_capacity

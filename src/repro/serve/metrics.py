@@ -1,24 +1,17 @@
-"""Serve telemetry: one recorder shared by the wave and continuous engines.
+"""Serve telemetry: one recorder per serving session.
 
 The ROADMAP's serving goal is latency/throughput under heavy concurrent
-traffic, and until now the engines were flying blind: the wave engine's
-power-of-two padding cost was invisible, and there was no per-request
-latency at all.  :class:`ServeTelemetry` records
+traffic.  :class:`ServeTelemetry` records
 
 * the **request lifecycle** — arrival (submit), admission (first device
   iteration), completion — from which queue wait, service time and
   end-to-end latency (p50/p99/mean) derive;
-* **chunk-level** counters for the continuous engine — chunks executed,
+* **chunk-level** counters of the serving engines — chunks executed,
   FLEXA iterations per second of device wall, slot occupancy (live slots /
   slab capacity, weighted per chunk), padding waste (idle-slot row
   iterations), and the iterations evicted requests actually advanced,
   which split the occupied slots' row iterations into live work and
   freeze (a slot held after it converged inside a chunk);
-* **wave-level** counters for the bucketed engine — bucket occupancy
-  (real requests / padded bucket), padding waste (row iterations spent on
-  padding clones) and freeze waste (row iterations spent stepping
-  already-converged instances while a straggler holds the while_loop
-  open) — the apples-to-apples baseline columns of ``BENCH_serve.json``;
 * the **compile caches** (``repro.solvers.cache``) — hit/miss/eviction/
   size per cache, so a serving process can see whether its signatures fit
   the ``REPRO_COMPILE_CACHE_SIZE`` budget.
@@ -64,7 +57,7 @@ class RequestTrace:
     completed: float | None = None
     iters: int = 0
     converged: bool = False
-    engine: str = ""                # "wave" | "continuous"
+    engine: str = ""    # "inline" | "continuous" (mesh too) | "remote"
     #: "ok" | "diverged" | "stalled" (watchdog quarantine verdicts) |
     #: "timeout" (deadline eviction via ``expire_overdue``).
     status: str = "ok"
@@ -145,8 +138,6 @@ class ServeTelemetry:
     # layout's padding
     nnz_stored: int = 0
     nnz_capacity: int = 0
-    # wave-engine per-bucket records
-    waves: list = field(default_factory=list)
     # opt-in per-chunk residual sampling (dashboard sparklines); off by
     # default so no extra device readback happens unless requested
     sample_progress: bool = False
@@ -283,33 +274,6 @@ class ServeTelemetry:
         the counter is what the conservation tests use)."""
         self.migrations += 1
 
-    def record_wave(self, *, bucket: int, n_real: int, iters,
-                    wall_s: float, device_iters_max: int | None = None,
-                    flops: int = 0) -> None:
-        """One wave bucket: ``iters`` are the per-row iteration counts of
-        the *real* requests; ``device_iters_max`` the max over ALL rows
-        including padding clones (under randomized selection a clone's
-        own PRNG stream can out-iterate every real request and keep the
-        while_loop open — the device executed *that* many iterations)."""
-        iters = [int(i) for i in iters]
-        iters_max = max(iters) if iters else 0
-        if device_iters_max is not None:
-            iters_max = max(iters_max, int(device_iters_max))
-        row_iters = bucket * iters_max          # what the device executed
-        useful = sum(iters)
-        self.waves.append({
-            "bucket": bucket, "n_real": n_real, "padded": bucket - n_real,
-            "occupancy": n_real / bucket if bucket else 0.0,
-            "iters_max": iters_max, "useful_row_iters": useful,
-            "row_iters": row_iters,
-            "padding_waste": ((bucket - n_real) * iters_max / row_iters
-                              if row_iters else 0.0),
-            "freeze_waste": ((n_real * iters_max - useful) / row_iters
-                             if row_iters else 0.0),
-            "flops": int(flops),
-            "wall_s": wall_s,
-        })
-
     # ------------------------------------------------------------- #
     # aggregation
     # ------------------------------------------------------------- #
@@ -328,7 +292,7 @@ class ServeTelemetry:
         rows (K·(capacity − occupied)).  A request still in a slot has
         its rows in ``freeze_iters`` until its eviction moves them to
         ``live_iters``, so the split is exact once the engine has
-        drained.  Waves attribute both exactly too.  ``compiles`` counts
+        drained.  ``compiles`` counts
         the process-wide compile-cache misses (``cache_stats``) — the
         same source the snapshot's ``compile_cache`` section reports."""
         led = CostLedger()
@@ -338,14 +302,6 @@ class ServeTelemetry:
                 freeze_iters=occupied - self.chunk_advanced_iters,
                 padding_iters=self.chunk_row_iters - occupied,
                 device_flops=self.chunk_flops)
-        for w in self.waves:
-            pad = w["padded"] * w["iters_max"]
-            led.add(row_iters=w["row_iters"],
-                    live_iters=w["useful_row_iters"],
-                    padding_iters=pad,
-                    freeze_iters=(w["row_iters"] - w["useful_row_iters"]
-                                  - pad),
-                    device_flops=w.get("flops", 0))
         led.add(compiles=sum(c["misses"]
                              for c in cache_stats().values()))
         return led
@@ -392,21 +348,6 @@ class ServeTelemetry:
                 "nnz_stored": self.nnz_stored,
                 "nnz_capacity": self.nnz_capacity,
                 "nnz_pad_share": 1.0 - self.nnz_stored / self.nnz_capacity}
-        if self.waves:
-            row = sum(w["row_iters"] for w in self.waves)
-            useful = sum(w["useful_row_iters"] for w in self.waves)
-            pad = sum(w["padded"] * w["iters_max"] for w in self.waves)
-            out["wave"] = {
-                "waves": len(self.waves),
-                "row_iters": row,
-                "device_flops": sum(w.get("flops", 0) for w in self.waves),
-                "occupancy_mean": (float(np.mean(
-                    [w["occupancy"] for w in self.waves]))),
-                "padding_waste": pad / row if row else 0.0,
-                "freeze_waste": ((row - useful - pad) / row
-                                 if row else 0.0),
-                "wall_s": sum(w["wall_s"] for w in self.waves),
-            }
         return out
 
 

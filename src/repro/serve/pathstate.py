@@ -1,4 +1,4 @@
-"""Engine-agnostic regularization-path state machine for serving.
+"""Regularization-path state machine for serving.
 
 A :class:`PathRequest` describes a whole λ-path as one serve-level job;
 :class:`PathState` turns it into a *request generator*: ``next_request``
@@ -8,17 +8,13 @@ screened via ``active_mask``), and ``on_completion`` digests the point's
 response — running the KKT recheck and emitting either a re-solve of the
 same point or the next λ — until the path is done.
 
-The state machine is deliberately ignorant of *which* engine executes
-the requests: the continuous runtime (``repro.serve.continuous``) admits
-them into its slot slabs point by point, and the client's wave backend
-(``repro.client.backends``) runs the same machine over
-``SolverServeEngine`` waves — one definition of the homotopy/KKT
-protocol, bit-identical answers whichever scheduler serves it (the
-serving counterpart of ``repro.path.solve_path``).
+The state machine knows nothing of the engine that executes the
+requests: the continuous runtime (``repro.serve.continuous``, and the
+mesh engine built on it) admits them into its slot slabs point by point
+— the serving counterpart of ``repro.path.solve_path``.
 """
 from __future__ import annotations
 
-import itertools
 from dataclasses import dataclass
 
 import numpy as np

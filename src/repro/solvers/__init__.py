@@ -12,17 +12,16 @@ internal to the inline backend.
 * the batched multi-instance FLEXA engine: B independent instances
   advance in lock-step inside one compiled (vmap + while_loop) program
   (:func:`make_batched_solver`, ``batched.py``).
-* the resumable slab core (:func:`slab_alloc` / :func:`make_chunk_stepper`
-  / :func:`make_slot_writer`) — what the continuous-batching runtime
-  (``repro.serve.continuous``) schedules over; slabs carry a per-slot
+* the resumable slab core (:func:`slab_alloc` / :func:`make_chunk_stepper`)
+  — what the serving engines (``repro.serve.continuous`` and its
+  mesh-sharded form) schedule over; slabs carry a per-slot
   stopping-tolerance vector so one engine can mix tenant tolerances.
 * :func:`register` / :func:`available_methods` — extend or inspect the
   method registry; :func:`cache_stats` — compile-cache counters.
 """
 from repro.solvers.batched import (BatchedProblemSpec, SlabState,
                                    make_batched_solver, make_chunk_stepper,
-                                   make_sharded_chunk_stepper,
-                                   make_slot_writer, slab_alloc)
+                                   slab_alloc)
 from repro.solvers.cache import cache_stats
 from repro.solvers.registry import available_methods, get_solver, register
 from repro.solvers.result import SolverResult
@@ -30,7 +29,6 @@ from repro.solvers.result import SolverResult
 __all__ = [
     "make_batched_solver", "BatchedProblemSpec",
     "SlabState", "slab_alloc", "make_chunk_stepper",
-    "make_sharded_chunk_stepper", "make_slot_writer",
     "SolverResult", "register", "get_solver", "available_methods",
     "cache_stats",
 ]

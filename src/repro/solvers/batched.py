@@ -22,21 +22,19 @@ This module instead *vmaps Algorithm 1 itself* over a stack of instances:
 * compiled programs are cached on ``(spec, cfg)`` via a bounded,
   instrumented LRU (``repro.solvers.cache.CompileCache``, capacity from
   ``REPRO_COMPILE_CACHE_SIZE``) — one compile cache entry per (family,
-  shape, config) signature — so a serving process pays compilation once
-  per bucket (``repro.serve.engine.SolverServeEngine`` builds on exactly
-  this).
+  shape, config) signature.  The inline backend's batch solves and the
+  lockstep path driver (``repro.path``) run this program.
 
-Besides the run-to-convergence wave program, this module exposes the
-*resumable* slab core the continuous-batching runtime
-(``repro.serve.continuous``) schedules over: :func:`slab_alloc` packs a
-fixed-capacity stack of instance buffers, :func:`make_slot_writer`
-compiles an in-place ``dynamic_update_slice`` admission of one new
-instance into a slot, :func:`make_row_writer` writes one admitted
-request's data rows alone into the donated slab, and
-:func:`make_chunk_stepper` compiles "advance every live slot by K
-iterations" with the same freeze-on-convergence merge the wave driver
-uses — so a slot's trajectory is bit-identical whichever driver runs
-it.
+Besides the run-to-convergence program, this module exposes the
+*resumable* slab core the serving engines (``repro.serve.continuous``
+and its mesh-sharded form ``repro.serve.mesh``) schedule over:
+:func:`slab_alloc` packs a fixed-capacity stack of instance buffers,
+:func:`make_row_writer` writes one admitted request's data rows alone
+into the donated slab, and :func:`make_chunk_stepper` compiles "advance
+every live slot by K iterations", on one device or with the slot axis
+sharded over a mesh, with the same freeze-on-convergence merge the
+run-to-convergence program uses (:func:`_frozen_step`) — so a slot's
+trajectory is bit-identical whichever driver runs it.
 
 γ, τ, the PRNG key of the randomized selection rules, and the selection
 mask are per-instance state, so each instance follows the identical
@@ -183,13 +181,42 @@ def _instance_init(spec: BatchedProblemSpec, cfg: SolverConfig,
     return _flexa.init_state(problem, x0, cfg, key=key)
 
 
+def _bmask(mask, ndim: int):
+    """Broadcast a (S,) bool mask against an (S, ...) array."""
+    return mask.reshape((-1,) + (1,) * (ndim - 1))
+
+
 def _freeze_done(done, new_state: FlexaState, old_state: FlexaState):
     """Keep the old state on instances already finished (their k stops;
     their x and the design product ``u`` at it stay together)."""
-    def merge(new, old):
-        keep = done.reshape((-1,) + (1,) * (new.ndim - 1))
-        return jnp.where(keep, old, new)
-    return jax.tree_util.tree_map(merge, new_state, old_state)
+    return jax.tree_util.tree_map(
+        lambda new, old: jnp.where(_bmask(done, new.ndim), old, new),
+        new_state, old_state)
+
+
+def _frozen_step(vstep, cfg: SolverConfig, data, c, col_sq, tau_base,
+                 active, state: FlexaState, stop, tol):
+    """One freeze-merge iteration over a stack of instances: every
+    instance steps, those already stopped keep their old state, and
+    ``stop`` gains the instances that now reach ``tol`` (a scalar, or
+    one tolerance per instance) or ``cfg.max_iters``.  Returns
+    ``(state, stop, info)``.  Every driver of the stack (the
+    run-to-convergence loop, the chunk, the recorded-history loop)
+    iterates through this one step."""
+    new_state, info = vstep(data, c, col_sq, tau_base, active, state)
+    merged = _freeze_done(stop, new_state, state)
+    stop = stop | (merged.stat <= tol) | (merged.k >= cfg.max_iters)
+    return merged, stop, info
+
+
+def _stack_norms(spec: BatchedProblemSpec, cfg: SolverConfig, data):
+    """``(col_sq, tau_base)``, each (B, n), of a stack of instances'
+    family data: the column norms and the §4 base τ built from them."""
+    fam = get_family(spec.family)
+    col_sq = jax.vmap(fam.col_sq)(*data)
+    tau_base = jax.vmap(
+        lambda csq: _tau_base(fam.half_curv(csq), cfg, spec.n))(col_sq)
+    return col_sq, tau_base
 
 
 def _build_batched_solver(spec: BatchedProblemSpec, cfg: SolverConfig):
@@ -200,18 +227,14 @@ def _build_batched_solver(spec: BatchedProblemSpec, cfg: SolverConfig):
     n),)`` for logreg/svm), ``c``: (B,), ``x0``: (B, n).  ``active`` is an
     optional (B, n) per-instance freeze mask (``None`` ⇒ all coordinates
     live — the pre-screening behaviour, bit for bit).  The cache key is
-    (spec, cfg); jit handles distinct B by recompiling per batch bucket,
-    which is why the serve engine pads requests into fixed buckets.
+    (spec, cfg); jit recompiles per distinct B.
     """
-    fam = get_family(spec.family)
     vstep = jax.vmap(partial(_instance_step, spec, cfg))
     vinit = jax.vmap(partial(_instance_init, spec, cfg))
-    vtau = jax.vmap(lambda csq: _tau_base(fam.half_curv(csq), cfg, spec.n))
 
     @jax.jit
     def run(data, c, x0, active=None):
-        col_sq = jax.vmap(fam.col_sq)(*data)     # (B, n), once per solve
-        tau_base = vtau(col_sq)                  # (B, n)
+        col_sq, tau_base = _stack_norms(spec, cfg, data)  # once per solve
         B = x0.shape[0]
         if active is None:
             active = jnp.ones((B, spec.n), jnp.float32)
@@ -224,11 +247,10 @@ def _build_batched_solver(spec: BatchedProblemSpec, cfg: SolverConfig):
 
         def body(carry):
             state, done = carry
-            new_state, _ = vstep(data, c, col_sq, tau_base, active, state)
-            merged = _freeze_done(done, new_state, state)
-            done = done | (merged.stat <= cfg.tol) \
-                | (merged.k >= cfg.max_iters)
-            return merged, done
+            state, done, _ = _frozen_step(vstep, cfg, data, c, col_sq,
+                                          tau_base, active, state, done,
+                                          cfg.tol)
+            return state, done
 
         final, _ = jax.lax.while_loop(cond, body, (state, done))
         return final, final.stat <= cfg.tol
@@ -236,9 +258,8 @@ def _build_batched_solver(spec: BatchedProblemSpec, cfg: SolverConfig):
     return run
 
 
-#: Bounded LRU over (spec, cfg) — the wave-serving compile cache.  Call it
-#: exactly like the old ``lru_cache``'d function: ``make_batched_solver(
-#: spec, cfg)``.  Counters surface via ``repro.serve.metrics``.
+#: Bounded LRU over (spec, cfg): ``make_batched_solver(spec, cfg)``.
+#: Counters surface via ``repro.serve.metrics``.
 make_batched_solver = CompileCache("batched_solver", _build_batched_solver)
 
 
@@ -306,19 +327,16 @@ def shipped_row_bytes(spec: BatchedProblemSpec) -> int:
     return 8 * spec.nnz_cap + 4 * (spec.n + 1) + 4 * spec.m
 
 
-def _stored(rows: tuple) -> tuple:
-    """Shipped rows as the slab stores them: a column-compressed design
-    in the blocked layout (traceable)."""
-    return tuple(block_layout(r.values, r.rows, r.col_ptr, r.shape)
-                 if isinstance(r, CSCDesign) else r for r in rows)
-
-
 def _set_rows(data: tuple, slot, rows: tuple) -> tuple:
-    """``data`` with slot ``slot`` of every leaf set from ``rows``."""
+    """``data`` with slot ``slot`` of every leaf set from ``rows``, a
+    column-compressed design laid out blocked as the slab stores it
+    (traceable)."""
+    stored = (block_layout(r.values, r.rows, r.col_ptr, r.shape)
+              if isinstance(r, CSCDesign) else r for r in rows)
     return tuple(
         jax.tree_util.tree_map(lambda d, r: d.at[slot].set(r.astype(d.dtype)),
                                d, r)
-        for d, r in zip(data, _stored(rows)))
+        for d, r in zip(data, stored))
 
 
 def slab_alloc(spec: BatchedProblemSpec, cfg: SolverConfig,
@@ -345,48 +363,6 @@ def slab_alloc(spec: BatchedProblemSpec, cfg: SolverConfig,
                      state=state,
                      active=jnp.ones((S, spec.n), jnp.float32),
                      tol=jnp.full((S,), cfg.tol, jnp.float32))
-
-
-def _build_slot_writer(spec: BatchedProblemSpec, cfg: SolverConfig):
-    """Compile ``write(slab, slot, new_data, new_c, new_x0, key) -> slab``.
-
-    One new instance is spliced into slot ``slot`` of every stacked buffer
-    (``.at[slot].set`` on a traced index — a ``dynamic_update_slice``), its
-    column norms / base τ are recomputed, and its :class:`FlexaState` is
-    freshly initialized exactly as a solo solve would (``init_state`` on
-    the rebuilt family problem).  The slab is donated: admission is an
-    in-place splice, not a reallocation, however large the resident data.
-    """
-    fam = get_family(spec.family)
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def write(slab: SlabState, slot, new_data, new_c, new_x0, key,
-              new_active=None, new_tol=None):
-        new_data = _stored(new_data)
-        problem = family_problem(new_data, new_c, spec)
-        inst = _flexa.init_state(problem, new_x0, cfg, key=key)
-        csq = fam.col_sq(*new_data)
-        tb = _tau_base(fam.half_curv(csq), cfg, spec.n)
-        if new_active is None:
-            new_active = jnp.ones((spec.n,), jnp.float32)
-        if new_tol is None:
-            new_tol = jnp.float32(cfg.tol)
-        return SlabState(
-            data=_set_rows(slab.data, slot, new_data),
-            c=slab.c.at[slot].set(new_c),
-            col_sq=slab.col_sq.at[slot].set(csq),
-            tau_base=slab.tau_base.at[slot].set(tb),
-            state=jax.tree_util.tree_map(
-                lambda s, v: s.at[slot].set(v.astype(s.dtype)),
-                slab.state, inst),
-            active=slab.active.at[slot].set(new_active),
-            tol=slab.tol.at[slot].set(new_tol),
-        )
-
-    return write
-
-
-make_slot_writer = CompileCache("slot_writer", _build_slot_writer)
 
 
 def _build_row_writer(spec: BatchedProblemSpec):
@@ -416,15 +392,9 @@ def _build_row_writer(spec: BatchedProblemSpec):
 make_row_writer = CompileCache("row_writer", _build_row_writer)
 
 
-def _bmask(mask, ndim: int):
-    """Broadcast a (S,) bool mask against an (S, ...) array."""
-    return mask.reshape((-1,) + (1,) * (ndim - 1))
-
-
 def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
                 chunk_iters: int, health: HealthConfig | None = None):
-    """The (un-jitted) fused tick body shared by the single-device and
-    mesh-sharded chunk steppers:
+    """The (un-jitted) fused tick body of :func:`make_chunk_stepper`:
 
         core(slab, stop, admit, new_c, new_x0, new_ids, new_active,
              new_tol) -> (slab, stop)
@@ -432,7 +402,7 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
     or, with the numerical-health watchdog enabled (``health`` a
     :class:`repro.obs.health.HealthConfig`):
 
-        core(slab, stop, admit, ..., new_active, prev_stat, stall)
+        core(slab, stop, admit, ..., new_tol, prev_stat, stall)
             -> (slab, status, prev_stat, stall)
 
     where ``status`` is the (S,) int32 verdict vector (STATUS_RUNNING /
@@ -442,8 +412,7 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
     of consecutive non-decreasing chunks), reset on admitted rows.  The
     health pass runs *after* the iteration loop and only reads its
     outputs — the iteration math is byte-identical either way, which is
-    the watchdog's bitwise-while-healthy guarantee.  With
-    ``health=None`` this function builds the exact legacy program.
+    the watchdog's bitwise-while-healthy guarantee.
 
     Phase 1 — **admission splice**: slots flagged in ``admit`` (an (S,)
     bool mask) already hold their new family data rows in ``slab.data``
@@ -457,17 +426,16 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
     neighbours).  Non-admitted rows are ignored (masked select), so the
     host can leave stale bytes in the per-slot vectors.
 
-    Phase 2 — **K iterations** on every unstopped slot, with the wave
-    driver's exact freeze-on-convergence merge: a slot flips its own
-    ``stop`` bit the moment it converges (``stat ≤ tol``) or exhausts
-    ``max_iters`` and is frozen from the next inner iteration on, so its
-    final state is the state at first convergence — the same answer
-    :func:`make_batched_solver`'s while_loop produces, independent of
-    the chunk size K.
+    Phase 2 — **K iterations** of :func:`_frozen_step` on every
+    unstopped slot: a slot flips its own ``stop`` bit the moment it
+    converges (``stat ≤ tol``) or exhausts ``max_iters`` and is frozen
+    from the next inner iteration on, so its final state is the state
+    at first convergence — the same answer :func:`make_batched_solver`'s
+    while_loop produces, independent of the chunk size K.
 
     Every operation here is per-slot (vmapped iteration, masked row
     selects) — no cross-slot reductions or collectives — which is what
-    lets :func:`make_sharded_chunk_stepper` wrap the identical body in a
+    lets :func:`make_chunk_stepper` wrap the identical body in a
     ``shard_map`` over the slot axis with no communication.
     """
     fam = get_family(spec.family)
@@ -521,12 +489,10 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
         # value-identical to the scalar program.
         def body(_, carry):
             state, stop = carry
-            new_state, _ = vstep(slab.data, slab.c, slab.col_sq,
-                                 slab.tau_base, slab.active, state)
-            merged = _freeze_done(stop, new_state, state)
-            stop = stop | (merged.stat <= slab.tol) \
-                | (merged.k >= cfg.max_iters)
-            return merged, stop
+            state, stop, _ = _frozen_step(
+                vstep, cfg, slab.data, slab.c, slab.col_sq, slab.tau_base,
+                slab.active, state, stop, slab.tol)
+            return state, stop
         state, stop = jax.lax.fori_loop(0, chunk_iters, body,
                                         (slab.state, stop))
         return slab._replace(state=state), stop
@@ -573,84 +539,19 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
     return core_health
 
 
-def _build_chunk_stepper(spec: BatchedProblemSpec, cfg: SolverConfig,
-                         chunk_iters: int,
-                         health: HealthConfig | None = None):
-    """Compile one fused scheduler tick (see :func:`_chunk_core` for the
-    phase-by-phase contract):
-
-        chunk(slab, stop, admit, new_c, new_x0, new_ids, new_active,
-              new_tol) -> (slab, stop)
-
-    Fusing admission into the step matters operationally: a scheduler
-    tick is ONE chunk program and one (S,) mask readback, however many
-    requests were admitted — separate per-slot splice calls would pay
-    dispatch per admission and dominate the serving makespan at small
-    instance sizes.  Only the admitted data rows travel apart
-    (:func:`make_row_writer`), so the chunk's arguments hold one data
-    slab and (S,)/(S, n) per-slot vectors.  The slab and stop mask are
-    donated (in-place advance).
-
-    With ``health`` set, the tick takes and returns the device-resident
-    per-slot health carry and the readback widens to an int32 status
-    vector (still exactly one transfer per tick):
-
-        chunk(slab, stop, admit, ..., new_tol, prev_stat, stall)
-            -> (slab, status, prev_stat, stall)
-    """
-    core = _chunk_core(spec, cfg, chunk_iters, health)
-
-    if health is None:
-        @partial(jax.jit, donate_argnums=(0, 1))
-        def chunk(slab: SlabState, stop, admit, new_c, new_x0, new_ids,
-                  new_active=None, new_tol=None):
-            if new_active is None:
-                new_active = jnp.ones_like(slab.active)
-            if new_tol is None:
-                new_tol = jnp.full_like(slab.c, cfg.tol)
-            return core(slab, stop, admit, new_c, new_x0, new_ids,
-                        new_active, new_tol)
-    else:
-        @partial(jax.jit, donate_argnums=(0, 1, 8, 9))
-        def chunk(slab: SlabState, stop, admit, new_c, new_x0, new_ids,
-                  new_active, new_tol, prev_stat, stall):
-            if new_active is None:
-                new_active = jnp.ones_like(slab.active)
-            if new_tol is None:
-                new_tol = jnp.full_like(slab.c, cfg.tol)
-            return core(slab, stop, admit, new_c, new_x0, new_ids,
-                        new_active, new_tol, prev_stat, stall)
-
-    return chunk
-
-
-make_chunk_stepper = CompileCache("chunk_stepper", _build_chunk_stepper)
-
-
-def _build_sharded_chunk_stepper(spec: BatchedProblemSpec,
-                                 cfg: SolverConfig, chunk_iters: int,
-                                 n_devices: int,
-                                 health: HealthConfig | None = None):
-    """Compile the fused tick with the slot axis sharded over a 1-D
-    device mesh — the kernel of ``repro.serve.mesh.MeshServeEngine``.
-
-    The body is literally :func:`_chunk_core` — bit-for-bit the program
-    :func:`make_chunk_stepper` runs — wrapped in ``shard_map`` with
-    every argument partitioned on its leading (slot) dimension, so each
-    of the ``n_devices`` mesh devices advances its own contiguous block
-    of ``S / n_devices`` slots.  The core is collective-free (per-slot
-    vmap + masked selects; no ``axis_index``, no cross-slot reductions),
-    so the sharded program needs no communication.
-
-    The slab capacity S must be divisible by ``n_devices`` (the engine
-    allocates S = n_devices × per-device capacity).  Slab and stop mask
-    are donated exactly as in the single-device stepper.
-    """
+def _shard_slots(core, spec: BatchedProblemSpec, n_devices: int,
+                 n_carry: int):
+    """``core`` under ``shard_map`` over a 1-D ``("serve",)`` mesh of
+    ``n_devices``, every argument and result partitioned on its leading
+    (slot) dimension: each device advances its own contiguous block of
+    ``S / n_devices`` slots.  The core is collective-free, so the
+    sharded program needs no communication.  ``n_carry`` trailing
+    arguments (the health carry) shard like the rest, and the core
+    returns ``1 + n_carry`` per-slot vectors after the slab."""
     from jax.sharding import PartitionSpec
 
     from repro.launch.mesh import make_mesh
 
-    core = _chunk_core(spec, cfg, chunk_iters, health)
     mesh = make_mesh((int(n_devices),), ("serve",))
     row = PartitionSpec("serve")       # shard dim 0, replicate the rest
     slab_specs = SlabState(
@@ -659,44 +560,59 @@ def _build_sharded_chunk_stepper(spec: BatchedProblemSpec,
         c=row, col_sq=row, tau_base=row,
         state=FlexaState(*([row] * len(FlexaState._fields))),
         active=row, tol=row)
-    payload_specs = (row,) * 5         # c, x0, ids, active, tol
-    if health is None:
-        in_specs = (slab_specs, row, row) + payload_specs
-        out_specs = (slab_specs, row)
-    else:
-        # Health carry (prev_stat, stall) shards on the slot axis like
-        # everything else; the verdict replaces the stop mask output.
-        in_specs = (slab_specs, row, row) + payload_specs + (row, row)
-        out_specs = (slab_specs, row, row, row)
-    sharded = jax.shard_map(core, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False)
+    # stop, admit, then c, x0, ids, active, tol, then the carry
+    in_specs = (slab_specs,) + (row,) * (7 + n_carry)
+    out_specs = (slab_specs,) + (row,) * (1 + n_carry)
+    return jax.shard_map(core, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    if health is None:
-        @partial(jax.jit, donate_argnums=(0, 1))
-        def chunk(slab: SlabState, stop, admit, new_c, new_x0, new_ids,
-                  new_active=None, new_tol=None):
-            if new_active is None:
-                new_active = jnp.ones_like(slab.active)
-            if new_tol is None:
-                new_tol = jnp.full_like(slab.c, cfg.tol)
-            return sharded(slab, stop, admit, new_c, new_x0, new_ids,
-                           new_active, new_tol)
-    else:
-        @partial(jax.jit, donate_argnums=(0, 1, 8, 9))
-        def chunk(slab: SlabState, stop, admit, new_c, new_x0, new_ids,
-                  new_active, new_tol, prev_stat, stall):
-            if new_active is None:
-                new_active = jnp.ones_like(slab.active)
-            if new_tol is None:
-                new_tol = jnp.full_like(slab.c, cfg.tol)
-            return sharded(slab, stop, admit, new_c, new_x0, new_ids,
-                           new_active, new_tol, prev_stat, stall)
+
+def _build_chunk_stepper(spec: BatchedProblemSpec, cfg: SolverConfig,
+                         chunk_iters: int, n_devices: int = 1,
+                         health: HealthConfig | None = None):
+    """Compile one fused scheduler tick (see :func:`_chunk_core` for the
+    phase-by-phase contract):
+
+        chunk(slab, stop, admit, new_c, new_x0, new_ids, new_active,
+              new_tol, *health_carry) -> (slab, stop)
+
+    Fusing admission into the step matters operationally: a scheduler
+    tick is ONE chunk program and one (S,) mask readback, however many
+    requests were admitted — separate per-slot splice calls would pay
+    dispatch per admission and dominate the serving makespan at small
+    instance sizes.  Only the admitted data rows travel apart
+    (:func:`make_row_writer`), so the chunk's arguments hold one data
+    slab and (S,)/(S, n) per-slot vectors.  The slab, the stop mask and
+    the health carry are donated (in-place advance).
+
+    ``health_carry`` is empty while ``health`` is ``None``.  With
+    ``health`` set it is the device-resident per-slot carry
+    ``(prev_stat, stall)``, and the readback widens to an int32 status
+    vector (still exactly one transfer per tick):
+
+        chunk(slab, stop, admit, ..., new_tol, prev_stat, stall)
+            -> (slab, status, prev_stat, stall)
+
+    With ``n_devices > 1`` the slot axis is sharded over that many
+    devices (:func:`_shard_slots`, the mesh engine's program); the slab
+    capacity S must then be divisible by ``n_devices``.  At one device
+    the program is the plain jitted core.
+    """
+    core = _chunk_core(spec, cfg, chunk_iters, health)
+    n_carry = 0 if health is None else 2
+    if n_devices > 1:
+        core = _shard_slots(core, spec, n_devices, n_carry)
+
+    @partial(jax.jit, donate_argnums=(0, 1) + tuple(range(8, 8 + n_carry)))
+    def chunk(slab: SlabState, stop, admit, new_c, new_x0, new_ids,
+              new_active, new_tol, *health_carry):
+        return core(slab, stop, admit, new_c, new_x0, new_ids,
+                    new_active, new_tol, *health_carry)
 
     return chunk
 
 
-make_sharded_chunk_stepper = CompileCache("sharded_chunk_stepper",
-                                          _build_sharded_chunk_stepper)
+make_chunk_stepper = CompileCache("chunk_stepper", _build_chunk_stepper)
 
 
 def read_slots(state: FlexaState, slots) -> list[FlexaState]:
@@ -820,27 +736,22 @@ def _solve_batched(problems: Sequence[Problem], x0=None,
             meta={"batch": B, "family": spec.family,
                   "wall_s": time.perf_counter() - t0})
 
-    # History path: same math, stepped from the host so trajectories can be
-    # recorded (used by benchmarks; convergence freezing identical).
-    fam = get_family(spec.family)
-    vstep = jax.jit(jax.vmap(partial(_instance_step, spec, cfg)))
-    col_sq = jax.vmap(fam.col_sq)(*data)
-    tau_base = jax.vmap(
-        lambda csq: _tau_base(fam.half_curv(csq), cfg, spec.n))(col_sq)
+    # History path: the same freeze-merge step, stepped from the host so
+    # trajectories can be recorded (used by benchmarks).
+    step = jax.jit(partial(_frozen_step,
+                           jax.vmap(partial(_instance_step, spec, cfg)), cfg))
+    col_sq, tau_base = _stack_norms(spec, cfg, data)
     if active is None:
         active = jnp.ones((B, spec.n), jnp.float32)
     state = jax.vmap(partial(_instance_init, spec, cfg))(
         data, c, x0, jnp.arange(B))
-    done = np.zeros((B,), bool)
+    done = jnp.zeros((B,), bool)
     hist: dict[str, list] = {k: [] for k in
                              ("V", "stat", "E_max", "sel_frac", "gamma",
                               "tau_scale", "time")}
-    while not done.all():
-        new_state, info = vstep(data, c, col_sq, tau_base, active, state)
-        state = _freeze_done(jnp.asarray(done), new_state, state)
-        stat = np.asarray(state.stat)
-        done = done | (stat <= cfg.tol) | (np.asarray(state.k)
-                                           >= cfg.max_iters)
+    while not np.asarray(done).all():
+        state, done, info = step(data, c, col_sq, tau_base, active, state,
+                                 done, cfg.tol)
         for key in ("V", "stat", "E_max", "sel_frac", "gamma", "tau_scale"):
             hist[key].append(np.asarray(info[key]))
         hist["time"].append(time.perf_counter() - t0)

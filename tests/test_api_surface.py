@@ -35,15 +35,13 @@ SURFACE = {
         "BatchedProblemSpec", "SlabState", "SolverResult",
         "available_methods", "cache_stats", "get_solver",
         "make_batched_solver", "make_chunk_stepper",
-        "make_sharded_chunk_stepper", "make_slot_writer",
         "register", "slab_alloc",
     ],
     "repro.serve": [
-        "AdmissionQueue", "ContinuousSolverEngine", "GenerationResult",
+        "AdmissionQueue", "ContinuousSolverEngine",
         "MeshServeEngine", "MeshTelemetry",
         "PathRequest", "PathState", "QueueEntry", "RequestTrace",
-        "ServeEngine", "ServeTelemetry", "SolveRequest", "SolveResponse",
-        "SolverServeEngine",
+        "ServeTelemetry", "SolveRequest", "SolveResponse",
     ],
     "repro.path": [
         "DEFAULT_KKT_SLACK", "MAX_KKT_ROUNDS", "PathResult",
@@ -58,7 +56,7 @@ SURFACE = {
         "PathSpec",
         "SoloResult", "SoloSpec", "SpecError", "TicketDiagnostics",
         "UnknownBackendError",
-        "UnsupportedWorkloadError", "WaveBackend", "WorkItem",
+        "UnsupportedWorkloadError", "WorkItem",
         "available_backends", "make_backend", "normalize",
         "register_backend", "solve_request_of",
     ],
@@ -89,24 +87,43 @@ def test_public_surface_snapshot(module):
         assert hasattr(mod, name), f"{module}.{name} exported but absent"
 
 
-def test_remote_package_stays_lazy():
-    """``import repro.remote`` exposes only policy + protocol; the
-    server and the registered backend are imported on demand (the
-    client registry pulls ``repro.remote.backend`` the first time
-    ``backend="remote"`` is requested).  Checked in a fresh interpreter:
-    this process may already hold both from another test file."""
+def _fresh_interpreter(code: str) -> str:
+    """Run ``code`` in a new Python process on the CPU; its stdout."""
     import subprocess
     import sys
-    code = ("import sys, repro.remote; "
-            "print(sorted(m for m in ('repro.remote.server', "
-            "'repro.remote.backend') if m in sys.modules))")
     src = os.path.dirname(os.path.dirname(os.path.dirname(
         repro.client.__file__)))
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": os.pathsep.join(
         p for p in (src, os.environ.get("PYTHONPATH")) if p)}
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, check=True, timeout=300, env=env)
-    assert out.stdout.strip() == "[]", out.stdout + out.stderr
+    return out.stdout.strip() + out.stderr
+
+
+@pytest.mark.parametrize("module", ("repro.client", "repro.serve",
+                                    "repro.solvers"))
+def test_solver_packages_never_import_the_lm_stack(module):
+    """The solver service's packages load no LM model code (the LM
+    generator lives in ``repro.models.generate``).  Checked in a fresh
+    interpreter: this process may hold the models from another test."""
+    out = _fresh_interpreter(
+        f"import sys, {module}; "
+        "print(sorted(m for m in sys.modules "
+        "if m.startswith('repro.models')))")
+    assert out.splitlines()[0] == "[]", out
+
+
+def test_remote_package_stays_lazy():
+    """``import repro.remote`` exposes only policy + protocol; the
+    server and the registered backend are imported on demand (the
+    client registry pulls ``repro.remote.backend`` the first time
+    ``backend="remote"`` is requested).  Checked in a fresh interpreter:
+    this process may already hold both from another test file."""
+    out = _fresh_interpreter(
+        "import sys, repro.remote; "
+        "print(sorted(m for m in ('repro.remote.server', "
+        "'repro.remote.backend') if m in sys.modules))")
+    assert out.splitlines()[0] == "[]", out
 
 
 # ------------------------------------------------------------------ #
@@ -159,8 +176,8 @@ def test_engine_construction_warns_once(mini):
     try:
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
-            repro.serve.SolverServeEngine(SolverConfig(max_iters=5))
-            repro.serve.SolverServeEngine(SolverConfig(max_iters=5))
+            repro.serve.MeshServeEngine(SolverConfig(max_iters=5))
+            repro.serve.MeshServeEngine(SolverConfig(max_iters=5))
             repro.serve.ContinuousSolverEngine(
                 SolverConfig(max_iters=5), ServeConfig(slab_capacity=2))
         fw = _future_warnings(w)
@@ -178,7 +195,7 @@ def test_client_backends_never_trigger_legacy_warnings(mini):
         cfg = SolverConfig(tol=1e-6, max_iters=500, tau_adapt=False)
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
-            for backend in ("inline", "wave", "continuous", "mesh"):
+            for backend in ("inline", "continuous", "mesh"):
                 FlexaClient(backend=backend, solver=cfg).run(
                     SoloSpec(problem=mini))
         assert _future_warnings(w) == []

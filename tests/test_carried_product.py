@@ -5,7 +5,8 @@ for the gradient and one forward product for the objective.
 * the pass count, read off the traced program (2 per iteration for every
   family and layout, in the solo step and in the continuous chunk body);
 * the carried u equals a fresh product at the state's x after every
-  driver (solo, compiled, wave, continuous and mesh slabs, freeze masks);
+  driver (solo, compiled, batched, continuous and mesh slabs, freeze
+  masks);
 * the iteration against the three-pass iteration it replaced, kept here
   as the oracle: same iterations, stat, objective history and x.
 """
@@ -184,9 +185,9 @@ def test_solo_drivers_carry_the_product_at_x(case):
 
 @pytest.mark.parametrize("case", ["lasso", "logreg", "lasso_sparse"])
 def test_wave_run_carries_the_product_with_a_freeze_mask(case):
-    """The wave program, with instances that stop at different
-    iterations (frozen while the others run) and a freeze mask on some
-    coordinates."""
+    """The batched run-to-convergence program, with instances that stop
+    at different iterations (frozen while the others run) and a freeze
+    mask on some coordinates."""
     probs = [PROBLEMS[case](s) for s in range(3)]
     n = probs[0].n
     active = np.ones((3, n), np.float32)

@@ -1,7 +1,7 @@
 """``repro.client`` — the one front door.
 
 The acceptance matrix lives here: all four workload kinds (solo, batch,
-path, CV) × all three backends (inline, wave, continuous) × ≥2 problem
+path, CV) × three backends (inline, continuous, mesh) × ≥2 problem
 families, each compared against the legacy entry point it replaces:
 
 * inline results are **bitwise** equal to the legacy path (same code,
@@ -10,7 +10,7 @@ families, each compared against the legacy entry point it replaces:
   envelope (fp32 reduction-order noise shifts stopping times, never
   answers — see repro/solvers/batched.py).
 
-Plus the session behaviours (stream/step/pending, buffered waves,
+Plus the session behaviours (stream/step/pending,
 backend capability errors, spec validation, ClientConfig composition)
 and the coarse-to-fine CV continuation contract.
 """
@@ -27,16 +27,16 @@ from repro.config.base import ClientConfig, ServeConfig, SolverConfig
 from repro.problems.lasso import make_lasso, nesterov_instance
 from repro.problems.logreg import random_logreg_instance
 
-BACKENDS = ("inline", "wave", "continuous")
+BACKENDS = ("inline", "continuous", "mesh")
 #: Fixed τ + tol-stopping at 1e-7: the configuration whose cross-driver
 #: agreement the serve/path PRs measured at ≤1e-5 (3e-6 typical).
 CFG = SolverConfig(tol=1e-7, max_iters=4000, tau_adapt=False)
-SERVE = ServeConfig(max_batch=4, slab_capacity=4, chunk_iters=50)
+SERVE = ServeConfig(slab_capacity=4, chunk_iters=50)
 SOLO_FAMILIES = ("lasso", "logreg")
 PATH_FAMILIES = ("lasso", "group_lasso")
 GRID = dict(n_points=5, lam_min_ratio=0.1)
 
-ATOL = {"inline": 0.0, "wave": 1e-5, "continuous": 1e-5}
+ATOL = {"inline": 0.0, "continuous": 1e-5, "mesh": 1e-5}
 
 
 def client(backend: str) -> FlexaClient:
@@ -176,7 +176,7 @@ def test_matrix_cv(backend, family, cv_refs):
                   backend)
 
 
-@pytest.mark.parametrize("backend", ("wave", "continuous"))
+@pytest.mark.parametrize("backend", ("continuous", "mesh"))
 def test_matrix_path_cold_respects_warm_flag(backend, path_refs):
     """PathSpec.warm/screen reach the serve path protocol too: a cold
     unscreened path through a serve backend matches the inline cold
@@ -248,18 +248,6 @@ def test_stream_yields_in_completion_order():
     for t in tickets:
         assert seen[t].converged
 
-def test_wave_backend_buffers_then_batches_one_wave():
-    c = client("wave")
-    for s in range(3):
-        c.submit(SoloSpec(problem=_instance("lasso", s)))
-    assert c.pending == 3                  # nothing dispatched yet
-    done = c.step()                        # ONE wave for all three
-    assert len(done) == 3 and c.pending == 0
-    stats = c.stats()
-    assert stats["engines"][0]["requests"] == 3
-    assert stats["engines"][0]["batches"] == 1
-
-
 def test_inline_completes_at_submit():
     c = client("inline")
     t = c.submit(SoloSpec(problem=_instance("lasso", 0)))
@@ -289,24 +277,24 @@ def test_solo_history_contract_inline():
 def test_unknown_backend_rejected():
     with pytest.raises(UnknownBackendError, match="unknown backend"):
         FlexaClient(backend="quantum")
-    assert set(available_backends()) >= {"inline", "wave", "continuous"}
+    assert set(available_backends()) >= {"inline", "continuous", "mesh"}
 
 
-@pytest.mark.parametrize("backend", ("wave", "continuous"))
+@pytest.mark.parametrize("backend", ("continuous", "mesh"))
 def test_non_flexa_methods_are_inline_only(backend):
     with pytest.raises(UnsupportedWorkloadError, match="inline"):
         client(backend).submit(SoloSpec(problem=_instance("lasso", 0),
                                         method="fista"))
 
 
-@pytest.mark.parametrize("backend", ("wave", "continuous"))
+@pytest.mark.parametrize("backend", ("continuous", "mesh"))
 def test_record_history_is_inline_only(backend):
     with pytest.raises(UnsupportedWorkloadError, match="record_history"):
         client(backend).submit(BatchSpec(
             problems=[_instance("lasso", 0)], record_history=True))
 
 
-@pytest.mark.parametrize("backend", ("wave", "continuous"))
+@pytest.mark.parametrize("backend", ("continuous", "mesh"))
 def test_nonquadratic_paths_are_inline_only(backend):
     with pytest.raises(UnsupportedWorkloadError, match="inline"):
         client(backend).submit(PathSpec(problem=_instance("logreg", 0),
@@ -352,28 +340,15 @@ def test_eager_submit_failure_leaks_no_ticket():
 
 
 # ------------------------------------------------------------------ #
-# Config composition (the ServeConfig.max_batch wart, retired)       #
+# Config composition                                                 #
 # ------------------------------------------------------------------ #
 def test_client_config_composes_solver_and_serve():
     cfg = ClientConfig(solver=CFG,
-                       serve=ServeConfig(max_batch=8), backend="wave")
+                       serve=ServeConfig(slab_capacity=2),
+                       backend="continuous")
     c = FlexaClient(cfg)
-    assert c.config.serve.max_batch == 8
-    assert c.backend == "wave"
+    assert c.config.serve.slab_capacity == 2
+    assert c.backend == "continuous"
     # overrides win over the config object's fields
     c2 = FlexaClient(cfg, backend="inline")
-    assert c2.backend == "inline" and c2.config.serve.max_batch == 8
-
-
-def test_wave_engine_accepts_serve_config_directly():
-    """The satellite: no more hand-threading ``max_batch`` — the wave
-    engine takes the same ServeConfig as the continuous engine, and the
-    plain kwarg stays as a back-compat override."""
-    from repro.serve import SolverServeEngine
-
-    eng = SolverServeEngine(CFG, ServeConfig(max_batch=8))
-    assert eng.max_batch == 8
-    eng = SolverServeEngine(CFG, ServeConfig(max_batch=8), max_batch=2)
-    assert eng.max_batch == 2              # explicit kwarg wins
-    eng = SolverServeEngine(CFG)
-    assert eng.max_batch == ServeConfig().max_batch
+    assert c2.backend == "inline" and c2.config.serve.slab_capacity == 2

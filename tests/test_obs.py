@@ -180,12 +180,14 @@ def test_telemetry_ledger_from_chunks_and_waves():
                       flops=10 * 4 * 24 * 64)
     for iters in (10, 10, 4):               # the three slots' requests
         tele.record_advanced(iters)
-    tele.record_wave(bucket=8, n_real=5, iters=[7, 7, 3, 2, 1],
-                     wall_s=0.1, flops=8 * 7 * 24 * 64)
+    tele.record_chunk(live=5, capacity=8, chunk_iters=7, wall_s=0.1,
+                      flops=7 * 8 * 24 * 64)
+    for iters in (7, 7, 3, 2, 1):           # the second slab's requests
+        tele.record_advanced(iters)
     led = tele.ledger()
     assert led.conserved()
-    # chunk: row 40, occupied 30, advanced 24 → freeze 6, padding 10
-    # wave:  row 56, live 20, padding 3·7=21, freeze 56−20−21=15
+    # chunk 1: row 40, occupied 30, advanced 24 → freeze 6, padding 10
+    # chunk 2: row 56, occupied 35, advanced 20 → freeze 15, padding 21
     assert led.row_iters == 40 + 56
     assert led.live_iters == 24 + 20
     assert led.padding_iters == 10 + 21
@@ -193,8 +195,7 @@ def test_telemetry_ledger_from_chunks_and_waves():
     assert led.device_flops == (40 + 56) * 24 * 64
     snap = tele.snapshot()
     assert snap["ledger"]["row_iters"] == led.row_iters
-    assert snap["wave"]["device_flops"] == 56 * 24 * 64
-    assert snap["continuous"]["device_flops"] == 40 * 24 * 64
+    assert snap["continuous"]["device_flops"] == (40 + 56) * 24 * 64
 
 
 # ------------------------------------------------------------------ #
@@ -230,7 +231,7 @@ def test_snapshot_empty_telemetry_percentiles_are_none():
     for key in ("latency_p50", "latency_p99", "latency_mean",
                 "latency_max", "queue_wait_p50", "queue_wait_p99"):
         assert snap[key] is None
-    assert "continuous" not in snap and "wave" not in snap
+    assert "continuous" not in snap
 
 
 def test_snapshot_schema_stability():
@@ -239,14 +240,13 @@ def test_snapshot_schema_stability():
     tele.record_admit(0)
     tele.record_completion(0, iters=5, converged=True)
     tele.record_chunk(live=1, capacity=2, chunk_iters=5, wall_s=0.1)
-    tele.record_wave(bucket=2, n_real=1, iters=[5], wall_s=0.1)
     snap = tele.snapshot()
     assert set(snap) == {
         "schema",
         "requests", "completed", "in_flight", "converged", "iters_total",
         "latency_p50", "latency_p99", "latency_mean", "latency_max",
         "queue_wait_p50", "queue_wait_p99", "ledger", "compile_cache",
-        "continuous", "wave"}
+        "continuous"}
     assert set(snap["ledger"]) == set(LEDGER_KEYS) | {"utilization"}
 
 

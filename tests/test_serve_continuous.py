@@ -12,8 +12,8 @@ from repro.problems.lasso import nesterov_instance
 from repro.problems.logreg import random_logreg_instance
 from repro.problems.svm import random_svm_instance
 from repro.serve import (AdmissionQueue, ContinuousSolverEngine,
-                         QueueEntry, ServeTelemetry, SolveRequest,
-                         SolverServeEngine)
+                         MeshServeEngine, MeshTelemetry, QueueEntry,
+                         ServeTelemetry, SolveRequest)
 from repro.solvers.api import _solve as solve
 from repro.solvers.cache import cache_stats
 import repro.solvers.batched as B
@@ -89,9 +89,9 @@ def test_continuous_convergence_eviction_matches_solo():
 
 
 def test_chunk_stepper_matches_wave_program():
-    """A full slab chunk-stepped to completion reproduces the wave
-    while_loop program exactly (same freeze merge ⇒ same stopping
-    iteration, chunk size K irrelevant)."""
+    """A full slab chunk-stepped to completion reproduces the batched
+    run-to-convergence while_loop program exactly (same freeze merge ⇒
+    same stopping iteration, chunk size K irrelevant)."""
     import jax.numpy as jnp
 
     probs = FAMILY_BATCHES["lasso"]()[:4]
@@ -110,7 +110,7 @@ def test_chunk_stepper_matches_wave_program():
     ids = [eng.submit(to_request(p)) for p in probs]
     resps = eng.drain()
     for j, i in enumerate(ids):
-        # NB the wave program seeds per-instance keys by *slot*, the
+        # NB the batched program seeds per-instance keys by *slot*, the
         # continuous runtime by *request id* — identical here because
         # submission order fills slots 0..3 with ids 0..3.
         assert resps[i].iters == int(np.asarray(wave_final.k)[j])
@@ -259,32 +259,80 @@ def test_continuous_engine_rejects_malformed_requests():
 # ------------------------------------------------------------------ #
 # Slab pack/unpack API                                               #
 # ------------------------------------------------------------------ #
-def test_slot_writer_packs_one_instance():
-    import jax
+def _one_tick(layout: str, watchdog: bool):
+    """One tick of the one chunk builder at ``n_devices=1`` on a slab of
+    four slots, three of them admitted this tick (the fourth empty)."""
     import jax.numpy as jnp
-    from repro.core import flexa as _flexa
+    from repro.obs.health import HealthConfig
+    from test_sparse_designs import csc_from_dense
 
-    p = nesterov_instance(m=20, n=64, nnz_frac=0.15, c=1.0, seed=0)
-    cfg = SolverConfig()
-    spec = B.BatchedProblemSpec.of(p)
-    slab = B.slab_alloc(spec, cfg, capacity=3)
-    write = B.make_slot_writer(spec, cfg)
-    key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), 42)
-    slab = write(slab, jnp.asarray(1, jnp.int32),
-                 (jnp.asarray(p.data["A"]), jnp.asarray(p.data["b"])),
-                 jnp.asarray(1.0, jnp.float32),
-                 jnp.zeros((spec.n,), jnp.float32), key)
-    np.testing.assert_allclose(np.asarray(slab.data[0][1]),
-                               np.asarray(p.data["A"]), atol=1e-6)
-    assert float(np.asarray(slab.c)[1]) == 1.0
-    (row,) = B.read_slots(slab.state, [1])
-    ref = _flexa.init_state(p, np.zeros(spec.n, np.float32), cfg,
-                            key=key)
-    np.testing.assert_allclose(row.v_prev, float(ref.v_prev), rtol=1e-6)
-    assert row.k == 0 and np.isinf(row.stat)
-    # untouched slots keep their empty-slab placeholders
-    assert float(np.asarray(slab.c)[0]) == 1.0
-    assert np.isinf(np.asarray(slab.state.stat)[0])
+    probs = FAMILY_BATCHES["lasso"]()[:3]
+    reqs = [SolveRequest(A=(np.asarray(p.data["A"]) if layout == "dense"
+                            else csc_from_dense(p.data["A"])),
+                         b=np.asarray(p.data["b"]), c=float(p.g_weight))
+            for p in probs]
+    spec = reqs[0].spec
+    assert spec.layout == layout
+    cfg = SolverConfig(max_iters=300, tol=1e-6, tau_adapt=False)
+    S, n = 4, spec.n
+    slab = B.slab_alloc(spec, cfg, S)
+    write = B.make_row_writer(spec)
+    for slot, r in enumerate(reqs):
+        slab = write(slab, np.int32(slot),
+                     *(jnp.asarray(a) if not hasattr(a, "padded") else a
+                       for a in r.data_arrays(spec)))
+    admit = np.array([True, True, True, False])
+    payload = (np.array([r.c for r in reqs] + [1.0], np.float32),
+               np.zeros((S, n), np.float32),
+               np.arange(S, dtype=np.int32),
+               np.ones((S, n), np.float32),
+               np.full((S,), cfg.tol, np.float32))
+    health = HealthConfig(stall_window=10) if watchdog else None
+    carry = () if health is None else (np.full((S,), np.inf, np.float32),
+                                       np.zeros((S,), np.int32))
+    chunk = B.make_chunk_stepper(spec, cfg, 8, 1, health)
+    return chunk(slab, jnp.ones((S,), bool), jnp.asarray(admit),
+                 *(jnp.asarray(a) for a in payload),
+                 *(jnp.asarray(a) for a in carry))
+
+
+@pytest.mark.parametrize("layout", ("dense", "csc"))
+@pytest.mark.parametrize("watchdog", (False, True), ids=("off", "on"))
+def test_one_chunk_builder_at_one_device(watchdog, layout):
+    """The one chunk builder at one device: a tick returns ``(slab,
+    stop)`` with the watchdog off and ``(slab, status, prev_stat,
+    stall)`` with it on, and on healthy slots its slab equals the other
+    variant's bit for bit (the health pass only reads the iterates)."""
+    import jax
+    from repro.obs.health import STATUS_RUNNING, STATUS_STOPPED
+
+    out = _one_tick(layout, watchdog)
+    other = _one_tick(layout, not watchdog)
+    if watchdog:
+        assert len(out) == 4
+        slab, status, prev_stat, stall = out
+        assert np.asarray(status).dtype == np.int32
+        assert set(np.asarray(status).tolist()) <= {STATUS_RUNNING,
+                                                    STATUS_STOPPED}
+        np.testing.assert_array_equal(np.asarray(prev_stat)[:3],
+                                      np.asarray(slab.state.stat)[:3])
+        assert np.asarray(stall).dtype == np.int32
+        stop = np.asarray(status) != STATUS_RUNNING
+        other_slab, other_stop = other
+    else:
+        assert len(out) == 2
+        slab, stop = out
+        assert np.asarray(stop).dtype == bool
+        other_slab, status = other[:2]
+        np.testing.assert_array_equal(
+            np.asarray(status) != STATUS_RUNNING, np.asarray(stop))
+        other_stop = np.asarray(status) != STATUS_RUNNING
+    np.testing.assert_array_equal(np.asarray(stop), np.asarray(other_stop))
+    assert not np.asarray(stop)[:3].all()       # admitted slots ran
+    assert np.asarray(slab.state.k)[:3].min() > 0
+    for a, b in zip(jax.tree_util.tree_leaves(slab),
+                    jax.tree_util.tree_leaves(other_slab)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
 def test_row_writer_leaves_other_slots_bitwise_unchanged():
@@ -386,8 +434,7 @@ def test_compile_cache_bounded_by_env(monkeypatch):
     assert cache.maxsize() == cache.default_maxsize
 
     snap = cache_stats()
-    for name in ("batched_solver", "chunk_stepper", "slot_writer",
-                 "row_writer"):
+    for name in ("batched_solver", "chunk_stepper", "row_writer"):
         assert {"hits", "misses", "evictions", "size",
                 "maxsize"} <= set(snap[name])
 
@@ -401,55 +448,23 @@ def test_cache_counters_flow_through_serve_telemetry():
 # ------------------------------------------------------------------ #
 # Telemetry                                                          #
 # ------------------------------------------------------------------ #
-def test_wave_engine_reports_padding_and_occupancy():
-    probs = FAMILY_BATCHES["lasso"]()[:3]
-    cfg = SolverConfig(max_iters=300, tol=1e-6, tau_adapt=False)
-    eng = SolverServeEngine(cfg, max_batch=4)
-    eng.submit([to_request(p) for p in probs])     # 3 → bucket of 4
-
-    assert eng.stats["padded"] == 1
-    assert 0.0 < eng.stats["occupancy"] < 1.0
-    assert eng.stats["padding_waste"] == pytest.approx(0.25)
-    (wave,) = eng.telemetry.waves
-    assert wave["bucket"] == 4 and wave["n_real"] == 3
-    assert wave["occupancy"] == pytest.approx(0.75)
-    assert wave["padding_waste"] + wave["freeze_waste"] < 1.0
-    snap = eng.telemetry.snapshot()
-    assert snap["wave"]["waves"] == 1
-    assert snap["completed"] == 3
-    assert snap["latency_p99"] is not None
-
-
 def test_shared_telemetry_never_collides_request_ids():
-    """One telemetry shared by both engines (the apples-to-apples mode)
+    """One telemetry shared by two engines (the apples-to-apples mode)
     must keep every request distinct — ids are allocated by the
     telemetry, not per-engine counters."""
     probs = FAMILY_BATCHES["lasso"]()[:2]
     cfg = SolverConfig(max_iters=50, tol=-1.0, tau_adapt=False)
-    tele = ServeTelemetry()
-    wave = SolverServeEngine(cfg, max_batch=2, telemetry=tele)
-    cont = ContinuousSolverEngine(
-        cfg, ServeConfig(slab_capacity=2, chunk_iters=16),
-        telemetry=tele)
-    wave.submit([to_request(p) for p in probs])
-    for p in probs:
-        cont.submit(to_request(p))
+    serve = ServeConfig(slab_capacity=2, chunk_iters=16, mesh_devices=1)
+    tele = MeshTelemetry()
+    mesh = MeshServeEngine(cfg, serve, telemetry=tele)
+    cont = ContinuousSolverEngine(cfg, serve, telemetry=tele)
+    ids = [eng.submit(to_request(p)) for eng in (mesh, cont)
+           for p in probs]
+    mesh.drain()
     cont.drain()
-    assert len(tele.requests) == 4
-    assert sorted(r.engine for r in tele.requests.values()) == \
-        ["continuous", "continuous", "wave", "wave"]
+    assert sorted(ids) == [0, 1, 2, 3]
+    assert sorted(tele.requests) == [0, 1, 2, 3]
     assert all(r.completed is not None for r in tele.requests.values())
-
-
-def test_wave_submit_backdates_arrivals():
-    probs = FAMILY_BATCHES["lasso"]()[:2]
-    cfg = SolverConfig(max_iters=50, tol=-1.0, tau_adapt=False)
-    eng = SolverServeEngine(cfg, max_batch=2)
-    eng.submit([to_request(p) for p in probs], arrivals=[-3.0, -1.0])
-    waits = sorted(r.queue_wait for r in eng.telemetry.requests.values())
-    assert waits[0] >= 1.0 and waits[1] >= 3.0
-    with pytest.raises(ValueError, match="align"):
-        eng.submit([to_request(probs[0])], arrivals=[0.0, 1.0])
 
 
 def test_telemetry_latency_percentiles_explicit_clock():
@@ -490,9 +505,9 @@ def test_trace_generators_are_seeded_and_shaped():
 
 @pytest.mark.slow
 def test_serve_load_full_sweep(tmp_path, monkeypatch):
-    """The full trace sweep: continuous must beat the wave engine on the
-    heavy-tail trace (makespan, p99, device work) with solo-equivalent
-    responses — the BENCH_serve.json acceptance block."""
+    """The full trace sweep: every trace replays through the continuous
+    engine, and its heavy-tail responses are solo-equivalent — the
+    BENCH_serve.json acceptance block."""
     import benchmarks.serve_load as SL
 
     monkeypatch.setattr(SL, "RESULTS", tmp_path)

@@ -221,6 +221,20 @@ def test_mesh_one_device_matches_continuous_bitwise():
     assert em.steal_log == []                # nowhere to steal from
 
 
+def test_mesh_one_device_runs_the_plain_chunk_program():
+    """At one device the one chunk builder hands the mesh slab the
+    continuous slab's own compiled program (no ``shard_map``)."""
+    p = FAMILY_BATCHES["lasso"]()[0]
+    em = MeshServeEngine(CFG, mesh_serve())
+    ec = ContinuousSolverEngine(
+        CFG, ServeConfig(slab_capacity=2, chunk_iters=16))
+    em.submit(to_request(p))
+    ec.submit(to_request(p))
+    (sm,), (sc,) = em._slabs.values(), ec._slabs.values()
+    assert sm.n_devices == sc.n_devices == 1
+    assert sm._chunk is sc._chunk
+
+
 def test_mesh_engine_validates_config():
     avail = len(jax.devices())
     with pytest.raises(ValueError, match="XLA_FLAGS"):

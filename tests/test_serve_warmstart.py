@@ -104,15 +104,6 @@ def test_warm_from_validation_errors():
     eng.drain()
 
 
-def test_wave_engine_rejects_warm_from():
-    from repro.serve import SolverServeEngine
-
-    _, A, b = _instance()
-    eng = SolverServeEngine(CFG)
-    with pytest.raises(ValueError, match="continuous-engine feature"):
-        eng.submit([SolveRequest(A=A, b=b, c=1.0, warm_from=0)])
-
-
 # ------------------------------------------------------------------ #
 # PathRequest through the engine
 # ------------------------------------------------------------------ #

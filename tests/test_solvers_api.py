@@ -215,43 +215,16 @@ def test_random_selection_is_seed_deterministic(mini_lasso):
 # ------------------------------------------------------------------ #
 # Solver serving engine                                              #
 # ------------------------------------------------------------------ #
-def test_solver_serve_engine_buckets_and_amortizes(mini_batch):
-    from repro.serve.engine import SolveRequest, SolverServeEngine
-
-    cfg = SolverConfig(max_iters=1500, tol=1e-7, tau_adapt=False)
-    eng = SolverServeEngine(cfg, max_batch=4)
-    reqs = [SolveRequest(A=np.asarray(p.data["A"]),
-                         b=np.asarray(p.data["b"]), c=float(p.g_weight))
-            for p in mini_batch[:3]]          # 3 requests → bucket of 4
-    odd = nesterov_instance(m=24, n=48, nnz_frac=0.15, c=1.0, seed=7)
-    reqs.append(SolveRequest(A=np.asarray(odd.data["A"]),
-                             b=np.asarray(odd.data["b"]), c=1.0))
-
-    resps = eng.submit(reqs)
-    assert eng.stats["requests"] == 4
-    assert eng.stats["padded"] == 1           # 3 → 4 bucket
-    assert eng.stats["signatures"] == 2       # two shape signatures
-    assert all(r.converged for r in resps)
-    assert all(r.stat <= 1e-7 for r in resps)
-    # tol-based stopping times carry fp32 noise (see the batched-match
-    # test) — at the common optimum 1e-4 separates right from wrong.
-    for i, p in enumerate(mini_batch[:3]):
-        ri = solve(p, method="flexa", cfg=cfg)
-        np.testing.assert_allclose(resps[i].x, np.asarray(ri.x), atol=1e-4)
-
-    # a second wave reuses the compiled signatures
-    eng.submit(reqs)
-    assert eng.stats["requests"] == 8
-    assert eng.stats["signatures"] == 2
-
-
 def test_solver_serve_engine_heterogeneous_family_mix(mini_batch):
-    """One wave mixing Lasso, logreg and svm requests: each family lands in
-    its own compiled signature and every response matches its solo solve."""
-    from repro.serve.engine import SolveRequest, SolverServeEngine
+    """One engine serving Lasso, logreg and svm requests together: each
+    family lands in its own slab (one per family signature) and every
+    response matches its solo solve."""
+    from repro.config.base import ServeConfig
+    from repro.serve import ContinuousSolverEngine, SolveRequest
 
     cfg = SolverConfig(max_iters=2000, tol=1e-6, tau_adapt=False)
-    eng = SolverServeEngine(cfg, max_batch=4)
+    eng = ContinuousSolverEngine(
+        cfg, ServeConfig(slab_capacity=4, chunk_iters=50))
     probs = list(mini_batch[:2]) \
         + [random_logreg_instance(m=30, n=48, nnz_frac=0.2, c=0.5, seed=s)
            for s in range(2)] \
@@ -262,8 +235,10 @@ def test_solver_serve_engine_heterogeneous_family_mix(mini_batch):
     reqs += [SolveRequest(A=np.asarray(p.data["Z"]), c=float(p.g_weight),
                           family=p.family) for p in probs[2:]]
 
-    resps = eng.submit(reqs)
-    assert eng.stats["signatures"] == 3
+    ids = [eng.submit(r) for r in reqs]
+    out = eng.drain()
+    resps = [out[i] for i in ids]
+    assert len(eng._slabs) == 3
     assert all(r.converged for r in resps)
     for i, p in enumerate(probs):
         ri = solve(p, method="flexa", cfg=cfg)
@@ -271,13 +246,14 @@ def test_solver_serve_engine_heterogeneous_family_mix(mini_batch):
 
 
 def test_solver_serve_engine_rejects_malformed_family_requests():
-    from repro.serve.engine import SolveRequest, SolverServeEngine
+    from repro.serve import ContinuousSolverEngine, SolveRequest
 
-    eng = SolverServeEngine(SolverConfig(max_iters=10))
+    eng = ContinuousSolverEngine(SolverConfig(max_iters=10))
     Z = np.zeros((5, 4), np.float32)
     with pytest.raises(ValueError, match="takes no b"):
-        eng.submit([SolveRequest(A=Z, b=np.zeros(5, np.float32),
-                                 family="logreg")])
+        eng.submit(SolveRequest(A=Z, b=np.zeros(5, np.float32),
+                                family="logreg"))
     with pytest.raises(ValueError, match="needs b"):
-        eng.submit([SolveRequest(A=Z, c=1.0)])
-    assert eng.stats["requests"] == 0      # atomic rejection
+        eng.submit(SolveRequest(A=Z, c=1.0))
+    # atomic rejection: nothing queued, no request recorded
+    assert eng.pending == 0 and not eng.telemetry.requests

@@ -9,7 +9,7 @@ from repro.configs.registry import get_reduced
 from repro.data.synthetic import TokenPipeline
 from repro.models import io as IO
 from repro.models import transformer as T
-from repro.serve.engine import ServeEngine
+from repro.models.generate import ServeEngine
 from repro.train.loop import StragglerMonitor, TrainLoop
 
 
