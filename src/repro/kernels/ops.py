@@ -197,17 +197,25 @@ def compact_best_response(x, g, d, c, idx, *, force=None):
 
 
 def blocked_product(values, gidx, sidx, tile_g, tile_s, table, n_out, *,
-                    force=None):
+                    live=None, force=None):
     """One product of a blocked sparse design (``problems.sparse.
     BlockedDesign``): out[sidx[k]] += values[k]·table[gidx[k]], (n_out,).
 
     ``values``, ``gidx``, ``sidx``: (L,) stored entries, L a multiple of
     ``spmv.TILE``; ``tile_g`` / ``tile_s``: (L / TILE,) int32, the block
-    of ``table`` / of the output that tile t's entries index.  On the
-    kernel path each tile's window of ``table`` is cut by a one-hot
+    of ``table`` / of the output that tile t's entries index, or −1 for
+    the tiles past the design's last block pair, which hold no entries.
+    On the kernel path each tile's window of ``table`` is cut by a one-hot
     product, the kernel forms each tile's partial output window, and a
     second one-hot product adds the windows into the output; both at
     ``Precision.HIGHEST``, so they move float32 values exactly.
+
+    The kernel computes only the tiles that hold entries, and none where
+    ``live`` (a bool scalar, one per design under ``vmap``) is False:
+    such a product is then zeros, for a caller that throws it away.
+    ``live=None`` (every unbatched call) computes every tile that holds
+    entries.  The answer of a computed product never depends on the gate.
+    The XLA path (``ref``) computes every entry whatever ``live`` says.
     """
     mode = _mode(force)
     if mode == "ref":
@@ -220,9 +228,12 @@ def blocked_product(values, gidx, sidx, tile_g, tile_s, table, n_out, *,
                   (0, n_g * B - table.shape[0])).reshape(n_g, B)
     win = jnp.dot(jax.nn.one_hot(tile_g, n_g, dtype=jnp.float32), tab,
                   precision=hi)
+    count = jnp.sum(tile_g >= 0, dtype=jnp.int32)   # the tiles with entries
+    if live is not None:
+        count = jnp.where(live, count, 0)
     part = _spmv.tile_products(
         values.reshape(nt, 8, 128), gidx.reshape(nt, 8, 128),
-        sidx.reshape(nt, 8, 128), win.reshape(nt, B // 128, 128),
+        sidx.reshape(nt, 8, 128), win.reshape(nt, B // 128, 128), count,
         interpret=mode == "interpret")
     out = jnp.dot(jax.nn.one_hot(tile_s, n_s, dtype=jnp.float32).T,
                   part.reshape(nt, B), precision=hi)

@@ -26,7 +26,10 @@ word frequencies.  The three products the quadratic families need — A·x,
 Aᵀ·r and the column norms ‖aⱼ‖² — are one pass over the tiles each
 (``repro.kernels.ops.blocked_product``: a Pallas kernel on TPU, gathers
 and ``segment_sum`` elsewhere).  A·x runs under the named scope
-``spmv``, Aᵀ·r and the column norms under ``spmv_t``.
+``spmv``, Aᵀ·r and the column norms under ``spmv_t``.  The tiles past a
+design's last block pair carry the block index −1 and are not computed,
+and :func:`gate_designs` marks a stack's designs whose products a caller
+throws away (stopped or empty slots), which are not computed either.
 :func:`design_matvec`, :func:`design_rmatvec` and :func:`design_col_sq`
 pick the product by layout, so the problem families keep one definition
 of their math for both layouts.
@@ -158,25 +161,31 @@ class CSCDesign:
 class BlockedDesign:
     """One design in the stored layout (module docstring): ``values``,
     ``rows``, ``cols`` (L,) by tile, and ``tile_rb``, ``tile_cb``
-    (L / TILE,) int32, the row block and column block of each tile.
+    (L / TILE,) int32, the row block and column block of each tile (−1
+    past the last block pair).  ``live`` is ``None``, or a bool scalar
+    (one per design of a stack) that gates the products: where it is
+    False they are zeros and are not computed (:func:`gate_designs`).
     A pytree, so a stack of designs vmaps like a stack of dense
     matrices."""
 
-    def __init__(self, values, rows, cols, tile_rb, tile_cb, shape):
+    def __init__(self, values, rows, cols, tile_rb, tile_cb, shape,
+                 live=None):
         self.values = values
         self.rows = rows
         self.cols = cols
         self.tile_rb = tile_rb
         self.tile_cb = tile_cb
         self.shape = (int(shape[0]), int(shape[1]))
+        self.live = live
 
     def tree_flatten(self):
         return ((self.values, self.rows, self.cols, self.tile_rb,
-                 self.tile_cb), self.shape)
+                 self.tile_cb, self.live), self.shape)
 
     @classmethod
     def tree_unflatten(cls, shape, children):
-        return cls(*children, shape)
+        *arrays, live = children
+        return cls(*arrays, shape, live=live)
 
     @property
     def m(self) -> int:
@@ -194,13 +203,15 @@ class BlockedDesign:
         """A·x, (m,)."""
         with jax.named_scope("spmv"):
             return ops.blocked_product(self.values, self.cols, self.rows,
-                                       self.tile_cb, self.tile_rb, x, self.m)
+                                       self.tile_cb, self.tile_rb, x, self.m,
+                                       live=self.live)
 
     def rmatvec(self, r) -> jnp.ndarray:
         """Aᵀ·r, (n,)."""
         with jax.named_scope("spmv_t"):
             return ops.blocked_product(self.values, self.rows, self.cols,
-                                       self.tile_rb, self.tile_cb, r, self.n)
+                                       self.tile_rb, self.tile_cb, r, self.n,
+                                       live=self.live)
 
     def col_sq(self) -> jnp.ndarray:
         """‖aⱼ‖² per column, (n,)."""
@@ -208,7 +219,7 @@ class BlockedDesign:
             return ops.blocked_product(
                 self.values * self.values, self.rows, self.cols,
                 self.tile_rb, self.tile_cb, jnp.ones((self.m,), jnp.float32),
-                self.n)
+                self.n, live=self.live)
 
 
 def _cols_of(col_ptr, L: int, n: int):
@@ -231,7 +242,9 @@ def block_layout(values, rows, col_ptr, shape) -> BlockedDesign:
     running count of its row block's entries less the count before its
     column block's run: one cumulative sum over (L, row blocks) int32,
     then one scatter of each array.  The top-up of each pair to whole
-    tiles, and the tiles past the last pair, hold zero entries.
+    tiles, and the tiles past the last pair, hold zero entries; the
+    latter carry the block index −1 (row and column), so the products
+    skip them.
     """
     m, n = shape
     L = values.shape[-1]
@@ -265,10 +278,13 @@ def block_layout(values, rows, col_ptr, shape) -> BlockedDesign:
 
     first = jnp.arange(L // TILE, dtype=i32)[:, None] * TILE
     start, size = base.reshape(1, -1), tiles.reshape(1, -1) * TILE
-    pair = jnp.argmax((first >= start) & (first < start + size), axis=1)
+    inside = (first >= start) & (first < start + size)
+    pair = jnp.argmax(inside, axis=1)
+    held = jnp.any(inside, axis=1)
     return BlockedDesign(
         place(jnp.asarray(values, jnp.float32)), place(rows), place(cols),
-        (pair % nrb).astype(i32), (pair // nrb).astype(i32), shape)
+        jnp.where(held, pair % nrb, -1).astype(i32),
+        jnp.where(held, pair // nrb, -1).astype(i32), shape)
 
 
 _block_layout_jit = jax.jit(block_layout, static_argnums=(3,))
@@ -276,6 +292,23 @@ _block_layout_jit = jax.jit(block_layout, static_argnums=(3,))
 
 def is_sparse(A) -> bool:
     return isinstance(A, (CSCDesign, BlockedDesign))
+
+
+def gate_designs(data: tuple, live, *, force_open: bool = False) -> tuple:
+    """A stack's family data with each sparse design's products gated to
+    the instances where ``live()``, a (B,) bool mask, holds; the others'
+    products are zeros and are not computed, for a caller that throws
+    them away.  Dense arrays pass through, and ``live`` is called only
+    when ``data`` holds a sparse design, so a dense stack traces exactly
+    as without the gate.  ``force_open`` (for tests) returns ``data``
+    unchanged."""
+    if force_open or not any(isinstance(d, BlockedDesign) for d in data):
+        return data
+    mask = live()
+    return tuple(
+        BlockedDesign(d.values, d.rows, d.cols, d.tile_rb, d.tile_cb,
+                      d.shape, live=mask)
+        if isinstance(d, BlockedDesign) else d for d in data)
 
 
 def design_matvec(A, x):

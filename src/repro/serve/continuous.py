@@ -264,11 +264,14 @@ class _SlotSlab:
         return make_chunk_stepper(self.spec, self.cfg, self.chunk_iters,
                                   self.n_devices, self._health_cfg)
 
-    def _record_chunk(self, wall: float) -> None:
-        self.telemetry.record_chunk(live=self.live, capacity=self.capacity,
-                                    chunk_iters=self.chunk_iters,
-                                    wall_s=wall,
-                                    flops=self._chunk_flops(self.capacity))
+    def _record_chunk(self, wall: float, gated) -> None:
+        """``gated``: the (S,) mask of the slots that entered the chunk
+        stopped, on a sparse slab (``None`` on a dense one)."""
+        self.telemetry.record_chunk(
+            live=self.live, capacity=self.capacity,
+            chunk_iters=self.chunk_iters, wall_s=wall,
+            flops=self._chunk_flops(self.capacity),
+            gated=None if gated is None else int(gated.sum()))
 
     def _record_advanced(self, slot: int, iters: int) -> None:
         """Iterations an evicted request advanced — the ledger's live
@@ -470,6 +473,10 @@ class _SlotSlab:
         if not self.active.any():
             return []
         t0 = time.perf_counter()
+        # Slots that enter the chunk stopped (empty or finished): on a
+        # sparse slab the products skip them (gate_designs).
+        gated = None if self.spec.layout == "dense" \
+            else self.stop & ~self._admit
         # NOTE the .copy() on every staging-buffer→device crossing:
         # jnp.asarray zero-copies aligned host buffers on CPU, and these
         # staging buffers are mutated on later ticks — an alias would
@@ -495,7 +502,9 @@ class _SlotSlab:
             admit = self._no_admit
         with obs.span("serve.chunk", cat="continuous", tick=tick,
                       live=self.live, capacity=self.capacity,
-                      chunk_iters=self.chunk_iters):
+                      chunk_iters=self.chunk_iters,
+                      **({} if gated is None
+                         else {"gated": int(gated.sum())})):
             self.slab, flags, *carry = self._chunk(
                 self.slab, self._to_device(self.stop.copy()), admit,
                 *self._payload, *self._health_carry)
@@ -508,7 +517,7 @@ class _SlotSlab:
         status = flags if self._health_carry else None
         stop = flags if status is None else status != STATUS_RUNNING
         wall = time.perf_counter() - t0
-        self._record_chunk(wall)
+        self._record_chunk(wall, gated)
 
         if self.telemetry.sample_progress:
             # Opt-in residual sampling for dashboard sparklines — one

@@ -170,14 +170,16 @@ class _MeshSlab(_SlotSlab):
     def _slab_capacity(self, serve: ServeConfig) -> int:
         return self.n_devices * self.per_device_capacity
 
-    def _record_chunk(self, wall: float) -> None:
+    def _record_chunk(self, wall: float, gated) -> None:
         per = self.per_device_capacity
         for d in range(self.n_devices):
             self.telemetry.device(d).record_chunk(
                 live=self._live_on(d), capacity=per,
                 chunk_iters=self.chunk_iters,
                 wall_s=wall / self.n_devices,
-                flops=self._chunk_flops(per))
+                flops=self._chunk_flops(per),
+                gated=None if gated is None
+                else int(gated[d * per:(d + 1) * per].sum()))
 
     def _record_advanced(self, slot: int, iters: int) -> None:
         self.telemetry.device(

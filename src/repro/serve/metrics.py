@@ -138,6 +138,12 @@ class ServeTelemetry:
     # layout's padding
     nnz_stored: int = 0
     nnz_capacity: int = 0
+    # sparse-slab chunks: slots that entered a chunk stopped (empty or
+    # finished), whose products the kernel skips, and all slots (S a
+    # chunk); a lower bound on the slot-iterations skipped, since a slot
+    # that stops inside a chunk is skipped for the rest of it too
+    gated_slot_chunks: int = 0
+    slot_chunks: int = 0
     # opt-in per-chunk residual sampling (dashboard sparklines); off by
     # default so no extra device readback happens unless requested
     sample_progress: bool = False
@@ -245,7 +251,13 @@ class ServeTelemetry:
     # engine-side counters
     # ------------------------------------------------------------- #
     def record_chunk(self, *, live: int, capacity: int, chunk_iters: int,
-                     wall_s: float, flops: int = 0) -> None:
+                     wall_s: float, flops: int = 0,
+                     gated: int | None = None) -> None:
+        """One chunk; ``gated`` (sparse slabs only) is the number of its
+        slots that entered it stopped."""
+        if gated is not None:
+            self.gated_slot_chunks += int(gated)
+            self.slot_chunks += capacity
         self.chunks += 1
         self.chunk_iters += chunk_iters
         self.chunk_row_iters += chunk_iters * capacity
@@ -347,7 +359,11 @@ class ServeTelemetry:
             out["sparse"] = {
                 "nnz_stored": self.nnz_stored,
                 "nnz_capacity": self.nnz_capacity,
-                "nnz_pad_share": 1.0 - self.nnz_stored / self.nnz_capacity}
+                "nnz_pad_share": 1.0 - self.nnz_stored / self.nnz_capacity,
+                "gated_slot_chunks": self.gated_slot_chunks,
+                "slot_chunks": self.slot_chunks,
+                "gate_share": (self.gated_slot_chunks / self.slot_chunks
+                               if self.slot_chunks else 0.0)}
         return out
 
 
@@ -414,6 +430,9 @@ class MeshTelemetry(ServeTelemetry):
                                         for t in self.per_device)
         self.chunk_flops = sum(t.chunk_flops for t in self.per_device)
         self.chunk_wall = sum(t.chunk_wall for t in self.per_device)
+        self.gated_slot_chunks = sum(t.gated_slot_chunks
+                                     for t in self.per_device)
+        self.slot_chunks = sum(t.slot_chunks for t in self.per_device)
         # Health events are recorded on the owning device's child (the
         # mesh slab's _record_quarantine hook), so the global counters
         # are the per-device sum — same conservation law as the chunk

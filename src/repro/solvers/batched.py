@@ -72,8 +72,8 @@ from repro.obs.health import (HealthConfig, STATUS_RUNNING,
                               STATUS_STALLED)
 from repro.problems.families import build_problem, get_family, infer_family
 from repro.problems.sparse import (TILE, BlockedDesign, CSCDesign,
-                                   block_layout, design_layout, is_sparse,
-                                   stack_designs)
+                                   block_layout, design_layout, gate_designs,
+                                   is_sparse, stack_designs)
 from repro.solvers.cache import CompileCache
 from repro.solvers.result import SolverResult
 
@@ -202,8 +202,14 @@ def _frozen_step(vstep, cfg: SolverConfig, data, c, col_sq, tau_base,
     one tolerance per instance) or ``cfg.max_iters``.  Returns
     ``(state, stop, info)``.  Every driver of the stack (the
     run-to-convergence loop, the chunk, the recorded-history loop)
-    iterates through this one step."""
-    new_state, info = vstep(data, c, col_sq, tau_base, active, state)
+    iterates through this one step.
+
+    On sparse designs the products of the instances already stopped are
+    not computed (:func:`~repro.problems.sparse.gate_designs`): their
+    step is thrown away, so the answers do not change, and their entries
+    of ``info`` are those of a step taken with zero products."""
+    new_state, info = vstep(gate_designs(data, lambda: ~stop), c, col_sq,
+                            tau_base, active, state)
     merged = _freeze_done(stop, new_state, state)
     stop = stop | (merged.stat <= tol) | (merged.k >= cfg.max_iters)
     return merged, stop, info
@@ -451,10 +457,12 @@ def _chunk_core(spec: BatchedProblemSpec, cfg: SolverConfig,
         # mask — cheaper than dynamic gathers at slab widths, and every
         # slab row is finite (data or zero placeholders) so no NaNs can
         # leak through the select.  Its operations carry the ``splice``
-        # scope.
+        # scope.  On sparse slabs the rows not admitted skip their
+        # products (their results are selected away).
         with jax.named_scope("splice"):
-            csq_new = jax.vmap(fam.col_sq)(*slab.data)
-            init = vinit(slab.data, new_c, new_x0, new_ids)
+            data = gate_designs(slab.data, lambda: admit)
+            csq_new = jax.vmap(fam.col_sq)(*data)
+            init = vinit(data, new_c, new_x0, new_ids)
             state = jax.tree_util.tree_map(
                 lambda s, v: jnp.where(_bmask(admit, s.ndim),
                                        v.astype(s.dtype), s),

@@ -8,11 +8,13 @@ too.
 """
 import re
 import sys
+from functools import partial
 from pathlib import Path
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.client import BatchSpec, FlexaClient, PathSpec, SoloSpec
@@ -21,8 +23,10 @@ from repro.client.errors import UnsupportedWorkloadError
 from repro.config.base import ServeConfig, SolverConfig
 from repro.obs import Tracer, tracing
 from repro.problems.lasso import make_lasso, nesterov_instance
+from repro.kernels import ref
 from repro.kernels.spmv import BLOCK, TILE
 from repro.problems.sparse import (CSCDesign, block_layout, capacity_bucket,
+                                   gate_designs, stack_designs,
                                    tile_padding_bound)
 from repro.remote.protocol import ProtocolError, encode_problem
 from repro.serve import SolveRequest
@@ -147,6 +151,77 @@ def test_block_layout_keeps_every_entry_and_pads_per_block_pair():
         assert (used & (pair == p_)).sum() == -(-held // TILE)
     empty = np.flatnonzero(~used)
     assert not empty.size or not used[empty[0]:].any()
+    # exactly the tiles past the last pair carry the block index -1
+    held = sum(-(-live[pair == p_].sum() // TILE) for p_ in set(pair[used]))
+    past = np.arange(vals.shape[0]) >= held
+    assert past.any()
+    np.testing.assert_array_equal(np.asarray(s.tile_rb) < 0, past)
+    np.testing.assert_array_equal(np.asarray(s.tile_cb) < 0, past)
+    assert (np.asarray(s.tile_rb)[~past] >= 0).all()
+
+
+def _pool_stack(pool, k, capacity):
+    return stack_designs(
+        [CSCDesign(pool.values[i], pool.rows[i], pool.col_ptr[i],
+                   (pool.m, pool.n)) for i in range(k)], capacity)
+
+
+_PRODUCTS = {"matvec": lambda d, v: d.matvec(v),
+             "rmatvec": lambda d, v: d.rmatvec(v),
+             "col_sq": lambda d, v: d.col_sq()}
+
+
+@pytest.mark.parametrize("product", sorted(_PRODUCTS))
+def test_gated_products_equal_the_ungated_ones_on_live_designs(
+        pool, product, monkeypatch):
+    """A vmapped product over a stack with a mixed live mask (the Pallas
+    kernel, interpreted) gives the ungated call's outputs bit for bit on
+    the live designs and zeros on the gated ones; padded to 8 × the
+    bucket, most of each design's tile steps hold no entries."""
+    monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    k = 4
+    stack = _pool_stack(pool, k, 8 * 4096)
+    live = np.array([False, True, False, True])
+    n_in = pool.n if product == "matvec" else pool.m
+    v = jnp.asarray(np.random.default_rng(5).standard_normal((k, n_in)),
+                    jnp.float32)
+    f = jax.jit(jax.vmap(_PRODUCTS[product]))
+    gated, = gate_designs((stack,), lambda: jnp.asarray(live))
+    got, full = np.asarray(f(gated, v)), np.asarray(f(stack, v))
+    np.testing.assert_array_equal(got[live], full[live])
+    assert not got[~live].any()
+    # the ungated stack equals each design's own call to float32 accuracy
+    for i in range(k):
+        one = jax.tree_util.tree_map(lambda a: a[i], stack)
+        np.testing.assert_allclose(
+            full[i], np.asarray(_PRODUCTS[product](one, v[i])),
+            rtol=1e-6, atol=1e-6 * np.abs(full[i]).max())
+
+
+@pytest.mark.parametrize("product", ["matvec", "rmatvec"])
+def test_trailing_tiles_with_the_sentinel_match_the_reference(
+        product, monkeypatch):
+    """Over several block pairs and many trailing tiles, the kernel
+    (interpreted) computes what ``ref.blocked_product_ref`` does."""
+    monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    rng = np.random.default_rng(11)
+    m, n = BLOCK + 300, 2 * BLOCK + 100
+    A = np.where(rng.random((m, n)) < 0.001,
+                 rng.standard_normal((m, n)), 0.0).astype(np.float32)
+    d = csc_from_dense(A)
+    p = d.padded(4 * capacity_bucket(d.nnz, m, n))
+    s = block_layout(p.values, p.rows, p.col_ptr, p.shape)
+    assert (np.asarray(s.tile_rb) < 0).sum() > 16      # trailing steps
+    if product == "matvec":
+        v = jnp.asarray(rng.standard_normal(n), jnp.float32)
+        want = ref.blocked_product_ref(s.values, s.cols, s.rows, v, m)
+    else:
+        v = jnp.asarray(rng.standard_normal(m), jnp.float32)
+        want = ref.blocked_product_ref(s.values, s.rows, s.cols, v, n)
+    got = np.asarray(_PRODUCTS[product](s, v))
+    want = np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=1e-6,
+                               atol=1e-6 * np.abs(want).max())
 
 
 def test_capacity_bucket_is_the_next_power_of_two():
@@ -321,6 +396,120 @@ def test_solo_batch_and_wave_agree_with_the_continuous_engine(pool):
                                        atol=1e-5)
 
 
+#: Requests submitted before each of the first ticks (pool indices):
+#: arrivals that fill the S = 3 slab, queue, and leave it part empty.
+SCRIPT = ([0, 1], [], [2, 3, 4], [], [5], [1])
+
+
+def _scripted_run(pool):
+    """SCRIPT through the continuous engine on the Pallas kernel
+    (interpreted), traced; the chunk programs are built for this run
+    alone.  Returns the engine and the tracer."""
+    B.make_chunk_stepper.clear()
+    try:
+        client, tracer = _client(S=3), Tracer()
+        with tracing(tracer):
+            for batch in SCRIPT:
+                for i in batch:
+                    client.submit(SoloSpec(pool.problem(i)))
+                client.step()
+            while client.pending:
+                client.step()
+    finally:
+        B.make_chunk_stepper.clear()
+    return client._backend._eng, tracer
+
+
+@pytest.fixture(scope="module")
+def scripted(pool):
+    """The scripted run with the gate, and with the gate forced open
+    (the ungated products every slot computed before the gate)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("REPRO_KERNELS", "interpret")
+        gated = _scripted_run(pool)
+        mp.setattr(B, "gate_designs",
+                   partial(gate_designs, force_open=True))
+        opened = _scripted_run(pool)
+    return gated, opened
+
+
+def test_gate_leaves_every_answer_bitwise_as_the_open_gate(scripted):
+    (gated, _), (opened, _) = scripted
+    assert len(gated._responses) == sum(map(len, SCRIPT))
+    assert sorted(gated._responses) == sorted(opened._responses)
+    for req_id, got in gated._responses.items():
+        want = opened._responses[req_id]
+        np.testing.assert_array_equal(got.x, want.x)
+        assert (got.iters, got.stat, got.status) == (
+            want.iters, want.stat, want.status)
+
+
+def _gated_by_hand(eng, tick: int, S: int = 3) -> int:
+    """Slots that enter tick ``tick``'s chunk stopped, from the audit
+    log alone: those not holding a request admitted by then and not
+    yet evicted."""
+    return S - sum(rec["admit_tick"] <= tick <= rec["evict_tick"]
+                   for rec in eng.audit)
+
+
+def test_gate_counters_equal_the_hand_count(scripted):
+    (eng, tracer), _ = scripted
+    chunks = [s for s in tracer.spans if s.name == "serve.chunk"]
+    assert chunks
+    by_hand = [_gated_by_hand(eng, s.args["tick"]) for s in chunks]
+    assert [s.args["gated"] for s in chunks] == by_hand
+    assert 0 < sum(by_hand) < 3 * len(chunks)
+    tele = eng.telemetry
+    assert tele.gated_slot_chunks == sum(by_hand)
+    assert tele.slot_chunks == 3 * len(chunks)
+    sparse = tele.snapshot()["sparse"]
+    assert sparse["gate_share"] == sum(by_hand) / (3 * len(chunks))
+
+
+def test_dense_chunk_spans_and_snapshot_carry_no_gate():
+    client = _client(S=2)
+    tracer = Tracer()
+    with tracing(tracer):
+        _serve(client, [nesterov_instance(m=30, n=80, nnz_frac=0.1, seed=s)
+                        for s in range(3)])
+    chunks = [s for s in tracer.spans if s.name == "serve.chunk"]
+    assert chunks and not any("gated" in s.args for s in chunks)
+    tele = client.telemetry
+    assert tele.gated_slot_chunks == tele.slot_chunks == 0
+    assert "sparse" not in tele.snapshot()
+
+
+def _dense_programs():
+    """The dense programs the gate helper is traced through: the chunk
+    (splice and iterations; watchdog off and on) and the batched
+    run-to-convergence solver, lowered from shapes."""
+    from repro.obs.health import HealthConfig
+    spec = B.BatchedProblemSpec(m=20, n=48)
+    cfg = SolverConfig(tol=1e-4, max_iters=50)
+    S, n = 4, spec.n
+    f32, i32, b = jnp.float32, jnp.int32, jnp.bool_
+    slab = jax.eval_shape(lambda: B.slab_alloc(spec, cfg, S))
+    sd = jax.ShapeDtypeStruct
+    args = (slab, sd((S,), b), sd((S,), b), sd((S,), f32), sd((S, n), f32),
+            sd((S,), i32), sd((S, n), f32), sd((S,), f32))
+    carry = (sd((S,), f32), sd((S,), i32))
+    health = HealthConfig(stall_window=3)
+    data = (sd((S, spec.m, n), f32), sd((S, spec.m), f32))
+    return [
+        B._build_chunk_stepper(spec, cfg, 4).lower(*args).as_text(),
+        B._build_chunk_stepper(spec, cfg, 4, health=health).lower(
+            *args, *carry).as_text(),
+        B._build_batched_solver(spec, cfg).lower(
+            data, sd((S,), f32), sd((S, n), f32)).as_text(),
+    ]
+
+
+def test_dense_programs_lower_as_without_the_gate(monkeypatch):
+    with_gate = _dense_programs()
+    monkeypatch.setattr(B, "gate_designs", lambda data, live, **_: data)
+    assert _dense_programs() == with_gate
+
+
 def test_mesh_backend_serves_sparse_designs_as_the_continuous_one(pool):
     probs = [pool.problem(i) for i in range(4)]
     ref = _serve(_client(S=2), probs)
@@ -328,9 +517,19 @@ def test_mesh_backend_serves_sparse_designs_as_the_continuous_one(pool):
                        solver=SolverConfig(tol=TOL, max_iters=MAX_ITERS),
                        serve=ServeConfig(slab_capacity=2, chunk_iters=16,
                                          mesh_devices=1))
-    for r, m in zip(ref, _serve(mesh, probs)):
+    tracer = Tracer()
+    with tracing(tracer):
+        got = _serve(mesh, probs)
+    for r, m in zip(ref, got):
         np.testing.assert_array_equal(np.asarray(m.x), np.asarray(r.x))
         assert m.iters == r.iters
+    # the gate counters roll up from the device's own count
+    eng = mesh._backend._eng
+    ticks = [s.args["tick"] for s in tracer.spans if s.name == "serve.chunk"]
+    sparse = mesh.telemetry.snapshot()["sparse"]
+    assert sparse["gated_slot_chunks"] == sum(
+        _gated_by_hand(eng, t, S=2) for t in ticks)
+    assert sparse["slot_chunks"] == 2 * len(ticks)
 
 
 # ------------------------------------------------------------------ #
