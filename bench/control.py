@@ -7,9 +7,12 @@ For each seed, in one process: a window of the cell's own traffic
 through the program, then the numbers of ``bench/check.py`` for the
 program's answers (the lower reading), and for the plain reference put
 in the program's place on the same instances (which has to read
-higher):
+higher).  The reference is the one of the pool's problem family, found
+by ``bench/check.py``, so the sides below work for every family that
+has a reference file:
 
-* ``bf16`` — the control: A and x rounded to bfloat16;
+* ``bf16`` — the control: the design (A, or Z) and the vectors it
+  multiplies rounded to bfloat16;
 * ``high`` — three bfloat16 passes;
 * ``early`` — a fault: float32 as the configuration states, stopped at
   ten times the request's tolerance and answered as converged (cells
@@ -52,12 +55,14 @@ def readings(cell, config, traffic, seed, seconds, peak, sides):
                                       not_ok)[0],
              "iters": {int(a[0]): a[2] for a in answers}, **numbers}]
     instances = sorted({a[0] for a in answers})
+    family = check.family_of(pool)
     for side in sides:
         precision, factor = SIDES[side]
         if factor != 1.0 and tol <= 0:
             continue
         ctl = {i: check.reference(pool.data(i), pool.c, tol * factor,
-                                  max_iters, precision=precision)
+                                  max_iters, precision=precision,
+                                  family=family)
                for i in instances}
         ctl_answers = [(i, x, k, "ok", k < max_iters)
                        for i, (x, k, _) in ctl.items()]

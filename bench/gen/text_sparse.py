@@ -20,6 +20,8 @@ downloaded, so each choice below is an assumption, listed under
 4. The entries of B are N(0, 1), each row then scaled to unit norm, as
    the rows (documents) of rcv1's tf-idf features are.
 
+:func:`bag_of_words` makes this design alone, for any family's pool.
+
 Then Nesterov's construction (``bench/gen/nesterov.py``) on the sparse
 B: A = B diag(scale) keeps B's pattern, x* is planted on the s columns
 of largest |Bᵀy|, b = A x* + y, and V* = ‖y‖² + c‖x*‖₁ in closed form.
@@ -67,10 +69,14 @@ def _strides(m: int) -> np.ndarray:
     return s[np.gcd(s, m) == 1].astype(np.int32)
 
 
-def _one(base_key, sign_key, support, *, m: int, n: int, cap: int,
-         nnz_mean: float, nnz_spread: float, c: float):
-    (k_nnz, k_rank, k_perm, k_off, k_stride, k_val, k_y, k_theta,
-     k_xi) = jax.random.split(base_key, 9)
+def bag_of_words(keys, *, m: int, n: int, cap: int, nnz_mean: float,
+                 nnz_spread: float):
+    """One design by steps 1–4 above, traceable: ``(vals, rows, cols,
+    col_ptr)``, the entries column by column padded with zeros (row 0,
+    column n − 1) to ``cap``, and ``col_ptr`` (n + 1,).  ``keys``: six
+    PRNG keys, drawing the nnz, the ranking of the columns, the row
+    permutation, the offsets, the strides and the values."""
+    k_nnz, k_rank, k_perm, k_off, k_stride, k_val = keys
     target = nnz_mean * (1.0 + nnz_spread * jax.random.uniform(
         k_nnz, (), jnp.float32, -1.0, 1.0))
     by_rank = zipf_counts(target, m=m, n=n)
@@ -94,6 +100,16 @@ def _one(base_key, sign_key, support, *, m: int, n: int, cap: int,
                      0.0)
     row_sq = jax.ops.segment_sum(vals * vals, rows, num_segments=m)
     vals = vals / jnp.sqrt(jnp.maximum(row_sq[rows], 1e-30))
+    return vals, rows, cols, col_ptr
+
+
+def _one(base_key, sign_key, support, *, m: int, n: int, cap: int,
+         nnz_mean: float, nnz_spread: float, c: float):
+    (k_nnz, k_rank, k_perm, k_off, k_stride, k_val, k_y, k_theta,
+     k_xi) = jax.random.split(base_key, 9)
+    vals, rows, cols, col_ptr = bag_of_words(
+        (k_nnz, k_rank, k_perm, k_off, k_stride, k_val), m=m, n=n, cap=cap,
+        nnz_mean=nnz_mean, nnz_spread=nnz_spread)
 
     def rmatvec(v, r):
         return jax.ops.segment_sum(v * r[rows], cols, num_segments=n,
